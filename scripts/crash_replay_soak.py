@@ -90,7 +90,6 @@ def run_child() -> None:
     # raises out of flush(), so "FLUSHED" never prints and the soak
     # fails loudly
     cfg.ledger_strict = True
-    cfg.jax_compilation_cache_dir = os.environ.get("SOAK_COMPILE_CACHE", "")
     chaos_ms = float(os.environ.get("SOAK_CHAOS_MS", "0"))
     if chaos_ms:
         cfg.chaos_enabled = True
@@ -149,8 +148,8 @@ def _mk_global():
     return server, imp, obs
 
 
-def _spawn_child(wal_dir: str, forward_address: str, chaos_ms: float,
-                 compile_cache: str) -> subprocess.Popen:
+def _spawn_child(wal_dir: str, forward_address: str, chaos_ms: float
+                 ) -> subprocess.Popen:
     env = dict(os.environ)
     env.update({
         CHILD_ENV_FLAG: "1",
@@ -158,7 +157,6 @@ def _spawn_child(wal_dir: str, forward_address: str, chaos_ms: float,
         "SOAK_FORWARD_ADDRESS": forward_address,
         "SOAK_WAL_DIR": wal_dir,
         "SOAK_CHAOS_MS": str(chaos_ms),
-        "SOAK_COMPILE_CACHE": compile_cache,
     })
     proc = subprocess.Popen(
         [sys.executable, os.path.abspath(__file__)],
@@ -196,7 +194,6 @@ def run_soak(kills: int = 3, counters_per_round: int = 40,
     tmp = tempfile.mkdtemp(prefix="crash-replay-soak-")
     wal_dir = os.path.join(tmp, "wal")
     ctl_wal_dir = os.path.join(tmp, "wal-control")
-    cache_dir = os.path.join(tmp, "compile-cache")
     report = {"kills": 0, "restarts": 0, "rounds": []}
 
     def lines_for(round_no: int):
@@ -211,7 +208,7 @@ def run_soak(kills: int = 3, counters_per_round: int = 40,
         return out
 
     child = None
-    ctl = _spawn_child(ctl_wal_dir, c_imp.address, 0.0, "")
+    ctl = _spawn_child(ctl_wal_dir, c_imp.address, 0.0)
     try:
         for round_no in range(kills):
             if child is not None:
@@ -220,8 +217,7 @@ def run_soak(kills: int = 3, counters_per_round: int = 40,
                 # back, so respawn with chaos on
                 child.kill()
                 child.wait()
-            child = _spawn_child(wal_dir, f_imp.address, chaos_ms,
-                                 cache_dir)
+            child = _spawn_child(wal_dir, f_imp.address, chaos_ms)
             lines = lines_for(round_no)
             _feed(child, lines)
             _feed(ctl, lines + ["FLUSH"])
@@ -240,7 +236,7 @@ def run_soak(kills: int = 3, counters_per_round: int = 40,
             child.wait()
             report["kills"] += 1
             # restart with chaos OFF: the re-scan replays the log
-            child = _spawn_child(wal_dir, f_imp.address, 0.0, cache_dir)
+            child = _spawn_child(wal_dir, f_imp.address, 0.0)
             report["restarts"] += 1
             _feed(child, ["FLUSH"])  # drains the replayed segments
             assert wait_until(

@@ -92,7 +92,6 @@ def run_child() -> None:
     # kill/replay cycle — strict raises out of flush(), so "FLUSHED"
     # never prints and the soak fails loudly
     cfg.ledger_strict = True
-    cfg.jax_compilation_cache_dir = os.environ.get("SOAK_COMPILE_CACHE", "")
     delay_s = float(os.environ.get("SOAK_CUTOVER_DELAY_S", "0"))
     if delay_s:
         cfg.chaos_enabled = True
@@ -138,8 +137,8 @@ def run_child() -> None:
 # ---------------------------------------------------------------------------
 
 
-def _spawn_child(wal_dir: str, cutover_delay_s: float,
-                 compile_cache: str) -> subprocess.Popen:
+def _spawn_child(wal_dir: str, cutover_delay_s: float
+                 ) -> subprocess.Popen:
     env = dict(os.environ)
     env.update({
         CHILD_ENV_FLAG: "1",
@@ -148,7 +147,6 @@ def _spawn_child(wal_dir: str, cutover_delay_s: float,
                       + " --xla_force_host_platform_device_count=8"),
         "SOAK_RESHARD_WAL": wal_dir,
         "SOAK_CUTOVER_DELAY_S": str(cutover_delay_s),
-        "SOAK_COMPILE_CACHE": compile_cache,
     })
     proc = subprocess.Popen(
         [sys.executable, os.path.abspath(__file__)],
@@ -228,11 +226,10 @@ def run_soak(kills: int = 2, cutover_delay_s: float = 120.0,
     invariant breaks."""
     tmp = tempfile.mkdtemp(prefix="reshard-soak-")
     wal_dir = os.path.join(tmp, "reshard-wal")
-    cache_dir = os.path.join(tmp, "compile-cache")
     report = {"kills": 0, "restarts": 0, "rounds": []}
 
     child = None
-    ctl = _spawn_child(os.path.join(tmp, "ctl-wal"), 0.0, cache_dir)
+    ctl = _spawn_child(os.path.join(tmp, "ctl-wal"), 0.0)
     try:
         for round_no in range(kills):
             if child is not None:
@@ -240,7 +237,7 @@ def run_soak(kills: int = 2, cutover_delay_s: float = 120.0,
                 # each kill round needs the hold-open seam back
                 child.kill()
                 child.wait()
-            child = _spawn_child(wal_dir, cutover_delay_s, cache_dir)
+            child = _spawn_child(wal_dir, cutover_delay_s)
             lines = lines_for(round_no)
             _feed(child, lines + ["APPLY"])
             _await(child, "APPLIED")
@@ -261,7 +258,7 @@ def run_soak(kills: int = 2, cutover_delay_s: float = 120.0,
             # restart with chaos OFF at the OLD shard count: start()
             # replays the log into a topology that differs from the
             # killed cutover's target on purpose
-            child = _spawn_child(wal_dir, 0.0, cache_dir)
+            child = _spawn_child(wal_dir, 0.0)
             report["restarts"] += 1
             assert wait_until(lambda: not _wal_segments(wal_dir),
                               timeout=30.0), "reshard WAL did not drain"

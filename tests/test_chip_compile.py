@@ -1,0 +1,153 @@
+"""The server's main-path device programs compile for a TPU v5e.
+
+No chip is attached here: the TPU compiler is installed and compiles for
+a chip that is described, not attached (guide on-chip-measurement §2,
+step 3). Each case lowers one jitted program of the ingest / flush /
+import path at the widths a deployment runs (C = 128 and 2C = 256
+centroid columns, 16,384 HLL registers, BINS_PAD llhist bins) and 8,192
+rows — enough rows to tile, few enough that a case takes seconds — and
+asks the v5e compiler for an executable. What it refuses here it would
+refuse on the chip, at no chip time.
+
+The topology is described inside a module-scoped fixture, never at
+import: only one process may hold the TPU library, and under pytest-xdist
+every worker imports every test file. Keep all such tests in this file.
+A compile that passes is not a chip run.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from veneur_tpu.ops import batch_hll, batch_llhist, batch_tdigest, scalars
+
+K = 8192          # table rows per case
+B = 16384         # COO batch width (tpu.batch_cap of examples/example.yaml)
+R = 512           # imported rows per merge batch
+PS = (0.5, 0.9, 0.99)
+C = batch_tdigest.C
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    # the compiler logs under /tmp otherwise
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable for a described chip is written to the persistent
+    # cache but cannot be read back without the chip: cache off here
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    # a trace another test file left in this worker (same shapes, CPU
+    # side of batch_tdigest's backend branch) must not be reused here,
+    # nor ours by the files that follow
+    jax.clear_caches()
+    yield desc
+    jax.clear_caches()
+    jax.config.update("jax_enable_compilation_cache", was_enabled)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def tpu_segment_reduce(monkeypatch):
+    """batch_tdigest picks its segment-reduce at trace time from
+    jax.default_backend(), which is the CPU here: steer the probe so the
+    TPU side (_segment_reduce_matmul) is what compiles."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _shapes(tree, sharding):
+    """Pytree of arrays/ShapeDtypeStructs -> the same shapes, placed on
+    the described chip (no device holds an array here)."""
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+def _vec(n, dtype):
+    return jax.ShapeDtypeStruct((n,), dtype)
+
+
+def _mat(n, m, dtype):
+    return jax.ShapeDtypeStruct((n, m), dtype)
+
+
+def _table(init):
+    """Shapes of one family's K-row device table."""
+    return jax.eval_shape(lambda: init(K))
+
+
+f32, i32, i8 = jnp.float32, jnp.int32, jnp.int8
+COO = (_vec(B, i32), _vec(B, f32), _vec(B, f32))    # rows, values, weights
+BINNED = (_vec(B, i32), _vec(B, i32), _vec(B, i32))  # rows, index, count
+
+# (id, jitted program, its table's init, the other arguments, statics);
+# batch_llhist.merge takes a second table, marked by its init
+CASES = [
+    ("scalars.apply_counters", scalars.apply_counters,
+     scalars.init_counters, COO, ()),
+    ("scalars.apply_gauges", scalars.apply_gauges,
+     scalars.init_gauges, COO[:2], ()),
+    ("scalars.merge_gauges", scalars.merge_gauges,
+     scalars.init_gauges, (_vec(R, i32), _vec(R, f32)), ()),
+    ("batch_tdigest._apply_batch_jit", batch_tdigest._apply_batch_jit,
+     batch_tdigest.init_state, COO + (_vec(B, i32),), ()),
+    ("batch_tdigest.compact", batch_tdigest.compact,
+     batch_tdigest.init_state, (), ()),
+    ("batch_tdigest.flush_quantiles_packed",
+     batch_tdigest.flush_quantiles_packed,
+     batch_tdigest.init_state, (), (PS, True)),
+    ("batch_tdigest.flush_export_packed", batch_tdigest.flush_export_packed,
+     batch_tdigest.init_state, (), (PS,)),
+    ("batch_tdigest.merge_centroid_rows", batch_tdigest.merge_centroid_rows,
+     batch_tdigest.init_state,
+     (_vec(R, i32), _mat(R, C, f32), _mat(R, C, f32),
+      _vec(R, f32), _vec(R, f32), _vec(R, f32)), ()),
+    ("batch_hll.apply_batch", batch_hll.apply_batch,
+     batch_hll.init_state, BINNED, ()),
+    ("batch_hll.estimate", batch_hll.estimate, batch_hll.init_state, (), ()),
+    ("batch_hll.merge_rows", batch_hll.merge_rows,
+     batch_hll.init_state, (_vec(R, i32), _mat(R, batch_hll.M, i8)), ()),
+    ("batch_llhist.apply_batch", batch_llhist.apply_batch,
+     batch_llhist.init_state, BINNED, ()),
+    ("batch_llhist.merge", batch_llhist.merge,
+     batch_llhist.init_state, (batch_llhist.init_state,), ()),
+    ("batch_llhist.flush_packed", batch_llhist.flush_packed,
+     batch_llhist.init_state, (), (PS,)),
+]
+
+
+_SEGMENT_REDUCERS = (batch_tdigest.compact,
+                     batch_tdigest.flush_export_packed,
+                     batch_tdigest.merge_centroid_rows)
+
+
+@pytest.mark.parametrize("program,init,others,static",
+                         [pytest.param(*c[1:], id=c[0]) for c in CASES])
+def test_main_path_program_compiles_for_v5e(
+        one_chip, tpu_segment_reduce, program, init, others, static):
+    others = tuple(_table(o) if callable(o) else o for o in others)
+    args = _shapes((_table(init),) + others, one_chip)
+    lowered = program.lower(*args, *static)
+    if program in _SEGMENT_REDUCERS:
+        # the TPU side of the trace-time branch is a one-hot matmul
+        assert "dot_general" in lowered.as_text()
+    compiled = lowered.compile()
+    # an executable for the described TPU, not for the CPU this runs on
+    assert "tpu" in compiled.as_text().lower()
+    assert compiled.memory_analysis().temp_size_in_bytes >= 0

@@ -691,7 +691,11 @@ class TestCrashDrill:
 # -------------------------------------------------------------------------
 
 
+@pytest.mark.usefixtures("jax_cache_config")
 class TestCompilationCache:
+    """The directory rule of util/compilecache.py, driven through the
+    server."""
+
     def test_knob_points_jax_at_directory(self, tmp_path):
         import jax
 
@@ -704,13 +708,32 @@ class TestCompilationCache:
             kind="compilation_cache_enabled")
 
     def test_disabled_without_directory(self):
+        # CPU backend, nothing configured: the <checkout>/.jax_cache
+        # default is for accelerators only
         server, _ = mk_server()
         assert server.enable_compilation_cache() is False
 
+    def test_environment_directory_wins(self, tmp_path, monkeypatch):
+        """JAX_COMPILATION_CACHE_DIR set: the server sets no directory
+        in code, even with one configured."""
+        import jax
+
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "from-env"))
+        before = jax.config.jax_compilation_cache_dir
+        server, _ = mk_server(
+            jax_compilation_cache_dir=str(tmp_path / "from-config"))
+        assert server.enable_compilation_cache() is True
+        assert jax.config.jax_compilation_cache_dir == before
+        assert not (tmp_path / "from-config").exists()
+        event = server.telemetry.events.snapshot(
+            kind="compilation_cache_enabled")[-1]
+        assert event["directory"] == str(tmp_path / "from-env")
+
     def test_retrace_tags_carry_cache_outcome(self, tmp_path):
         cache_dir = tmp_path / "jit-cache"
-        cache_dir.mkdir()
         server, _ = mk_server(jax_compilation_cache_dir=str(cache_dir))
+        assert server.enable_compilation_cache() is True
         # miss: the recompile ADDED a cache entry
         server._store_resize("counter", 64, 128, 0.01, kind="resize")
         (cache_dir / "jit_x-abc-cache").write_bytes(b"x")

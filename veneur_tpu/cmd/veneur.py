@@ -72,10 +72,11 @@ def main(argv=None) -> int:
     from veneur_tpu.core.server import Server
     server = Server(cfg)
     server.start()
-    log.info("veneur-tpu %s started (local=%s, statsd=%s, ssf=%s, http=%s)",
+    log.info("veneur-tpu %s started (local=%s, statsd=%s, ssf=%s, http=%s, "
+             "device=%s)",
              veneur_tpu.__version__, server.is_local,
              cfg.statsd_listen_addresses, cfg.ssf_listen_addresses,
-             cfg.http_address)
+             cfg.http_address, server.device_info)
 
     stop = threading.Event()
 

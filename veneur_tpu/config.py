@@ -125,13 +125,6 @@ class TpuConfig:
     # hard cap on promoted device rows (HBM guard: slots are 16 KB
     # each; 65536 = 1 GB). Keys past the cap stay on the host tier.
     set_max_dev_slots: int = 65536
-    # run the t-digest flush's post-sort interpolation through the
-    # fused Pallas kernel (ops/pallas_tdigest). OFF by default until
-    # real-TPU validation lands; any kernel failure falls back to the
-    # jnp path permanently for the process. Requires histo_capacity to
-    # be a multiple of 128 (the kernel's row tile) — otherwise flushes
-    # stay on the jnp path (warned at startup).
-    pallas_tdigest_flush: bool = False
 
 
 @dataclass

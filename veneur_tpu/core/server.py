@@ -29,6 +29,7 @@ from veneur_tpu.samplers.metrics import (
     HistogramAggregates, InterMetric, MetricScope, UDPMetric,
 )
 from veneur_tpu.samplers.parser import ParseError, Parser
+from veneur_tpu.util import compilecache
 from veneur_tpu.util.matcher import SinkRoutingMatcher
 
 logger = logging.getLogger("veneur_tpu.server")
@@ -183,7 +184,6 @@ class Server:
             batch_cap=config.tpu.batch_cap,
             shard_devices=config.tpu.shards,
             max_rows=config.tpu.max_rows_per_family,
-            pallas_flush=config.tpu.pallas_tdigest_flush,
             set_promote_samples=config.tpu.set_promote_samples,
             set_max_dev_slots=config.tpu.set_max_dev_slots,
             llhist_capacity=config.tpu.llhist_capacity,
@@ -884,31 +884,24 @@ class Server:
     # -- lifecycle -------------------------------------------------------
 
     def enable_compilation_cache(self) -> bool:
-        """Point JAX's persistent compilation cache at the configured
-        directory (no-op without one): a crash-restart-replay cycle
-        (SIGUSR2 handoff, WAL recovery) comes up with warm kernels from
-        disk instead of paying the full retrace tax mid-recovery.
-        Thresholds zeroed: restart warmth is the point, so every
-        compile is worth caching. Returns True when enabled."""
-        cache_dir = self.config.jax_compilation_cache_dir
+        """Turn on JAX's persistent compilation cache under the one
+        directory rule of util/compilecache.py: a cold start, a
+        crash-restart-replay cycle (SIGUSR2 handoff, WAL recovery)
+        comes up with warm kernels from disk instead of paying the
+        full retrace tax. Returns True when enabled."""
+        try:
+            cache_dir = compilecache.enable(
+                self.config.jax_compilation_cache_dir)
+        except OSError:
+            logger.exception("could not create the persistent JAX "
+                             "compilation cache directory")
+            return False
         if not cache_dir:
             return False
-        try:
-            import jax
-            os.makedirs(cache_dir, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", cache_dir)
-            jax.config.update(
-                "jax_persistent_cache_min_entry_size_bytes", -1)
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0)
-            self.telemetry.record_event(
-                "compilation_cache_enabled", directory=cache_dir,
-                entries=max(0, self._compile_cache_entries()))
-            return True
-        except Exception:
-            logger.exception("could not enable the persistent JAX "
-                             "compilation cache")
-            return False
+        self.telemetry.record_event(
+            "compilation_cache_enabled", directory=cache_dir,
+            entries=max(0, compilecache.entries()))
+        return True
 
     def start(self) -> None:
         from veneur_tpu.util.crash import guarded
@@ -1150,8 +1143,15 @@ class Server:
         # listener bound, so a wedged startup never wins a handoff
         from veneur_tpu.core import restart
         restart.mark_ready()
+        import jax
+        devices = jax.devices()
+        # where this server runs, so no run is ambiguous about it
+        self.device_info = {"platform": devices[0].platform,
+                            "device_kind": devices[0].device_kind,
+                            "device_count": len(devices)}
         startup = {"pid": os.getpid(),
-                   "mode": "local" if self.is_local else "global"}
+                   "mode": "local" if self.is_local else "global",
+                   **self.device_info}
         if self.store.shard_plane is not None:
             # mesh topology in the flight recorder: which devices this
             # store partitioned over, under which routing policy
@@ -1191,20 +1191,6 @@ class Server:
             "pipeline_stall", component=component,
             heartbeat_age_s=round(age, 3))
 
-    def _compile_cache_entries(self) -> int:
-        """Entry count of the persistent JAX compilation cache dir
-        (-1 = cache disabled/unreadable) — the hit/miss probe: a
-        recompile that ADDED entries was a miss, one that didn't was
-        served from disk."""
-        cache_dir = self.config.jax_compilation_cache_dir
-        if not cache_dir:
-            return -1
-        try:
-            return sum(1 for name in os.listdir(cache_dir)
-                       if name.endswith("-cache"))
-        except OSError:
-            return -1
-
     def _store_resize(self, family: str, old_cap: int, new_cap: int,
                       seconds: float, kind: str = "resize",
                       prewarmed: bool = False) -> None:
@@ -1218,14 +1204,14 @@ class Server:
         cache = None
         if kind == "resize":
             self._cache_entries_at_resize[family] = \
-                self._compile_cache_entries()
+                compilecache.entries()
             if self.prewarmer is not None:
                 # queue the rung AFTER the one just reached, so the
                 # next doubling is already compiled when it lands
                 self.prewarmer.note_resize(family, new_cap)
         elif kind == "recompile":
             before = self._cache_entries_at_resize.pop(family, -1)
-            after = self._compile_cache_entries()
+            after = compilecache.entries()
             if before >= 0 and after >= 0:
                 cache = "miss" if after > before else "hit"
             if prewarmed and cache != "hit":
@@ -1478,6 +1464,13 @@ class Server:
 
     def _flush_loop(self) -> None:
         beat = self.overload.supervisor.beat
+        # the first interval starts when the kernel warm-up ends: a
+        # flush that races it compiles the same programs a second time,
+        # and a cold compile at 100k keys is minutes, not seconds
+        while self._warmup_thread.is_alive():
+            beat("flush-loop")
+            if self._shutdown.wait(0.2):
+                return
         while not self._shutdown.is_set():
             delay = (self._tick_delay() if self.config.synchronize_with_interval
                      else self.interval)
@@ -1496,6 +1489,8 @@ class Server:
         """Die loudly if flushes stall (reference server.go:877-919)."""
         allowed = self.config.flush_watchdog_missed_flushes * self.interval
         while not self._shutdown.wait(self.interval):
+            if self._warmup_thread.is_alive():
+                continue  # compiling, not stalled; _warmup restarts the clock
             since = time.time() - self.last_flush_unix
             self.telemetry.record_event(
                 "watchdog_tick", since_last_flush_s=round(since, 3),
@@ -1522,7 +1517,6 @@ class Server:
                 set_capacity=cfg.tpu.set_capacity,
                 batch_cap=cfg.tpu.batch_cap,
                 shard_devices=cfg.tpu.shards,
-                pallas_flush=cfg.tpu.pallas_tdigest_flush,
                 llhist_capacity=cfg.tpu.llhist_capacity,
                 histogram_encoding=cfg.histogram_encoding,
                 shard_routing=cfg.tpu.shard_routing)
@@ -1535,6 +1529,9 @@ class Server:
                 collect_forward=self.forwarder is not None)
         except Exception:
             logger.exception("kernel warmup failed")
+        finally:
+            # the flush watchdog and the readiness check count from here
+            self.last_flush_unix = time.time()
 
     def flush(self) -> None:
         """One flush pass (reference flusher.go:26-122)."""

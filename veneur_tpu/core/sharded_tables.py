@@ -724,11 +724,11 @@ class ShardedHistoTable(_PerDeviceStates, _DigestRouted, HistoTable):
             # fused flush+export: one dispatch, two transfers (the
             # merged state's staging is already folded, so the fold
             # inside the fused op is a no-op concat of zeros).
-            # Routed through the pallas-aware wrappers so
-            # tpu.pallas_tdigest_flush applies to sharded stores too.
-            packed, export_packed = self._flush_export(ps, merged)
+            packed, export_packed = batch_tdigest.flush_export_packed(
+                merged, ps)
         else:
-            packed = self._flush_packed(ps, merged, fold_staging=False)
+            packed = batch_tdigest.flush_quantiles_packed(
+                merged, ps, fold_staging=False)
             export_packed = None
         snap["packed"] = packed
         snap["export_packed"] = export_packed
@@ -745,9 +745,10 @@ class ShardedHistoTable(_PerDeviceStates, _DigestRouted, HistoTable):
                          need_export: bool):
         merged = self._merged_state(states, note=False)
         if need_export:
-            out = self._flush_export(ps, merged)
+            out = batch_tdigest.flush_export_packed(merged, ps)
         else:
-            out = self._flush_packed(ps, merged, fold_staging=False)
+            out = batch_tdigest.flush_quantiles_packed(
+                merged, ps, fold_staging=False)
         return (out, self._reset_state_donated(states))
 
     def _retopo_device_locked(self) -> None:

@@ -45,17 +45,11 @@ def merge_rows(regs, rows, in_regs):
     return jnp.maximum(regs, grid)
 
 
+@jax.jit
 def estimate(regs):
     """Per-key LogLog-Beta estimate (parity with the reference's vendored
-    estimator, hyperloglog.go:207-231 + utils.go:12-22). On TPU this
-    dispatches to the fused single-pass pallas kernel."""
-    from veneur_tpu.ops import pallas_hll
-    return pallas_hll.estimate(regs)
-
-
-@jax.jit
-def _estimate_jnp(regs):
-    """Two-pass jnp formulation (the portable fallback)."""
+    estimator, hyperloglog.go:207-231 + utils.go:12-22), as two row
+    reductions."""
     ez = jnp.sum(regs == 0, axis=-1).astype(jnp.float32)
     s = jnp.sum(jnp.exp2(-regs.astype(jnp.float32)), axis=-1)
     zl = jnp.log(ez + 1.0)

@@ -11,10 +11,7 @@ family's distributed story *exact* rather than approximate.
 
 The flush readout (quantiles + count + midpoint sum) is one jitted pass:
 gather the bins in value order, cumulative-sum, binary-search the rank
-per (row, percentile), interpolate inside the located bin. On TPU the
-scatter-add can run through the Pallas kernel (ops/pallas_llhist),
-latched off on any failure — the same safety model as the HLL estimate
-kernel.
+per (row, percentile), interpolate inside the located bin.
 
 The device table is padded to a lane-aligned width (BINS_PAD, multiple
 of 128); bins past llhist_ref.BINS are never written and every readout
@@ -46,17 +43,10 @@ def init_state(num_keys: int) -> jnp.ndarray:
 
 
 @partial(jax.jit, donate_argnums=0)
-def _apply_batch_jnp(regs, rows, bin_idx, weight):
+def apply_batch(regs, rows, bin_idx, weight):
     """Scatter-add a batch of pre-binned samples. rows == PAD_ROW marks
     padding (dropped by mode="drop")."""
     return regs.at[rows, bin_idx].add(weight, mode="drop")
-
-
-def apply_batch(regs, rows, bin_idx, weight):
-    """Batch scatter-add, through the Pallas kernel when it is active
-    for this shape (TPU only; any failure latches the jnp path)."""
-    from veneur_tpu.ops import pallas_llhist
-    return pallas_llhist.apply_batch(regs, rows, bin_idx, weight)
 
 
 @jax.jit
@@ -85,7 +75,9 @@ def flush_packed(regs, ps: tuple):
     csum = jnp.cumsum(c, axis=1)                    # int32, exact
     total = csum[:, -1]                             # int32, exact
     total_f = total.astype(jnp.float32)
-    approx_sum = (regs[:, :BINS].astype(jnp.float32) @ _BIN_MID)
+    # HIGHEST: a default-precision f32 matmul on the TPU is one bf16 pass
+    approx_sum = jnp.matmul(regs[:, :BINS].astype(jnp.float32), _BIN_MID,
+                            precision=jax.lax.Precision.HIGHEST)
 
     if ps:
         p_arr = jnp.asarray(ps, jnp.float32)

@@ -20,7 +20,6 @@ Cross-host (DCN) hops between tiers use the gRPC forward plane
 
 from __future__ import annotations
 
-import logging
 from functools import partial
 from typing import Dict, Tuple
 
@@ -31,41 +30,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from veneur_tpu.ops import batch_hll, batch_tdigest, scalars
 
-logger = logging.getLogger("veneur_tpu.parallel.mesh")
-
-# shard_map moved to the jax top level (and renamed its replication-
-# check kwarg check_rep -> check_vma) after 0.4.x; accept both so the
-# collective path runs on every toolchain the image ships
-if hasattr(jax, "shard_map"):
-    _shard_map, _CHECK_KW = jax.shard_map, "check_vma"
-else:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _CHECK_KW = "check_rep"
-
 SHARD_AXIS = "shard"
 
 
 def make_mesh(n_devices: int = 0) -> Mesh:
     devices = jax.devices()
     if n_devices and len(devices) < n_devices:
-        # the default platform (e.g. a single real TPU chip) is smaller
-        # than requested; fall back to the virtual CPU mesh
-        # (xla_force_host_platform_device_count) for sharding validation
-        try:
-            cpu = jax.devices("cpu")
-            if len(cpu) >= n_devices:
-                logger.warning(
-                    "make_mesh: default platform has %d devices < %d "
-                    "requested; falling back to the virtual CPU mesh "
-                    "(validation only — not a production topology)",
-                    len(devices), n_devices)
-                devices = cpu
-            else:
-                logger.warning(
-                    "make_mesh: only %d devices available, %d requested; "
-                    "building an undersized mesh", len(devices), n_devices)
-        except RuntimeError:
-            pass
+        raise ValueError(
+            f"make_mesh: {n_devices} devices requested but only "
+            f"{len(devices)} {devices[0].platform} device(s) exist")
     if n_devices:
         devices = devices[:n_devices]
     return Mesh(np.asarray(devices), (SHARD_AXIS,))
@@ -163,10 +136,7 @@ def _merge_shards_local(state):
 
     sets = jax.lax.pmax(state["sets"].astype(jnp.int32), SHARD_AXIS).astype(
         jnp.int8)
-    # lax.axis_size only exists on newer jax; psum(1) is the portable
-    # spelling of the same constant
-    n = (jax.lax.axis_size(SHARD_AXIS) if hasattr(jax.lax, "axis_size")
-         else jax.lax.psum(1, SHARD_AXIS))
+    n = jax.lax.axis_size(SHARD_AXIS)
     histos = _merge_digest_keysharded(state["histos"], n)
     return {
         "counters": counters,
@@ -187,9 +157,9 @@ def merge_shards(mesh: Mesh, state: Dict) -> Dict:
     # replication check off: outputs are replicated by construction
     # (derived from all_gather/psum results) but the tracker can't prove
     # it through sort
-    fn = _shard_map(
+    fn = jax.shard_map(
         _merge_shards_local, mesh=mesh, in_specs=(spec_in,),
-        out_specs=out_specs, **{_CHECK_KW: False})
+        out_specs=out_specs, check_vma=False)
     return fn(state)
 
 

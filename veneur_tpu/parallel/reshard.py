@@ -179,7 +179,10 @@ class ReshardController:
         if devices is None:
             if shards is None or int(shards) < 1:
                 raise ReshardError("target shards must be >= 1")
-            devices = local_shard_devices(int(shards))
+            try:
+                devices = local_shard_devices(int(shards))
+            except ValueError as e:
+                raise ReshardError(str(e)) from e
         devices = list(devices)
         if not devices:
             raise ReshardError("no devices available for target plane")

@@ -18,7 +18,6 @@ an operator can see a skewed key space (one hot shard) or a dead chip
 
 from __future__ import annotations
 
-import logging
 import threading
 from typing import Dict, List, Optional
 
@@ -26,33 +25,22 @@ import numpy as np
 
 from veneur_tpu.parallel import collectives
 
-logger = logging.getLogger("veneur_tpu.parallel.sharded_server")
-
 ROUTING_DIGEST = "digest"
 ROUTING_ROUNDROBIN = "roundrobin"
 
 
 def local_shard_devices(n: int) -> List:
-    """The n local devices to shard over; falls back to the virtual CPU
-    devices when the default platform is smaller (validation
-    topologies)."""
+    """The first n local devices of the default platform. Asking for
+    more shards than there are devices is an error: a mesh silently
+    moved to other devices, or shrunk, would serve from a topology the
+    operator did not configure."""
     import jax
 
     devices = jax.local_devices()
     if len(devices) < n:
-        try:
-            cpu = jax.devices("cpu")
-            if len(cpu) >= n:
-                logger.warning(
-                    "shard_devices=%d > %d local devices; using the "
-                    "virtual CPU mesh (validation only)", n, len(devices))
-                devices = cpu
-        except RuntimeError:
-            pass
-    if len(devices) < n:
-        logger.warning("shard_devices=%d > %d available; clamping",
-                       n, len(devices))
-        n = len(devices)
+        raise ValueError(
+            f"{n} shards requested but only {len(devices)} local "
+            f"{devices[0].platform} device(s) exist")
     return list(devices[:n])
 
 
@@ -135,12 +123,9 @@ class ShardedServingPlane:
 
 def build_plane(shards: int, routing: str = ROUTING_DIGEST
                 ) -> Optional[ShardedServingPlane]:
-    """Plane for `shards` local devices; None when the topology can't
-    shard (fewer than 2 devices) so callers fall back to single-device
-    tables."""
+    """Plane for `shards` local devices; None for a single-device
+    store (shards <= 1)."""
     if not shards or shards <= 1:
         return None
-    devices = local_shard_devices(shards)
-    if len(devices) < 2:
-        return None
-    return ShardedServingPlane(devices, routing=routing)
+    return ShardedServingPlane(local_shard_devices(shards),
+                               routing=routing)
