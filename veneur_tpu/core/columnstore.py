@@ -38,8 +38,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from veneur_tpu.core.telemetry import annotate
 from veneur_tpu.ops import (batch_hll, batch_llhist, batch_tdigest,
-                            hll_ref, llhist_ref, scalars)
+                            device_scope, hll_ref, llhist_ref, scalars)
 from veneur_tpu.samplers import metrics as m
 from veneur_tpu.samplers.metrics import MetricScope, UDPMetric
 
@@ -51,6 +52,7 @@ PAD_ROW = np.int32(2**31 - 1)
 
 
 @partial(jax.jit, donate_argnums=0)
+@device_scope("reset", "any")
 def _zeros_like_donated(tree):
     """Zero a drained interval generation IN PLACE (buffer donation —
     the SNIPPETS pjit donation vectors): the returned fresh generation
@@ -73,7 +75,8 @@ def _zeros_like_donated_on(device):
     on device 0 and the next flush's cross-shard stack would reject the
     duplicate placement."""
     return jax.jit(
-        lambda tree: jax.tree.map(jnp.zeros_like, tree),
+        device_scope("reset", "any")(
+            lambda tree: jax.tree.map(jnp.zeros_like, tree)),
         donate_argnums=0,
         out_shardings=jax.sharding.SingleDeviceSharding(device))
 
@@ -89,6 +92,7 @@ def _zeros_like_spare(captured):
 
 
 @partial(jax.jit, donate_argnums=0)
+@device_scope("reset", "histogram")
 def _reset_tdigest_donated(state):
     """Donated t-digest generation reset: rebuilds init_state's values
     (±inf min/max, zero grids) in the donated buffers."""
@@ -100,7 +104,8 @@ def _reset_tdigest_donated_on(device):
     # same device pin as _zeros_like_donated_on: init_state's values
     # are constants, so the output needs an explicit placement
     return jax.jit(
-        lambda st: batch_tdigest.init_state(st["wv"].shape[0]),
+        device_scope("reset", "histogram")(
+            lambda st: batch_tdigest.init_state(st["wv"].shape[0])),
         donate_argnums=0,
         out_shardings=jax.sharding.SingleDeviceSharding(device))
 
@@ -190,6 +195,11 @@ class _BaseTable:
         self.tombstoned_total = 0
         self.recycled_total = 0
         self.dispatch_total = 0
+        # the batch apply's wall (jit dispatch; on a backend that runs
+        # it inline, the kernel too) and the wait for apply_lock before
+        # it: the flush's swap and readout dispatch hold that lock
+        self.apply_seconds_total = 0.0
+        self.apply_lock_wait_seconds_total = 0.0
         self.resize_total = 0
         self.resize_seconds_total = 0.0
         self.resize_last_seconds = 0.0
@@ -279,33 +289,41 @@ class _BaseTable:
         cols = self._swap_locked()
         if cols is None:
             return
+        t_wait = time.perf_counter()
         self.apply_lock.acquire()
+        t0 = time.perf_counter()
+        self.apply_lock_wait_seconds_total += t0 - t_wait
         self.lock.release()
         try:
-            if self._recompile_pending:
-                # first batch apply after a capacity doubling: the jit
-                # kernels retrace+recompile for the new shape here. Time
-                # it (block once — compile is the cost being measured)
-                # so the TPU-specific resize tax is attributable.
-                self._recompile_pending = False
-                t0 = time.perf_counter()
+            # first batch apply after a capacity doubling: the jit
+            # kernels retrace+recompile for the new shape here. Time it
+            # (block once — compile is the cost being measured) so the
+            # TPU-specific resize tax is attributable.
+            recompile = self._recompile_pending
+            self._recompile_pending = False
+            with annotate("apply." + self.family):
                 self._apply_cols(cols)
-                # sharded tables keep per-device state in `states`
-                dev_state = getattr(self, "state",
-                                    getattr(self, "states", None))
-                if dev_state is not None:
-                    try:
-                        jax.block_until_ready(jax.tree.leaves(dev_state))
-                    except Exception:
-                        logger.exception(
-                            "post-resize recompile sync failed")
-                elapsed = time.perf_counter() - t0
+                if recompile:
+                    # sharded tables keep per-device state in `states`
+                    dev_state = getattr(self, "state",
+                                        getattr(self, "states", None))
+                    if dev_state is not None:
+                        try:
+                            jax.block_until_ready(
+                                jax.tree.leaves(dev_state))
+                        except Exception:
+                            logger.exception(
+                                "post-resize recompile sync failed")
+            elapsed = time.perf_counter() - t0
+            self.apply_seconds_total += elapsed
+            obs = self._deviceobs
+            if obs is not None:
+                obs.note_kernel("apply", self.family, elapsed)
+            if recompile:
                 self.recompile_last_seconds = elapsed
                 self.recompile_seconds_total += elapsed
-                obs = self._deviceobs
                 if obs is not None:
                     obs.note_compile(self.family, elapsed)
-                    obs.note_kernel("apply", self.family, elapsed)
                 hook = self.on_resize
                 if hook is not None:
                     try:
@@ -314,15 +332,6 @@ class _BaseTable:
                              prewarmed=self.capacity in self._prewarmed_caps)
                     except Exception:
                         logger.exception("resize hook failed")
-            else:
-                obs = self._deviceobs
-                if obs is not None:
-                    t0 = time.perf_counter()
-                    self._apply_cols(cols)
-                    obs.note_kernel("apply", self.family,
-                                    time.perf_counter() - t0)
-                else:
-                    self._apply_cols(cols)
             self.dispatch_total += 1
         finally:
             self.apply_lock.release()
@@ -2202,6 +2211,10 @@ class ColumnStore:
                          t.recompile_last_seconds, tags))
             rows.append(("columnstore.batch_dispatch_total", "counter",
                          float(t.dispatch_total), tags))
+            rows.append(("ingest.apply.seconds_total", "counter",
+                         t.apply_seconds_total, tags))
+            rows.append(("ingest.apply.lock_wait_seconds_total", "counter",
+                         t.apply_lock_wait_seconds_total, tags))
             pending = getattr(t, "_n", None)
             if pending is not None:  # statuses have no batch buffers
                 rows.append(("columnstore.batch_cap", "gauge",

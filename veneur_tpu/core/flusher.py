@@ -18,6 +18,7 @@ Semantic parity with reference flusher.go:26-122 and samplers.go:359-514:
 
 from __future__ import annotations
 
+import contextlib
 import math
 import threading
 import time
@@ -27,6 +28,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from veneur_tpu.core.columnstore import ColumnStore, RowMeta
+from veneur_tpu.core.telemetry import FlushRound
 from veneur_tpu.samplers import metrics as m
 from veneur_tpu.samplers.metrics import (
     Aggregate, HistogramAggregates, InterMetric, MetricScope, MetricType,
@@ -435,6 +437,10 @@ class FlushBatch:
         self.sections = sections
         self.bucket_sections: List[BucketSection] = bucket_sections or []
         self.extras = extras  # statuses: carry message/hostname fields
+        # the span source of the round that delivers this batch: the
+        # server sets its own before the sink threads start, and a sink
+        # times its encode and sends into it
+        self.timing = FlushRound()
         self._materialized: Optional[List[InterMetric]] = None
         self._mat_lock = threading.Lock()
 
@@ -513,7 +519,7 @@ def swap_columnstore(
     is_local: bool,
     percentiles: Sequence[float],
     collect_forward: bool = True,
-    timings: Optional[dict] = None,
+    timing: Optional[FlushRound] = None,
 ) -> dict:
     """Critical-path half of the columnar flush: swap every family's
     pending columns and device-state generation out at ONE interval
@@ -523,8 +529,15 @@ def swap_columnstore(
     swapped snapshot is private to the readout and can be drained on a
     background executor (`readout_columnstore`). The host-dominant
     families (statuses) snapshot in full here so every family shares
-    the same boundary."""
-    t0 = time.perf_counter()
+    the same boundary. `timing`, when given, takes the `swap` span."""
+    with (timing or FlushRound()).phase("swap", parent="store_flush"):
+        return _swap_columnstore(store, is_local, percentiles,
+                                 collect_forward)
+
+
+def _swap_columnstore(store: ColumnStore, is_local: bool,
+                      percentiles: Sequence[float],
+                      collect_forward: bool) -> dict:
     full_ps = tuple(percentiles)
     all_ps = tuple(sorted(set(full_ps) | {0.5}))
     need_export = is_local and collect_forward
@@ -547,8 +560,6 @@ def swap_columnstore(
     swap["rows"] = int(sum(
         np.count_nonzero(swap[f].get("touched", ()))
         for f in ("histogram", "counter", "gauge", "llhist", "set")))
-    if timings is not None:
-        timings["swap_s"] = time.perf_counter() - t0
     return swap
 
 
@@ -558,7 +569,7 @@ def readout_columnstore(
     is_local: bool,
     aggregates: HistogramAggregates,
     collect_forward: bool = True,
-    timings: Optional[dict] = None,
+    timing: Optional[FlushRound] = None,
     attribute: bool = False,
 ) -> Tuple[FlushBatch, ForwardableState]:
     """Background half of the columnar flush: dispatch every swapped
@@ -567,16 +578,20 @@ def readout_columnstore(
     rules as the legacy path (the docstring at module top); touches no
     live table state (beyond telemetry counters and the donated-buffer
     recycle), so it runs concurrently with ingest and with the next
-    interval's accumulation. `timings`, when given, receives per-phase
-    wall seconds (dispatch / device_sync / assembly); with `attribute`
-    it additionally receives a `families` tree — per family the host
-    dispatch cost, per-device sync waits, and the host transfer cost,
-    with absolute start offsets so the flush span can grow matching
-    child spans. The attributed segments sum to the `dispatch_s` +
-    `device_sync_s` totals (pinned within 10% by tests/test_latency.py)."""
+    interval's accumulation. `timing`, when given, receives the spans
+    (all under a `readout` parent): one `dispatch` per family, back to
+    back, so their sum is `dispatch_s`; `device_sync` around the `sync`
+    and `transfer` spans; `assembly` around `recycle` and one
+    `assembly_<family>` per family block. With `attribute` every family
+    is synced on its own, device by device, so a sync stall is booked
+    to the family and device that caused it: that is the DEFAULT path of
+    a server (`latency_observatory: true`, `Server._run_readout`), and
+    `latency.family_tree` builds the round's `families` tree from its
+    spans. Without it everything still on the device is drained in one
+    `sync`: the path no benchmark cell has measured."""
     import jax
 
-    t0 = time.perf_counter()
+    timing = timing or FlushRound()
     now = swap["now"]
     fwd = ForwardableState()
     sections: List[FlushSection] = []
@@ -587,45 +602,39 @@ def readout_columnstore(
     full_bits = int(aggregates.value)
     local_code = int(MetricScope.LOCAL_ONLY)
     global_code = int(MetricScope.GLOBAL_ONLY)
-    fam_seg: Optional[Dict[str, dict]] = \
-        {} if (attribute and timings is not None) else None
     deviceobs = getattr(store, "deviceobs", None)
 
-    def _mark(family: str, start: float) -> float:
-        """Close one family's dispatch segment; returns the next start."""
-        end = time.perf_counter()
+    @contextlib.contextmanager
+    def dispatching(family: str):
+        """One family's dispatch span."""
+        with timing.phase("dispatch", parent="readout",
+                          family=family) as span:
+            yield
         if deviceobs is not None and family != "status":
             # kernel-registry row: the waterfall's per-family dispatch_s
             # decomposed as a device.kernel.readout_s distribution
-            deviceobs.note_kernel("readout", family, end - start)
-        if fam_seg is not None:
-            fam_seg[family] = {"dispatch_s": end - start,
-                               "dispatch_start_s": start - t0,
-                               "transfer_s": 0.0, "devices": {}}
-        return end
+            deviceobs.note_kernel("readout", family, span["wall_s"])
 
     # ---- phase 1: dispatch every device flush, sync nothing ------------
-    # (per-family wall clocks: the dispatch segments are back-to-back,
-    # so their sum IS the dispatch_s total minus timer overhead)
-    tf = t0
-    h_snap = store.histos.readout(swap["histogram"])
-    tf = _mark("histogram", tf)
-    c_snap = store.counters.readout(swap["counter"])
-    tf = _mark("counter", tf)
-    g_snap = store.gauges.readout(swap["gauge"])
-    tf = _mark("gauge", tf)
-    ll_snap = store.llhists.readout(swap["llhist"])
-    tf = _mark("llhist", tf)
+    # (the per-family dispatch spans are back-to-back, so their sum IS
+    # the dispatch_s total)
+    with dispatching("histogram"):
+        h_snap = store.histos.readout(swap["histogram"])
+    with dispatching("counter"):
+        c_snap = store.counters.readout(swap["counter"])
+    with dispatching("gauge"):
+        g_snap = store.gauges.readout(swap["gauge"])
+    with dispatching("llhist"):
+        ll_snap = store.llhists.readout(swap["llhist"])
     # sets are host-dominant (the sparse set path only touches the
     # device when rows promoted this interval): the estimate realizes
     # eagerly inside readout
-    set_snap = store.sets.readout(swap["set"])
-    estimates, registers, s_touched, s_meta = \
-        store.sets.snapshot_finish(set_snap)
-    tf = _mark("set", tf)
-    st_vals, st_touched, st_meta = swap["status"]
-    _mark("status", tf)
-    t_dispatch = time.perf_counter()
+    with dispatching("set"):
+        set_snap = store.sets.readout(swap["set"])
+        estimates, registers, s_touched, s_meta = \
+            store.sets.snapshot_finish(set_snap)
+    with dispatching("status"):
+        st_vals, st_touched, st_meta = swap["status"]
 
     # ---- phase 2: drain the device queue, then transfer ----------------
     h_handles = [h_snap["packed"]]
@@ -644,44 +653,41 @@ def readout_columnstore(
          lambda: store.llhists.snapshot_finish(ll_snap)),
     )
     finished = {}
-    if fam_seg is None:
-        # one queue drain for everything still on device
-        jax.block_until_ready([h for _f, hs, _fn in family_finishes
-                               for h in hs])
+    # the attributed path syncs each family's handles device by device;
+    # grouping them is host work, done before the clock of device_sync
+    by_device = ({family: _handles_by_device(handles)
+                  for family, handles, _fn in family_finishes}
+                 if attribute else {})
+    with timing.phase("device_sync", parent="readout"):
+        if not attribute:
+            # one queue drain for everything still on device
+            with timing.phase("sync", parent="device_sync"):
+                jax.block_until_ready([h for _f, hs, _fn in family_finishes
+                                       for h in hs])
         for family, _handles, finish in family_finishes:
-            finished[family] = finish()
-    else:
-        # per-family, per-device sync + host transfer, each timed. Any
-        # residual (device grouping, numpy view setup) is attributed to
-        # the family's transfer segment so the segments still sum to
-        # the device_sync_s total.
-        for family, handles, finish in family_finishes:
-            f_start = time.perf_counter()
-            rec = fam_seg[family]
-            rec["device_start_s"] = f_start - t0
-            synced = 0.0
-            for dev, dev_handles in _handles_by_device(handles).items():
-                s0 = time.perf_counter()
-                jax.block_until_ready(dev_handles)
-                ds = time.perf_counter() - s0
-                rec["devices"][dev] = {"sync_s": ds}
-                synced += ds
-            finished[family] = finish()
-            rec["transfer_s"] = time.perf_counter() - f_start - synced
+            if attribute:
+                for dev, dev_handles in by_device[family].items():
+                    with timing.phase("sync", parent="device_sync",
+                                      family=family, device=dev):
+                        jax.block_until_ready(dev_handles)
+            with timing.phase("transfer", parent="device_sync",
+                              family=family):
+                finished[family] = finish()
     c_vals, c_touched, c_meta = finished["counter"]
     g_vals, g_touched, g_meta = finished["gauge"]
     out, export, h_touched, h_meta = finished["histogram"]
-    t_sync = time.perf_counter()
+    assembly = timing.phase("assembly", parent="readout").start()
     # transfers done: donate the drained generations back as the next
     # interval's spares (the second buffer of each family's
     # double-buffer; no-op for snaps whose state escaped — sparse
     # sets). Booked in the assembly phase: the zeroing dispatches are
     # async and off the segment-attribution pin.
-    store.counters.recycle(c_snap)
-    store.gauges.recycle(g_snap)
-    store.histos.recycle(h_snap)
-    store.llhists.recycle(ll_snap)
-    store.sets.recycle(set_snap)
+    with timing.phase("recycle", parent="assembly"):
+        store.counters.recycle(c_snap)
+        store.gauges.recycle(g_snap)
+        store.histos.recycle(h_snap)
+        store.llhists.recycle(ll_snap)
+        store.sets.recycle(set_snap)
 
     # ---- counters & gauges ---------------------------------------------
     def scalar_family(table, vals, touched, meta_list, mtype, fwd_list):
@@ -704,116 +710,121 @@ def readout_columnstore(
                 table.flush_names("", rows, meta_list, lambda m: m.name),
                 vals_sel, table.flush_tags(rows, meta_list), mtype))
 
-    scalar_family(store.counters, c_vals, c_touched, c_meta,
-                  MetricType.COUNTER, fwd.counters)
-    scalar_family(store.gauges, g_vals, g_touched, g_meta,
-                  MetricType.GAUGE, fwd.gauges)
+    with timing.phase("assembly_scalar", parent="assembly"):
+        scalar_family(store.counters, c_vals, c_touched, c_meta,
+                      MetricType.COUNTER, fwd.counters)
+        scalar_family(store.gauges, g_vals, g_touched, g_meta,
+                      MetricType.GAUGE, fwd.gauges)
 
     # ---- histograms & timers -------------------------------------------
-    hr = _valid_rows(h_touched, h_meta)
-    if hr.size:
-        htab = store.histos
-        scope = htab.scope_code[hr]
-        local_only = scope == local_code
-        global_only = scope == global_code
-        # server_aggs == aggregates (flusher.go:360-371 passes the
-        # configured set unconditionally), so the only per-scope bits
-        # variation is global-only rows emitting nothing on a local server
-        a_on = np.where(global_only & is_local, 0, full_bits)
-        use_global = global_only & (not is_local)
-        emit_ps = local_only | (not is_local)
+    with timing.phase("assembly_histogram", parent="assembly"):
+        hr = _valid_rows(h_touched, h_meta)
+        if hr.size:
+            htab = store.histos
+            scope = htab.scope_code[hr]
+            local_only = scope == local_code
+            global_only = scope == global_code
+            # server_aggs == aggregates (flusher.go:360-371 passes the
+            # configured set unconditionally), so the only per-scope bits
+            # variation is global-only rows emitting nothing on a local server
+            a_on = np.where(global_only & is_local, 0, full_bits)
+            use_global = global_only & (not is_local)
+            emit_ps = local_only | (not is_local)
 
-        cols = {k: np.asarray(out[k], np.float64)[hr]
-                for k in ("lmin", "lmax", "lsum", "lweight", "lrecip",
-                          "min", "max", "sum", "count", "hmean")}
-        quants = np.asarray(out["quantiles"], np.float64)[hr]
-        # one tag-cache pass for every histo section; sections slice it
-        tags_hr = htab.flush_tags(hr, h_meta)
+            cols = {k: np.asarray(out[k], np.float64)[hr]
+                    for k in ("lmin", "lmax", "lsum", "lweight", "lrecip",
+                              "min", "max", "sum", "count", "hmean")}
+            quants = np.asarray(out["quantiles"], np.float64)[hr]
+            # one tag-cache pass for every histo section; sections slice it
+            tags_hr = htab.flush_tags(hr, h_meta)
 
-        def agg_section(suffix, mask, values, mtype=MetricType.GAUGE):
-            if not mask.any():
-                return
-            sections.append(FlushSection(
-                htab.flush_names(
-                    suffix, hr[mask], h_meta,
-                    lambda m, s=suffix: f"{m.name}.{s}"),
-                values[mask], tags_hr[mask], mtype))
-
-        lmin, lmax = cols["lmin"], cols["lmax"]
-        lsum, lweight, lrecip = cols["lsum"], cols["lweight"], cols["lrecip"]
-        dmin, dmax = cols["min"], cols["max"]
-        dsum, dcount = cols["sum"], cols["count"]
-        with np.errstate(divide="ignore", invalid="ignore"):
-            avg = np.where(use_global, dsum / np.where(dcount, dcount, 1.0),
-                           lsum / np.where(lweight, lweight, 1.0))
-            hmean = np.where(use_global, cols["hmean"],
-                             lweight / np.where(lrecip, lrecip, 1.0))
-        agg_section("max", ((a_on & _A_MAX) != 0)
-                    & (~np.isinf(lmax) | use_global),
-                    np.where(use_global, dmax, lmax))
-        agg_section("min", ((a_on & _A_MIN) != 0)
-                    & (~np.isinf(lmin) | use_global),
-                    np.where(use_global, dmin, lmin))
-        agg_section("sum", ((a_on & _A_SUM) != 0)
-                    & ((lsum != 0) | use_global),
-                    np.where(use_global, dsum, lsum))
-        agg_section("avg", ((a_on & _A_AVERAGE) != 0)
-                    & (use_global | ((lsum != 0) & (lweight != 0))), avg)
-        agg_section("count", ((a_on & _A_COUNT) != 0)
-                    & ((lweight != 0) | use_global),
-                    np.where(use_global, dcount, lweight),
-                    MetricType.COUNTER)
-        agg_section("median", (a_on & _A_MEDIAN) != 0,
-                    quants[:, ps_index[0.5]])
-        agg_section("hmean", ((a_on & _A_HMEAN) != 0)
-                    & (use_global | ((lrecip != 0) & (lweight != 0))),
-                    hmean)
-
-        if full_ps and emit_ps.any():
-            pr = hr[emit_ps]
-            pq = quants[emit_ps]
-            ptags = tags_hr[emit_ps]
-            for p in full_ps:
+            def agg_section(suffix, mask, values, mtype=MetricType.GAUGE):
+                if not mask.any():
+                    return
                 sections.append(FlushSection(
                     htab.flush_names(
-                        p, pr, h_meta,
-                        lambda m, p=p: _percentile_name(m.name, p)),
-                    pq[:, ps_index[p]], ptags, MetricType.GAUGE))
+                        suffix, hr[mask], h_meta,
+                        lambda m, s=suffix: f"{m.name}.{s}"),
+                    values[mask], tags_hr[mask], mtype))
 
-        if need_export:
-            exp_means, exp_weights, exp_min, exp_max, exp_recip = export
-            fr = hr[~local_only]
-            if fr.size:
-                # one bulk fancy-index copy into a COMPACT matrix, then
-                # row views into it: per-row .copy() was pure overhead on
-                # the forward config's flush path, but views into the
-                # full (K, 2C+3) export would pin ~capacity-sized memory
-                # for the lifetime of the async forward send
-                cm, cw = exp_means[fr], exp_weights[fr]
-                cmin, cmax = exp_min[fr], exp_max[fr]
-                crecip = exp_recip[fr]
-                for j, row in enumerate(fr.tolist()):
-                    fwd.histograms.append((
-                        h_meta[row], cm[j], cw[j], float(cmin[j]),
-                        float(cmax[j]), float(crecip[j])))
+            lmin, lmax = cols["lmin"], cols["lmax"]
+            lsum, lweight = cols["lsum"], cols["lweight"]
+            lrecip = cols["lrecip"]
+            dmin, dmax = cols["min"], cols["max"]
+            dsum, dcount = cols["sum"], cols["count"]
+            with np.errstate(divide="ignore", invalid="ignore"):
+                avg = np.where(use_global,
+                               dsum / np.where(dcount, dcount, 1.0),
+                               lsum / np.where(lweight, lweight, 1.0))
+                hmean = np.where(use_global, cols["hmean"],
+                                 lweight / np.where(lrecip, lrecip, 1.0))
+            agg_section("max", ((a_on & _A_MAX) != 0)
+                        & (~np.isinf(lmax) | use_global),
+                        np.where(use_global, dmax, lmax))
+            agg_section("min", ((a_on & _A_MIN) != 0)
+                        & (~np.isinf(lmin) | use_global),
+                        np.where(use_global, dmin, lmin))
+            agg_section("sum", ((a_on & _A_SUM) != 0)
+                        & ((lsum != 0) | use_global),
+                        np.where(use_global, dsum, lsum))
+            agg_section("avg", ((a_on & _A_AVERAGE) != 0)
+                        & (use_global | ((lsum != 0) & (lweight != 0))), avg)
+            agg_section("count", ((a_on & _A_COUNT) != 0)
+                        & ((lweight != 0) | use_global),
+                        np.where(use_global, dcount, lweight),
+                        MetricType.COUNTER)
+            agg_section("median", (a_on & _A_MEDIAN) != 0,
+                        quants[:, ps_index[0.5]])
+            agg_section("hmean", ((a_on & _A_HMEAN) != 0)
+                        & (use_global | ((lrecip != 0) & (lweight != 0))),
+                        hmean)
+
+            if full_ps and emit_ps.any():
+                pr = hr[emit_ps]
+                pq = quants[emit_ps]
+                ptags = tags_hr[emit_ps]
+                for p in full_ps:
+                    sections.append(FlushSection(
+                        htab.flush_names(
+                            p, pr, h_meta,
+                            lambda m, p=p: _percentile_name(m.name, p)),
+                        pq[:, ps_index[p]], ptags, MetricType.GAUGE))
+
+            if need_export:
+                exp_means, exp_weights, exp_min, exp_max, exp_recip = export
+                fr = hr[~local_only]
+                if fr.size:
+                    # one bulk fancy-index copy into a COMPACT matrix, then
+                    # row views into it: per-row .copy() was pure overhead on
+                    # the forward config's flush path, but views into the
+                    # full (K, 2C+3) export would pin ~capacity-sized memory
+                    # for the lifetime of the async forward send
+                    cm, cw = exp_means[fr], exp_weights[fr]
+                    cmin, cmax = exp_min[fr], exp_max[fr]
+                    crecip = exp_recip[fr]
+                    for j, row in enumerate(fr.tolist()):
+                        fwd.histograms.append((
+                            h_meta[row], cm[j], cw[j], float(cmin[j]),
+                            float(cmax[j]), float(crecip[j])))
 
     # ---- sets -----------------------------------------------------------
-    sr = _valid_rows(s_touched, s_meta)
-    if sr.size:
-        stab = store.sets
-        s_local = stab.scope_code[sr] == local_code
-        if is_local:
-            if collect_forward:
-                for row in sr[~s_local].tolist():
-                    fwd.sets.append((s_meta[row], registers[row].copy()))
-            er = sr[s_local]
-        else:
-            er = sr
-        if er.size:
-            sections.append(FlushSection(
-                stab.flush_names("", er, s_meta, lambda m: m.name),
-                np.asarray(estimates, np.float64)[er],
-                stab.flush_tags(er, s_meta), MetricType.GAUGE))
+    with timing.phase("assembly_set", parent="assembly"):
+        sr = _valid_rows(s_touched, s_meta)
+        if sr.size:
+            stab = store.sets
+            s_local = stab.scope_code[sr] == local_code
+            if is_local:
+                if collect_forward:
+                    for row in sr[~s_local].tolist():
+                        fwd.sets.append((s_meta[row], registers[row].copy()))
+                er = sr[s_local]
+            else:
+                er = sr
+            if er.size:
+                sections.append(FlushSection(
+                    stab.flush_names("", er, s_meta, lambda m: m.name),
+                    np.asarray(estimates, np.float64)[er],
+                    stab.flush_tags(er, s_meta), MetricType.GAUGE))
 
     # ---- log-linear histograms ------------------------------------------
     # percentiles/sum/count columnarize like every other family; the
@@ -821,58 +832,59 @@ def readout_columnstore(
     # vectorized cumsum over the value-sorted bin table plus a nonzero
     # mask, exploded per-row only by materialize() and the legacy
     # `_flush_llhist_family` oracle (parity pinned by tests)
-    extras: List[InterMetric] = []
-    bucket_sections: List[BucketSection] = []
-    ll_out, ll_bins, ll_touched, ll_meta = finished["llhist"]
-    llr = np.flatnonzero(ll_touched)
-    if llr.size:
-        from veneur_tpu.ops import llhist_ref
+    with timing.phase("assembly_llhist", parent="assembly"):
+        extras: List[InterMetric] = []
+        bucket_sections: List[BucketSection] = []
+        ll_out, ll_bins, ll_touched, ll_meta = finished["llhist"]
+        llr = np.flatnonzero(ll_touched)
+        if llr.size:
+            from veneur_tpu.ops import llhist_ref
 
-        lltab = store.llhists
-        # ll_bins is compact over the touched rows in `llr` order; keep
-        # the compact index aligned while dropping reclaim stragglers
-        keep = np.fromiter((ll_meta[r] is not None for r in llr.tolist()),
-                           bool, llr.size)
-        llr, bins_sel = llr[keep], ll_bins[keep]
-        emit = np.ones(llr.size, bool)
-        if is_local and llr.size:
-            fwd_mask = lltab.scope_code[llr] != local_code
-            if fwd_mask.any():
-                if need_export:
-                    for j, row in zip(np.flatnonzero(fwd_mask).tolist(),
-                                      llr[fwd_mask].tolist()):
-                        fwd.llhists.append((ll_meta[row], bins_sel[j]))
-                emit = ~fwd_mask
-        er = llr[emit]
-        if er.size:
-            ebins = bins_sel[emit]
-            quants = np.asarray(ll_out["quantiles"], np.float64)[er]
-            tags_er = lltab.flush_tags(er, ll_meta)
-            for j, p in enumerate(full_ps):
+            lltab = store.llhists
+            # ll_bins is compact over the touched rows in `llr` order; keep
+            # the compact index aligned while dropping reclaim stragglers
+            keep = np.fromiter((ll_meta[r] is not None for r in llr.tolist()),
+                               bool, llr.size)
+            llr, bins_sel = llr[keep], ll_bins[keep]
+            emit = np.ones(llr.size, bool)
+            if is_local and llr.size:
+                fwd_mask = lltab.scope_code[llr] != local_code
+                if fwd_mask.any():
+                    if need_export:
+                        for j, row in zip(np.flatnonzero(fwd_mask).tolist(),
+                                          llr[fwd_mask].tolist()):
+                            fwd.llhists.append((ll_meta[row], bins_sel[j]))
+                    emit = ~fwd_mask
+            er = llr[emit]
+            if er.size:
+                ebins = bins_sel[emit]
+                quants = np.asarray(ll_out["quantiles"], np.float64)[er]
+                tags_er = lltab.flush_tags(er, ll_meta)
+                for j, p in enumerate(full_ps):
+                    sections.append(FlushSection(
+                        lltab.flush_names(
+                            p, er, ll_meta,
+                            lambda m, p=p: _percentile_name(m.name, p)),
+                        quants[:, j], tags_er, MetricType.GAUGE))
+                # count and sum from the HOST-side int64 bins (see the
+                # legacy helper: count must equal the le:+Inf bucket)
                 sections.append(FlushSection(
-                    lltab.flush_names(
-                        p, er, ll_meta,
-                        lambda m, p=p: _percentile_name(m.name, p)),
-                    quants[:, j], tags_er, MetricType.GAUGE))
-            # count and sum from the HOST-side int64 bins (see the
-            # legacy helper: count must equal the le:+Inf bucket)
-            sections.append(FlushSection(
-                lltab.flush_names("sum", er, ll_meta,
-                                  lambda m: f"{m.name}.sum"),
-                ebins.astype(np.float64) @ llhist_ref.BIN_MID,
-                tags_er, MetricType.GAUGE))
-            sections.append(FlushSection(
-                lltab.flush_names("count", er, ll_meta,
-                                  lambda m: f"{m.name}.count"),
-                ebins.sum(axis=1).astype(np.float64),
-                tags_er, MetricType.COUNTER))
-            c_sorted = ebins[:, llhist_ref.ORDER]
-            bucket_sections.append(BucketSection(
-                lltab.flush_names("bucket", er, ll_meta,
-                                  lambda m: f"{m.name}.bucket"),
-                tags_er,
-                np.cumsum(c_sorted, axis=1, dtype=np.float64),
-                c_sorted != 0))
+                    lltab.flush_names("sum", er, ll_meta,
+                                      lambda m: f"{m.name}.sum"),
+                    ebins.astype(np.float64) @ llhist_ref.BIN_MID,
+                    tags_er, MetricType.GAUGE))
+                sections.append(FlushSection(
+                    lltab.flush_names("count", er, ll_meta,
+                                      lambda m: f"{m.name}.count"),
+                    ebins.sum(axis=1).astype(np.float64),
+                    tags_er, MetricType.COUNTER))
+                c_sorted = ebins[:, llhist_ref.ORDER]
+                bucket_sections.append(BucketSection(
+                    lltab.flush_names("bucket", er, ll_meta,
+                                      lambda m: f"{m.name}.bucket"),
+                    tags_er,
+                    np.cumsum(c_sorted, axis=1, dtype=np.float64),
+                    c_sorted != 0))
 
     # ---- status checks --------------------------------------------------
     for row in np.flatnonzero(st_touched).tolist():
@@ -885,20 +897,9 @@ def readout_columnstore(
             tags=list(meta.tags), type=MetricType.STATUS,
             message=entry.message, hostname=entry.hostname))
 
-    if timings is not None:
-        t_end = time.perf_counter()
-        timings["dispatch_s"] = t_dispatch - t0
-        timings["device_sync_s"] = t_sync - t_dispatch
-        timings["assembly_s"] = t_end - t_sync
-        if fam_seg is not None:
-            timings["families"] = fam_seg
-        if store.shard_plane is not None:
-            # mesh topology alongside the phase numbers (a dict, so the
-            # per-phase statsd emission loop skips it): the bench's
-            # mesh-scaling scenario and the waterfall view read the
-            # shard width the measured flush actually merged over
-            timings["mesh"] = store.shard_plane.describe()
-    return FlushBatch(now, sections, extras, bucket_sections), fwd
+    batch = FlushBatch(now, sections, extras, bucket_sections)
+    assembly.stop()
+    return batch, fwd
 
 
 def flush_columnstore_batch(
@@ -907,7 +908,7 @@ def flush_columnstore_batch(
     percentiles: Sequence[float],
     aggregates: HistogramAggregates,
     collect_forward: bool = True,
-    timings: Optional[dict] = None,
+    timing: Optional[FlushRound] = None,
     attribute: bool = False,
 ) -> Tuple[FlushBatch, ForwardableState]:
     """Synchronous columnar flush: swap + readout in one call (the
@@ -917,7 +918,7 @@ def flush_columnstore_batch(
     parity tests pin the two equal."""
     swap = swap_columnstore(store, is_local, percentiles,
                             collect_forward=collect_forward,
-                            timings=timings)
+                            timing=timing)
     return readout_columnstore(store, swap, is_local, aggregates,
                                collect_forward=collect_forward,
-                               timings=timings, attribute=attribute)
+                               timing=timing, attribute=attribute)

@@ -20,12 +20,14 @@ from __future__ import annotations
 import logging
 import random
 import threading
+import time
 from typing import Optional
 
 import numpy as np
 
 from veneur_tpu import native
 from veneur_tpu.core import batchdecode
+from veneur_tpu.core.telemetry import annotate
 from veneur_tpu.samplers import metrics as m
 
 logger = logging.getLogger("veneur_tpu.ingest")
@@ -650,9 +652,21 @@ class BatchIngester(_ColumnarIngesterBase):
 
     def _dispatch_one(self, pump, server, timeout_ms: int,
                       ring_hists=None) -> bool:
+        """One sealed chunk into the store. Books the dispatcher's
+        account on the pump, once per chunk: the wall it waited in
+        `pump.next` and this thread's CPU (its busy wall is the window
+        less the wait), the `ingest.dispatch.*` rows of /metrics."""
+        cpu0, t0 = time.thread_time(), time.perf_counter()
         chunk = pump.next(timeout_ms)
+        pump.dispatch_wait_s += time.perf_counter() - t0
         if chunk is None:
             return False
+        with annotate("ingest.dispatch"):
+            self._dispatch_chunk(pump, server, chunk, ring_hists)
+        pump.dispatch_cpu_s += time.thread_time() - cpu0
+        return True
+
+    def _dispatch_chunk(self, pump, server, chunk, ring_hists) -> None:
         # sample-age stamp: the closest Python point to the C++ socket
         # read (readers seal within seal_age_ms of the first sample)
         server.latency.note_arrival("dogstatsd",
@@ -681,4 +695,3 @@ class BatchIngester(_ColumnarIngesterBase):
         if stalls != seen:
             server.stats.inc("ingest_pump_stalls", stalls - seen)
             pump._stalls_seen = stalls
-        return True

@@ -8,8 +8,9 @@ or sink owns the time. This module is the attribution layer:
 
 - **Dispatch attribution** — the flusher (core/flusher.py) times every
   family's device flush separately (dispatch / per-device
-  `block_until_ready` sync / host transfer) and records the breakdown
-  into the flush round's `families` tree; `/debug/flush?waterfall=1`
+  `block_until_ready` sync / host transfer) as spans of the round
+  (`telemetry.FlushRound`), and `family_tree` folds those into the
+  flush round's `families` tree; `/debug/flush?waterfall=1`
   renders the last N rounds as segment trees whose segments sum to the
   recorded `dispatch_s` + `device_sync_s` totals. Retraces (the first
   post-resize batch apply, per the PR-4 recompile telemetry) are
@@ -445,6 +446,32 @@ class LatencyObservatory:
 
 
 # -- flush waterfall -------------------------------------------------------
+
+def family_tree(readout) -> dict:
+    """One readout's per-family segment tree, from its spans
+    (`telemetry.FlushRound`, as `flusher.readout_columnstore` fills it
+    with `attribute`): per family the `dispatch` span, the `sync` span
+    of each device and the `transfer` span, and the wall-clock window
+    from the start of its dispatch to the end of its transfer
+    (`start_unix`, `end_unix`: what the `flush.family` SSF span is
+    stamped with)."""
+    tree: Dict[str, dict] = {}
+    for span in list(readout.spans):
+        family = span.get("family")
+        if family is None:
+            continue
+        start = readout.start_unix + span["start_s"]
+        rec = tree.setdefault(family, {
+            "dispatch_s": 0.0, "transfer_s": 0.0, "devices": {},
+            "start_unix": start, "end_unix": start})
+        rec["start_unix"] = min(rec["start_unix"], start)
+        rec["end_unix"] = max(rec["end_unix"], start + span["wall_s"])
+        if span["name"] == "sync":
+            rec["devices"][span["device"]] = {"sync_s": span["wall_s"]}
+        else:
+            rec[span["name"] + "_s"] = span["wall_s"]
+    return tree
+
 
 def family_segments_sum(families: dict) -> float:
     """Sum of every attributed segment in one round's family tree —

@@ -119,15 +119,26 @@ class KernelDropMonitor:
     immune to REUSEPORT port sharing). The exported value is the summed
     per-socket delta since watching began, so a listener restart never
     double-counts. Off Linux (no /proc/net/udp) the monitor is inert.
+
+    Beside the sockets' own rows it reads the HOST's `Udp: RcvbufErrors`
+    of /proc/net/snmp (datagrams that found a receive buffer full, on any
+    UDP socket of the network namespace, since watching began): a
+    sandboxed network stack that lists no socket in /proc/net/udp (gVisor:
+    the machines the benchmark runs on) still keeps that count, so
+    `ingest.kernel_drops` reads 0 there whatever is lost and
+    `ingest.udp_rcvbuf_errors` is the row that moves.
     """
 
     PROC_FILES = ("/proc/net/udp", "/proc/net/udp6")
+    SNMP_FILE = "/proc/net/snmp"
 
     def __init__(self):
         self._lock = threading.Lock()
         # inode -> [label, baseline (first-seen drops), last-seen drops]
         self._watched: Dict[int, list] = {}
         self._totals: Dict[str, int] = {}  # label -> accumulated delta
+        self._rcvbuf_baseline: Optional[int] = None
+        self.rcvbuf_errors = 0  # host-wide, since the first poll
 
     @property
     def watching(self) -> bool:
@@ -159,6 +170,32 @@ class KernelDropMonitor:
                 continue
         return out
 
+    @staticmethod
+    def parse_proc_snmp(text: str) -> Optional[int]:
+        """`/proc/net/snmp` -> the `Udp:` table's RcvbufErrors (a header
+        row of names, then a row of values); None where it has none."""
+        rows = [ln.split() for ln in text.splitlines()
+                if ln.startswith("Udp:")]
+        if len(rows) < 2 or "RcvbufErrors" not in rows[0]:
+            return None
+        try:
+            return int(rows[1][rows[0].index("RcvbufErrors")])
+        except (IndexError, ValueError):
+            return None
+
+    def _poll_rcvbuf_errors(self) -> None:
+        try:
+            with open(self.SNMP_FILE) as f:
+                errors = self.parse_proc_snmp(f.read())
+        except OSError:
+            return
+        if errors is None:
+            return
+        with self._lock:
+            if self._rcvbuf_baseline is None:
+                self._rcvbuf_baseline = errors  # earlier loss is not ours
+            self.rcvbuf_errors = max(0, errors - self._rcvbuf_baseline)
+
     def _read_proc(self) -> Dict[int, int]:
         merged: Dict[int, int] = {}
         for path in self.PROC_FILES:
@@ -174,6 +211,7 @@ class KernelDropMonitor:
         with self._lock:
             if not self._watched:
                 return 0
+        self._poll_rcvbuf_errors()
         by_inode = self._read_proc()
         fresh = 0
         with self._lock:
@@ -704,6 +742,9 @@ class OverloadManager:
         for label, n in sorted(self.kernel_drops.totals().items()):
             rows.append(("ingest.kernel_drops", "counter", float(n),
                          [f"listener:{label}"]))
+        if self.kernel_drops.watching:
+            rows.append(("ingest.udp_rcvbuf_errors", "counter",
+                         float(self.kernel_drops.rcvbuf_errors), ()))
         sup = self.supervisor
         stall_counts, probe_stalls = sup.counts_snapshot()
         for name, n in sorted(stall_counts.items()):

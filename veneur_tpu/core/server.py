@@ -21,6 +21,8 @@ from veneur_tpu import sinks as sinks_mod
 from veneur_tpu.config import Config, SinkConfig
 from veneur_tpu.core import networking
 from veneur_tpu.core.columnstore import ColumnStore
+from veneur_tpu.core.latency import family_tree
+from veneur_tpu.core.telemetry import FlushRound
 from veneur_tpu.core.flusher import (
     FlushBatch, ForwardableState, flush_columnstore_batch,
     readout_columnstore, swap_columnstore)
@@ -278,6 +280,7 @@ class Server:
         self.telemetry = telemetry_mod.Telemetry()
         self.telemetry.registry.add_collector(self._live_telemetry_rows)
         self.telemetry.registry.add_collector(self._ring_telemetry_rows)
+        self.telemetry.registry.add_collector(self._ingest_time_rows)
         self.telemetry.registry.add_collector(
             telemetry_mod.device_memory_rows)
 
@@ -715,10 +718,7 @@ class Server:
         queue.dwell llhists under the same ingest_ring names.)"""
         from veneur_tpu.core.ingest import addr_label
         rows = []
-        for listener in list(getattr(self, "_listeners", ()) or ()):
-            pump = getattr(listener, "pump", None)
-            if pump is None:
-                continue
+        for listener, pump in self._pumps():
             try:
                 depths, caps, sealed, stalls = pump.ring_stats()
             except Exception:
@@ -734,6 +734,39 @@ class Server:
                 rows.append(("ingest.ring.stalls_total", "counter",
                              float(stalls[i]), tags))
         return rows
+
+    def _ingest_time_rows(self):
+        """Scrape-time /metrics rows for the time the native ingest
+        path's threads spend: per reader thread its CPU seconds and the
+        seconds it was blocked on a full ring; over the dispatcher
+        threads, their CPU seconds and the seconds they waited for a
+        chunk (`core/ingest.py` books those on the pump, per chunk)."""
+        from veneur_tpu.core.ingest import addr_label
+        rows = []
+        pumps = self._pumps()
+        for listener, pump in pumps:
+            try:
+                cpu_s, stall_s = pump.reader_times()
+            except Exception:
+                continue
+            for i in range(len(cpu_s)):
+                tags = [f"reader:{addr_label(listener.address)}:{i}"]
+                rows.append(("ingest.reader.cpu_seconds_total", "counter",
+                             float(cpu_s[i]), tags))
+                rows.append(("ingest.reader.stall_seconds_total",
+                             "counter", float(stall_s[i]), tags))
+        if pumps:
+            rows.append(("ingest.dispatch.cpu_seconds_total", "counter",
+                         sum(p.dispatch_cpu_s for _l, p in pumps), ()))
+            rows.append(("ingest.dispatch.wait_seconds_total", "counter",
+                         sum(p.dispatch_wait_s for _l, p in pumps), ()))
+        return rows
+
+    def _pumps(self) -> list:
+        """(listener, its native pump) for every listener that has one."""
+        return [(listener, listener.pump)
+                for listener in list(getattr(self, "_listeners", ()) or ())
+                if getattr(listener, "pump", None) is not None]
 
     # -- spans -----------------------------------------------------------
 
@@ -1541,8 +1574,12 @@ class Server:
     def _flush_locked(self, deliver_only: bool = False) -> None:
         from veneur_tpu import trace as trace_mod
         from veneur_tpu.trace.store import trace_id_hex
-        flush_start = time.perf_counter()
-        self.last_flush_unix = time.time()
+        # the round's one span source: phases, spans and the profiler's
+        # annotations all come from rnd.phase(...)
+        rnd = FlushRound()
+        flush_phase = rnd.phase("flush").start()
+        preflush = rnd.phase("preflush", parent="flush").start()
+        self.last_flush_unix = rnd.start_unix
         # the interval this flush's snapshot covers began at the
         # previous flush boundary: the WAL stamps it onto the
         # forwardable snapshot so a replay lands under THIS interval
@@ -1598,6 +1635,8 @@ class Server:
             "start_unix": self.last_flush_unix,
             "mode": "local" if self.is_local else "global",
             "sinks": {},
+            # the live list: a straggler's spans land after the round
+            "spans": rnd.spans,
         }
         if traced:
             # cross-link: /debug/flush (and its waterfall view) point at
@@ -1661,7 +1700,7 @@ class Server:
                 return False
             t = threading.Thread(
                 target=self._timed_sink_flush,
-                args=(key, parent_span, span_traced, round_info,
+                args=(key, parent_span, span_traced, round_info, rnd,
                       target) + args,
                 daemon=True, name=f"flush-{key}")
             t.start()
@@ -1675,7 +1714,7 @@ class Server:
 
         # per-phase wall clock for flush-latency attribution; read by
         # the bench's sustained gate (one flush at a time: _flush_lock)
-        phases = self.flush_phase_timings = {}
+        phases = self.flush_phase_timings = rnd.phases
         # sample-age watermarks roll at the same boundary the column
         # store snapshots: everything stamped before this flush's
         # snapshot is aged through to sink ack below
@@ -1688,13 +1727,14 @@ class Server:
         async_on = (bool(self.config.flush_async)
                     and not self._shutdown.is_set()
                     and not deliver_only)
-        t_store = time.perf_counter()
+        preflush.stop()
+        store_flush = rnd.phase("store_flush", parent="flush").start()
         record = None
         if not deliver_only:
             swap = swap_columnstore(
                 self.store, self.is_local, self.percentiles,
                 collect_forward=self.forwarder is not None,
-                timings=phases)
+                timing=rnd)
             record = {
                 "swap": swap,
                 "flush": self.flush_count,
@@ -1712,7 +1752,7 @@ class Server:
         # wedged past READOUT_MISS_LIMIT ticks (or fails outright) is
         # dropped, loudly. Shutdown drains with the full timeout.
         from concurrent.futures import TimeoutError as _JoinTimeout
-        t_join = time.perf_counter()
+        join = rnd.phase("join", parent="store_flush").start()
         drain = deliver_only or self._shutdown.is_set()
         inflight = self._inflight_flushes
         delivered = []
@@ -1747,7 +1787,7 @@ class Server:
                 continue
             inflight.pop(0)
             delivered.append(head)
-        phases["join_s"] = time.perf_counter() - t_join
+        join.stop()
         inline_device_s = 0.0
         if deliver_only:
             pass  # shutdown drain: no new interval boundary is opened
@@ -1756,8 +1796,9 @@ class Server:
                 lambda rec=record: self._run_readout(rec))
             inflight.append(record)
         else:
-            record["result"] = self._run_readout(record)
-            r_phases = record["result"][2]
+            record["result"] = self._run_readout(record,
+                                                 parent="store_flush")
+            r_phases = record["result"][2].phases
             # device work that DID run inline this tick — subtracted
             # from the critical-path row below
             inline_device_s = sum(
@@ -1767,8 +1808,7 @@ class Server:
         # the ledger's overlap stock: touched rows across every swapped-
         # but-undelivered interval still in the pipeline
         self._inflight_rows = sum(r["swap"]["rows"] for r in inflight)
-        phases["store_flush_s"] = time.perf_counter() - t_store
-        phases["preflush_s"] = t_store - flush_start
+        store_flush.stop()
         round_info["async"] = async_on
 
         def _deliver_round(rec, other_samples, primary: bool) -> int:
@@ -1778,15 +1818,17 @@ class Server:
             series — a drain tick delivering two intervals must not mix
             one interval's phase totals with another's family segments
             in the recorded round."""
-            batch, fwd, r_phases = rec["result"]
+            batch, fwd, readout = rec["result"]
             rec_span, rec_traced = rec["span"], rec["traced"]
-            # readout phases land in this round's series (one interval
-            # late under overlap — the bench gate reads distributions)
+            # readout spans and phases land in this round's series (one
+            # interval late under overlap — the bench gate reads
+            # distributions)
             if primary:
-                for k, v in r_phases.items():
-                    if isinstance(v, (int, float)) or k in ("mesh",
-                                                            "families"):
-                        phases[k] = v
+                rnd.merge(readout)
+                if rec.get("mesh") is not None:
+                    phases["mesh"] = rec["mesh"]
+            # the sinks time their encode and sends into this round
+            batch.timing = rnd
             self.stats.inc("metrics_flushed", len(batch))
             # flush-stage ledger rows (informational): what the
             # delivered interval's snapshot produced
@@ -1838,9 +1880,13 @@ class Server:
                 # thread-spawn and (worse) count as a probe against
                 # this sink's breaker
                 if len(batch) or other_samples or key in self._sink_spill:
+                    # from here to the sink's own flush call: thread
+                    # start, events, spill, routing
+                    starting = rnd.phase("egress_start", parent="flush",
+                                         sink=key).start(handoff=True)
                     _start_sink_thread(
                         key, self._flush_sink_safe, key, sink, batch,
-                        other_samples, parent_span=rec_span,
+                        other_samples, starting, parent_span=rec_span,
                         span_traced=rec_traced)
             return len(batch)
 
@@ -1882,14 +1928,13 @@ class Server:
         # delivered before daemon threads die with the process.
         grace = (max(self.interval, 30.0) if self._shutdown.is_set()
                  else self.interval)
-        deadline = flush_start + grace
-        t_join = time.perf_counter()
-        for t in threads:
-            remaining = deadline - time.perf_counter()
-            if remaining <= 0:
-                break
-            t.join(remaining)
-        phases["sink_join_s"] = time.perf_counter() - t_join
+        deadline = rnd.t0 + grace
+        with rnd.phase("sink_join", parent="flush"):
+            for t in threads:
+                remaining = deadline - time.perf_counter()
+                if remaining <= 0:
+                    break
+                t.join(remaining)
         stuck = [t.name for t in threads if t.is_alive()]
         if stuck:
             logger.error(
@@ -1938,7 +1983,7 @@ class Server:
                 self.trace_plane.exemplars.capture(
                     "pipeline.sample_age", max(0.0, ack_unix - oldest),
                     rec["span"].trace_id, ts=ack_unix)
-            rec_families = rec["result"][2].get("families")
+            rec_families = rec.get("families")
             if rec_families:
                 for family, (secs, cache) in retraces.items():
                     frec = rec_families.get(family)
@@ -1954,11 +1999,9 @@ class Server:
                     for frec in rec_families.values():
                         frec["lane"] = "async"
                 # async readout spans still parent under the ORIGINATING
-                # interval's flush span, stamped with the readout's own
-                # wall-clock base (not this tick's)
-                self._record_family_spans(
-                    rec["span"], families=rec_families,
-                    base_unix=rec.get("readout_start_unix"))
+                # interval's flush span, on the readout's own wall clock
+                # (not this tick's)
+                self._record_family_spans(rec["span"], rec_families)
                 if families is None:
                     # the round's waterfall tree shows the FIRST
                     # delivered interval's segments (the async one on a
@@ -1968,7 +2011,8 @@ class Server:
                     if rec.get("async"):
                         round_info["delivered_flush"] = rec["flush"]
         flush_span.finish()
-        duration = time.perf_counter() - flush_start
+        duration = flush_phase.stop()["wall_s"]
+        phases["flush_cpu_s"] = rnd.cpu_s()
         # the join-only critical path: total wall minus whatever device
         # readout ran INLINE this tick (zero under flush_async — the
         # acceptance row proving dispatch/sync/transfer left the path)
@@ -2029,19 +2073,35 @@ class Server:
         self.trace_plane.roll(
             [rec["name"] for rec in self.cardinality.top(16)])
 
-    def _run_readout(self, record: dict):
+    def _run_readout(self, record: dict, parent: Optional[str] = None):
         """The background half of one flush (runs on the flush-readout
         executor under flush_async, inline otherwise): drain the swapped
         generations — kernel dispatch, device sync, transfer, assembly —
         plus the backfill drain, whose metrics carry their ORIGINAL
         timestamps and so lose nothing by riding the next delivery.
-        Returns (batch, fwd, readout_phases)."""
-        record["readout_start_unix"] = time.time()
-        r_phases: dict = {}
+        Returns (batch, fwd, the readout's own FlushRound): the round
+        that delivers it merges the spans under `parent` (None under
+        overlap, where the readout ran before that round began)."""
+        readout = FlushRound()
+        with readout.phase("readout", parent=parent):
+            batch, fwd = self._readout_timed(record, readout)
+        if self.latency.enabled:
+            # every family was synced on its own (attribute): the
+            # waterfall's tree, on the readout's own wall clock
+            record["families"] = family_tree(readout)
+        if self.store.shard_plane is not None:
+            # mesh topology alongside the phase numbers (a dict, so the
+            # per-phase statsd emission loop skips it): the bench's
+            # mesh-scaling scenario and the waterfall view read the
+            # shard width the measured flush actually merged over
+            record["mesh"] = self.store.shard_plane.describe()
+        return batch, fwd, readout
+
+    def _readout_timed(self, record: dict, readout: FlushRound):
         batch, fwd = readout_columnstore(
             self.store, record["swap"], self.is_local, self.aggregates,
             collect_forward=self.forwarder is not None,
-            timings=r_phases, attribute=self.latency.enabled)
+            timing=readout, attribute=self.latency.enabled)
         if self.backfill is not None:
             # closed historical buckets flush alongside the live
             # interval, each series timestamped at its ORIGINAL
@@ -2056,15 +2116,14 @@ class Server:
             # executor, so serialization overlaps sink delivery — the
             # forward thread later finds fwd.wire pre-built and skips
             # straight to the POST. Carryover merges invalidate it.
-            t0 = time.perf_counter()
             from veneur_tpu.forward.convert import forwardable_to_wire
-            try:
-                fwd.wire = forwardable_to_wire(fwd)
-            except Exception:
-                fwd.wire = None  # forward thread re-encodes
-                logger.exception("forward pre-encode failed")
-            r_phases["forward_encode_s"] = time.perf_counter() - t0
-        return batch, fwd, r_phases
+            with readout.phase("forward_encode", parent="readout"):
+                try:
+                    fwd.wire = forwardable_to_wire(fwd)
+                except Exception:
+                    fwd.wire = None  # forward thread re-encodes
+                    logger.exception("forward pre-encode failed")
+        return batch, fwd
 
     def _readout_executor(self):
         """Get-or-create the background flush executor (flush_async),
@@ -2133,27 +2192,15 @@ class Server:
         # the per-name mint budgets (the shed rung's immediate recovery)
         self.cardinality.roll_interval()
 
-    def _record_family_spans(self, flush_span, families: dict,
-                             base_unix: float = None) -> None:
-        """Matching child spans under the flush span, one per family
-        device segment tree: the span's start/end reconstruct the
-        measured dispatch->transfer window (the reference ships its own
-        observability as SSF spans; so does the waterfall). `base_unix`
-        anchors the segment offsets at the READOUT's wall-clock start —
-        an async readout runs after its interval's flush span finished,
-        and stamping it off this tick's flush time would both misplace
-        the segments and parent them under the wrong interval's trace."""
-        base = base_unix if base_unix is not None else (
-            self.last_flush_unix + self.flush_phase_timings.get(
-                "preflush_s", 0.0))
+    def _record_family_spans(self, flush_span, families: dict) -> None:
+        """Matching child spans under the flush span, one per family of
+        the round's `families` tree (`latency.family_tree`, built from
+        the readout's spans): from the start of the family's dispatch to
+        the end of its transfer, on the READOUT's wall clock — an async
+        readout runs after its interval's flush span finished, and
+        stamping it off this tick's flush time would both misplace the
+        segments and parent them under the wrong interval's trace."""
         for family, rec in families.items():
-            start_off = rec.get("dispatch_start_s", 0.0)
-            end_off = start_off + rec.get("dispatch_s", 0.0)
-            dev_start = rec.get("device_start_s")
-            if dev_start is not None:
-                end_off = dev_start + rec.get("transfer_s", 0.0) + sum(
-                    d.get("sync_s", 0.0)
-                    for d in rec.get("devices", {}).values())
             tags = {"family": family,
                     "dispatch_s": f"{rec.get('dispatch_s', 0.0):.6f}",
                     "transfer_s": f"{rec.get('transfer_s', 0.0):.6f}"}
@@ -2165,16 +2212,18 @@ class Server:
                 if rec.get("compile_cache"):
                     tags["compile_cache"] = rec["compile_cache"]
             child = flush_span.child("flush.family", tags=tags)
-            child.proto.start_timestamp = int((base + start_off) * 1e9)
-            child.finish(end_time=base + end_off)
+            child.proto.start_timestamp = int(rec["start_unix"] * 1e9)
+            child.finish(end_time=rec["end_unix"])
 
     def _timed_sink_flush(self, key: str, parent_span, span_traced,
-                          round_info: dict, target, *args) -> None:
-        """Body of one per-sink flush thread: a child span under the
-        DELIVERED interval's flush span (an async round delivers the
-        previous interval's readout — its sink spans parent there),
-        wall-clock duration, the sink-outcome row shared with the
-        flight recorder, and the per-sink duration self-metric."""
+                          round_info: dict, rnd: FlushRound, target,
+                          *args) -> None:
+        """Body of one per-sink flush thread: a `sink` span of the
+        round, a child span under the DELIVERED interval's flush span
+        stamped from it (an async round delivers the previous interval's
+        readout — its sink spans parent there), the sink-outcome row
+        shared with the flight recorder, and the per-sink duration
+        self-metric."""
         outcome = round_info["sinks"].setdefault(key, {})
         child = parent_span.child("flush.sink", tags={"sink": key})
         # make this sink's span the ambient parent for the duration of
@@ -2187,14 +2236,14 @@ class Server:
         if span_traced:
             from veneur_tpu.trace import context as trace_ctx
             ctx_token = trace_ctx._current_span.set(child)
-        start = time.perf_counter()
         try:
-            ok = target(*args)
+            with rnd.phase("sink", parent="flush", sink=key) as sink_span:
+                ok = target(*args)
         finally:
             if ctx_token is not None:
                 from veneur_tpu.trace import context as trace_ctx
                 trace_ctx._current_span.reset(ctx_token)
-        duration = time.perf_counter() - start
+        duration = sink_span["wall_s"]
         was_timed_out = outcome.get("status") == "timed_out"
         breaker = self._sink_breakers.get(key)
         # ok is None when the sink was never exercised (nothing to
@@ -2213,7 +2262,18 @@ class Server:
                 breaker.record_failure()
         if ok is False:
             child.error()
-        child.finish()
+        started_unix = rnd.start_unix + sink_span["start_s"]
+        child.proto.start_timestamp = int(started_unix * 1e9)
+        child.finish(end_time=started_unix + duration)
+        # what the sink posted, where it times its sends into the round
+        # (its egress_post_wall spans: this thread's, inside this call)
+        for span in list(rnd.spans):
+            if (span["name"] == "egress_post_wall"
+                    and span["thread"] == sink_span["thread"]
+                    and span["start_s"] >= sink_span["start_s"]):
+                for field in ("bodies", "bytes", "gzip_bytes"):
+                    outcome[field] = (outcome.get(field, 0)
+                                      + span.get(field, 0))
         if was_timed_out:
             # finished after its round was declared over — keep that
             # visible while still landing the real outcome
@@ -2273,10 +2333,12 @@ class Server:
             return False
 
     def _flush_sink_safe(self, key: str, sink, batch: FlushBatch,
-                         other_samples=()) -> Optional[bool]:
+                         other_samples=(), starting=None) -> Optional[bool]:
         """Returns True/False for a delivery attempt, None when the sink
         was never exercised (nothing to flush) — None must not feed the
-        sink's breaker."""
+        sink's breaker. `starting` is the round's `egress_start` phase,
+        begun where the flush loop dispatched this thread; it ends where
+        the sink's own flush is called."""
         ok = True
         if other_samples:
             try:
@@ -2306,6 +2368,8 @@ class Server:
                 # blackhole and friends never do). getattr: duck-typed
                 # sinks that only implement flush() still work.
                 fb = getattr(sink, "flush_batch", None)
+                if starting is not None:
+                    starting.stop()
                 if fb is not None:
                     fb(batch)
                 else:
@@ -2317,6 +2381,8 @@ class Server:
             if sc is not None:
                 selected = _apply_sink_filters(selected, sc)
             current = selected
+            if starting is not None:
+                starting.stop()
             sink.flush(spill + selected if spill else selected)
             self.ledger.note("egress.acked",
                              len(selected) + len(spill or ()), key=name)

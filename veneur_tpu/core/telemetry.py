@@ -19,13 +19,20 @@ Three pieces, all thread-safe and all O(1)-bounded:
   ticks, restarts) for `GET /debug/events`.
 - `FlushRecorder`: the last N flush rounds with per-phase and per-sink
   latency for `GET /debug/flush`.
+- `FlushRound`: one round's span source. `round.phase(name, parent)`
+  times an interval once and feeds three outputs: the round's `phases`
+  totals, its `spans` list, and a `veneur/<name>` annotation on the
+  profiler's host plane (`annotate` is the same annotation alone, for
+  the ingest path, which belongs to no round).
 """
 
 from __future__ import annotations
 
 import bisect
+import contextlib
 import json
 import logging
+import sys
 import threading
 import time
 from collections import deque
@@ -373,10 +380,11 @@ class EventRecorder:
 
 
 class FlushRecorder:
-    """The last N flush rounds, each a dict with phase timings and
-    per-sink outcomes. Sink threads keep a reference to their round's
-    dict, so a straggler that finishes after its round was recorded
-    still lands its final status (flagged `late`)."""
+    """The last N flush rounds, each a dict with phase timings, the
+    round's `spans` (FlushRound) and per-sink outcomes. Sink threads
+    keep a reference to their round's dict and span list, so a
+    straggler that finishes after its round was recorded still lands
+    its final status (flagged `late`) and its spans."""
 
     def __init__(self, capacity: int = 64):
         self.capacity = capacity
@@ -393,14 +401,128 @@ class FlushRecorder:
             # threads (that sharing is what lets a late finish land), so
             # copy them too — a reader iterating a shared dict while the
             # straggler inserts a key would blow up mid-serialization
-            rounds = [dict(r, sinks={k: dict(v)
-                                     for k, v in r.get("sinks", {}).items()})
-                      for r in self._rounds]
-        return rounds[-limit:] if limit > 0 else rounds
+            rounds = list(self._rounds)[-limit:] if limit > 0 \
+                else list(self._rounds)
+            return [dict(r, sinks={k: dict(v)
+                                   for k, v in r.get("sinks", {}).items()},
+                         spans=[dict(s) for s in list(r.get("spans", ()))])
+                    for r in rounds]
 
     def __len__(self) -> int:
         with self._lock:
             return len(self._rounds)
+
+
+_trace_annotation = None
+
+
+def annotate(name: str):
+    """A `jax.profiler.TraceAnnotation("veneur/<name>")`: an event on
+    the profiler's host plane, on the device trace's own clock, while a
+    capture runs (a flag test when none does). A process that has not
+    imported JAX (veneur-proxy, a stub sink's test) gets a no-op and
+    gains no JAX import from here."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        if "jax" not in sys.modules:
+            return contextlib.nullcontext()
+        from jax.profiler import TraceAnnotation
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation("veneur/" + name)
+
+
+class _Phase:
+    """One timed interval of a FlushRound. A context manager; `start()`
+    / `stop()` are the same thing for an interval that does not nest
+    lexically (the round's root). `start(handoff=True)` is for an
+    interval that ends on another thread (a sink thread's start-up):
+    wall time only, no annotation and no CPU time."""
+
+    __slots__ = ("_round", "rec", "_ann", "_t0", "_c0")
+
+    def __init__(self, rnd: "FlushRound", rec: dict):
+        self._round = rnd
+        self.rec = rec
+
+    def start(self, handoff: bool = False) -> "_Phase":
+        self.rec["thread"] = threading.current_thread().name
+        self._ann = None
+        if not handoff:
+            self._ann = annotate(self.rec["name"])
+            self._ann.__enter__()
+            self._c0 = time.thread_time()
+        self._t0 = time.perf_counter()
+        return self
+
+    def stop(self) -> dict:
+        wall_s = time.perf_counter() - self._t0
+        cpu_s = 0.0
+        if self._ann is not None:
+            cpu_s = time.thread_time() - self._c0
+            self._ann.__exit__(None, None, None)
+        self._round._close(self.rec, self._t0, wall_s, cpu_s)
+        return self.rec
+
+    def __enter__(self) -> dict:
+        return self.start().rec
+
+    def __exit__(self, *exc) -> None:
+        self.stop()
+
+
+class FlushRound:
+    """The span source of one flush round (or of one readout, which
+    under `flush_async` runs a tick ahead of the round that delivers it
+    and is `merge`d in there). Handed to whatever works for the round:
+    the readout executor, the sink threads through their `FlushBatch`,
+    the POST workers. `spans` entries are {name, parent, thread,
+    start_s, wall_s, cpu_s, ...tags}, `start_s` counted from the
+    round's start; `phases[name + "_s"]` sums the wall of every span of
+    that name."""
+
+    def __init__(self):
+        self.start_unix = time.time()
+        self.t0 = time.perf_counter()  # what every start_s counts from
+        self._lock = threading.Lock()
+        self.phases: Dict[str, float] = {}
+        self.spans: List[dict] = []
+
+    def phase(self, name: str, parent: Optional[str] = None,
+              **tags) -> _Phase:
+        return _Phase(self, {"name": name, "parent": parent, **tags})
+
+    def _close(self, rec: dict, t0: float, wall_s: float,
+               cpu_s: float) -> None:
+        rec.update(start_s=t0 - self.t0, wall_s=wall_s, cpu_s=cpu_s)
+        key = rec["name"] + "_s"
+        with self._lock:
+            self.spans.append(rec)
+            self.phases[key] = self.phases.get(key, 0.0) + wall_s
+
+    def merge(self, other: "FlushRound") -> None:
+        """Take over another round's spans (re-based on this round's
+        start) and phase totals: a delivered readout's."""
+        shift = other.t0 - self.t0
+        with other._lock:
+            spans = [dict(s, start_s=s["start_s"] + shift)
+                     for s in other.spans]
+            phases = dict(other.phases)
+        with self._lock:
+            self.spans.extend(spans)
+            for key, secs in phases.items():
+                self.phases[key] = self.phases.get(key, 0.0) + secs
+
+    def cpu_s(self) -> float:
+        """CPU seconds of every thread that worked for the round: each
+        span's own thread time, a span nested in another on the same
+        thread counted once (in the outer one)."""
+        with self._lock:
+            spans = list(self.spans)
+        threads_of: Dict[str, set] = {}
+        for s in spans:
+            threads_of.setdefault(s["name"], set()).add(s["thread"])
+        return sum(s["cpu_s"] for s in spans
+                   if s["thread"] not in threads_of.get(s["parent"], ()))
 
 
 class Telemetry:

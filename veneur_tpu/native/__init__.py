@@ -135,6 +135,8 @@ def _declare(lib) -> None:
     lib.vnt_pump_ring_stats.restype = None
     lib.vnt_pump_ring_stats.argtypes = [
         ctypes.c_void_p, i64p, i64p, i64p, i64p]
+    lib.vnt_pump_reader_times.restype = None
+    lib.vnt_pump_reader_times.argtypes = [ctypes.c_void_p, i64p, i64p]
     lib.vnt_pump_signal_stop.restype = None
     lib.vnt_pump_signal_stop.argtypes = [ctypes.c_void_p]
     lib.vnt_pump_live.restype = ctypes.c_int32
@@ -764,6 +766,10 @@ class Pump:
             chunk_cap, ring_slots, seal_age_ms, poll_ms)
         self._desc = ChunkDesc()
         self.nreaders = int(self._lib.vnt_pump_nreaders(self._p))
+        # the dispatcher thread's account (core/ingest.py, one writer):
+        # its CPU seconds, and the wall seconds it waited in next()
+        self.dispatch_cpu_s = 0.0
+        self.dispatch_wait_s = 0.0
 
     def next(self, timeout_ms: int = 200) -> "PumpChunk | None":
         """Blocks up to timeout_ms for a sealed chunk. The returned
@@ -828,6 +834,16 @@ class Pump:
             self._p, _ptr(out[0], i64), _ptr(out[1], i64),
             _ptr(out[2], i64), _ptr(out[3], i64))
         return out[0], out[1], out[2], out[3]
+
+    def reader_times(self):
+        """Per-reader (cpu_seconds, stall_seconds) float arrays: the
+        reader thread's own CPU time as of its last chunk seal, and the
+        time it has been blocked on a full ring."""
+        out = np.empty((2, self.nreaders), np.int64)
+        i64 = ctypes.c_int64
+        self._lib.vnt_pump_reader_times(
+            self._p, _ptr(out[0], i64), _ptr(out[1], i64))
+        return out[0] / 1e9, out[1] / 1e9
 
     def live_readers(self) -> int:
         return self._lib.vnt_pump_live(self._p)

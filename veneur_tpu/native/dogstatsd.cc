@@ -721,6 +721,12 @@ inline int64_t now_ms() {
   return static_cast<int64_t>(ts.tv_sec) * 1000 + ts.tv_nsec / 1000000;
 }
 
+inline int64_t clock_ns(clockid_t clock) {
+  struct timespec ts;
+  clock_gettime(clock, &ts);
+  return static_cast<int64_t>(ts.tv_sec) * 1000000000 + ts.tv_nsec;
+}
+
 struct Chunk {
   int64_t cap;        // per-family sample capacity
   int64_t unk_cap;    // max deferred lines
@@ -856,6 +862,10 @@ struct ReaderLane {
   SpscRing free_q;  // dispatcher -> reader (recycled chunks)
   std::atomic<int64_t> sealed{0};  // chunks sealed (ring throughput)
   std::atomic<int64_t> stalls{0};  // reader waits for a free chunk
+  // the reader thread's own CPU time, stamped at each chunk seal, and
+  // the time it has spent blocked on a full ring (inside the stalls)
+  std::atomic<int64_t> cpu_ns{0};
+  std::atomic<int64_t> stall_ns{0};
 
   ReaderLane(int fd_, uint64_t ring_cap)
       : fd(fd_), ready(ring_cap), free_q(ring_cap) {}
@@ -899,6 +909,8 @@ inline void pump_seal(Pump* p, ReaderLane* lane, Chunk* c) {
   c->seal_ms = now_ms();
   lane->ready.push(c);
   lane->sealed.fetch_add(1, std::memory_order_relaxed);
+  lane->cpu_ns.store(clock_ns(CLOCK_THREAD_CPUTIME_ID),
+                     std::memory_order_relaxed);
   std::lock_guard<std::mutex> lock(p->mu);
   p->cv_ready.notify_one();
 }
@@ -914,6 +926,14 @@ inline Chunk* pump_take_free(Pump* p, ReaderLane* lane) {
   if (c != nullptr) return c;
   lane->stalls.fetch_add(1, std::memory_order_relaxed);
   p->stalls.fetch_add(1, std::memory_order_relaxed);
+  struct Blocked {  // books the wait on every way out
+    ReaderLane* lane;
+    int64_t since = clock_ns(CLOCK_MONOTONIC);
+    ~Blocked() {
+      lane->stall_ns.fetch_add(clock_ns(CLOCK_MONOTONIC) - since,
+                               std::memory_order_relaxed);
+    }
+  } blocked{lane};
   for (int waited_ms = 0;;) {
     std::unique_lock<std::mutex> lock(p->mu);
     c = lane->free_q.pop();  // re-check under mu: release notifies under it
@@ -1083,6 +1103,17 @@ void vnt_pump_ring_stats(void* pp, int64_t* depth, int64_t* cap,
     cap[i] = p->ring_slots;
     sealed[i] = lane->sealed.load(std::memory_order_relaxed);
     stalls[i] = lane->stalls.load(std::memory_order_relaxed);
+  }
+}
+
+// Per-lane reader time, beside the ring's counts: the reader thread's
+// CPU nanoseconds as of its last chunk seal, and the nanoseconds it has
+// spent blocked on a full ring (a wait still in progress not included).
+void vnt_pump_reader_times(void* pp, int64_t* cpu_ns, int64_t* stall_ns) {
+  Pump* p = static_cast<Pump*>(pp);
+  for (size_t i = 0; i < p->lanes.size(); i++) {
+    cpu_ns[i] = p->lanes[i]->cpu_ns.load(std::memory_order_relaxed);
+    stall_ns[i] = p->lanes[i]->stall_ns.load(std::memory_order_relaxed);
   }
 }
 

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from veneur_tpu.ops import hll_ref
+from veneur_tpu.ops import device_scope, hll_ref
 
 M = hll_ref.M  # 16384 registers per key
 
@@ -27,17 +27,20 @@ def init_state(num_keys: int) -> jnp.ndarray:
 
 
 @partial(jax.jit, donate_argnums=0)
+@device_scope("apply", "set")
 def apply_batch(regs, rows, reg_idx, rho):
     """Scatter-max a batch of hashed members. rows == K marks padding."""
     return regs.at[rows, reg_idx].max(rho.astype(jnp.int8), mode="drop")
 
 
 @jax.jit
+@device_scope("merge", "set")
 def merge(regs_a, regs_b):
     return jnp.maximum(regs_a, regs_b)
 
 
 @partial(jax.jit, donate_argnums=0)
+@device_scope("merge", "set")
 def merge_rows(regs, rows, in_regs):
     """Merge whole incoming register rows (import path): per-key max."""
     num_keys = regs.shape[0]
@@ -46,6 +49,7 @@ def merge_rows(regs, rows, in_regs):
 
 
 @jax.jit
+@device_scope("readout", "set")
 def estimate(regs):
     """Per-key LogLog-Beta estimate (parity with the reference's vendored
     estimator, hyperloglog.go:207-231 + utils.go:12-22), as two row

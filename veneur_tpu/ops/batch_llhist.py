@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from veneur_tpu.ops import llhist_ref
+from veneur_tpu.ops import device_scope, llhist_ref
 
 BINS = llhist_ref.BINS
 # lane-aligned device width (TPU last-dim tile is 128)
@@ -43,6 +43,7 @@ def init_state(num_keys: int) -> jnp.ndarray:
 
 
 @partial(jax.jit, donate_argnums=0)
+@device_scope("apply", "llhist")
 def apply_batch(regs, rows, bin_idx, weight):
     """Scatter-add a batch of pre-binned samples. rows == PAD_ROW marks
     padding (dropped by mode="drop")."""
@@ -50,11 +51,13 @@ def apply_batch(regs, rows, bin_idx, weight):
 
 
 @jax.jit
+@device_scope("merge", "llhist")
 def merge(regs_a, regs_b):
     return regs_a + regs_b
 
 
 @partial(jax.jit, donate_argnums=0)
+@device_scope("merge", "llhist")
 def merge_rows(regs, rows, in_regs):
     """Merge whole incoming bin rows (forward-import path): register
     add. Duplicate rows in one batch accumulate, matching the scalar
@@ -63,6 +66,7 @@ def merge_rows(regs, rows, in_regs):
 
 
 @partial(jax.jit, static_argnums=1)
+@device_scope("readout", "llhist")
 def flush_packed(regs, ps: tuple):
     """One-pass readout: {quantiles (K, P), count (K,), sum (K,)}.
 

@@ -49,6 +49,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from veneur_tpu.ops import device_scope
+
 COMPRESSION = 100.0  # parity with reference samplers/samplers.go:350
 C = 128  # centroid slots per key; >= COMPRESSION buckets, lane-aligned
 
@@ -302,6 +304,7 @@ def apply_batch(state, rows, values, weights, slots=None):
 
 
 @partial(jax.jit, donate_argnums=0)
+@device_scope("apply", "histogram")
 def _apply_batch_jit(state, rows, values, weights, slots):
     num_keys = state["wv"].shape[0]
     valid = rows < num_keys
@@ -354,6 +357,7 @@ def _fold_grids(state):
 
 
 @partial(jax.jit, donate_argnums=0)
+@device_scope("compact", "histogram")
 def compact(state):
     """Fold the staging grid into the main grid with the mean-sorted
     recompress, leaving staging empty. Run every few applied batches and
@@ -369,6 +373,7 @@ def compact(state):
 
 
 @jax.jit
+@device_scope("compact", "histogram")
 def recompress_state(state):
     """Re-tighten every row's slot grid (staging folded in): sort slots by
     mean and re-bucket by combined prefix weights. Exists for external
@@ -384,6 +389,7 @@ def recompress_state(state):
 
 
 @partial(jax.jit, donate_argnums=0)
+@device_scope("merge", "histogram")
 def merge_centroid_rows(state, rows, in_means, in_weights, in_min, in_max,
                         in_recip):
     """Merge externally-serialized digests into the table (the import path,
@@ -506,6 +512,7 @@ def _flush_quantiles_impl(state, percentiles: Sequence[float],
 
 
 @partial(jax.jit, static_argnums=(1, 2))
+@device_scope("readout", "histogram")
 def flush_quantiles(state, percentiles: Sequence[float],
                     fold_staging: bool = True):
     """Compute per-key digest outputs: quantiles (K, P), plus digest count,
@@ -529,6 +536,7 @@ def _pack_flush(out):
 
 
 @partial(jax.jit, static_argnums=(1, 2))
+@device_scope("readout", "histogram")
 def flush_quantiles_packed(state, percentiles: Sequence[float],
                            fold_staging: bool = True):
     """flush_quantiles concatenated into one (K, P+10) float32 array.
@@ -552,6 +560,7 @@ def unpack_flush(packed, num_percentiles: int):
 
 
 @partial(jax.jit, static_argnums=(1,))
+@device_scope("readout", "histogram")
 def flush_export_packed(state, percentiles: Sequence[float]):
     """The forwarding flush, fused: fold staging, sort ONCE, interpolate
     quantiles from the sorted pre-merge centroids, and recompress the

@@ -13,6 +13,8 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 
+from veneur_tpu.ops import device_scope
+
 
 def init_counters(num_keys: int):
     """Kahan-compensated f32 accumulator pair: counters are exact integer
@@ -32,6 +34,7 @@ def _kahan_add(state, partial):
 
 
 @partial(jax.jit, donate_argnums=0)
+@device_scope("apply", "counter")
 def apply_counters(state, rows, values, rates):
     """rows == K marks padding; contribution is trunc(value/rate)."""
     num_keys = state["sum"].shape[0]
@@ -53,6 +56,7 @@ def init_gauges(num_keys: int):
 
 
 @partial(jax.jit, donate_argnums=0)
+@device_scope("apply", "gauge")
 def apply_gauges(state, rows, values):
     """Last-write-wins: for each row, keep the batch's last occurrence."""
     num_keys = state["value"].shape[0]
@@ -68,6 +72,7 @@ def apply_gauges(state, rows, values):
 
 
 @partial(jax.jit, donate_argnums=0)
+@device_scope("merge", "gauge")
 def merge_gauges(state, rows, in_values):
     """Import-path merge: overwrite (reference samplers.go:200-202). Within
     one import batch the last value wins, matching the reference's

@@ -35,6 +35,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from veneur_tpu.ops import device_scope
+
 SHARD_AXIS = "shard"
 
 # pending-buffer padding marker, shared with core/columnstore.py (kept
@@ -143,6 +145,7 @@ def tile_batch(n: int, col: np.ndarray) -> np.ndarray:
 # them into per-device scatters with zero communication) ---------------
 
 @partial(jax.jit, donate_argnums=0)
+@device_scope("apply", "counter")
 def apply_counters_sharded(state, rows, values, rates):
     return jax.vmap(_counters_body)(state, rows, values, rates)
 
@@ -174,6 +177,7 @@ def _gauges_body(state, rows, values):
 
 
 @partial(jax.jit, donate_argnums=0)
+@device_scope("apply", "gauge")
 def apply_gauges_sharded(state, rows, values):
     return jax.vmap(_gauges_body)(state, rows, values)
 
@@ -185,6 +189,7 @@ merge_gauges_sharded = apply_gauges_sharded
 
 
 @partial(jax.jit, donate_argnums=0)
+@device_scope("apply", "llhist")
 def apply_llhist_sharded(regs, rows, bin_idx, weight):
     """(n, K, BINS_PAD) int32 stacked registers += masked batch."""
     def body(r, rw, bi, w):
@@ -193,6 +198,7 @@ def apply_llhist_sharded(regs, rows, bin_idx, weight):
 
 
 @partial(jax.jit, donate_argnums=0)
+@device_scope("merge", "llhist")
 def merge_llhist_rows_at(regs, shard_ids, rows, in_rows):
     """Import-path whole-row register ADD over stacked state: incoming
     row i lands at (shard_ids[i], rows[i]). Indexed scatter rather than
@@ -217,6 +223,7 @@ def _zeros_tree(state):
 
 
 @partial(jax.jit, donate_argnums=0)
+@device_scope("merge", "counter")
 def merge_counters_stacked_reset(state):
     """Fused donated interval merge: (merged Kahan pair, fresh zeroed
     stacked generation aliasing the donated input)."""
@@ -225,6 +232,7 @@ def merge_counters_stacked_reset(state):
 
 
 @partial(jax.jit, donate_argnums=0)
+@device_scope("merge", "gauge")
 def merge_gauges_stacked_reset(state):
     """Fused donated LWW merge: ((value, set), fresh generation)."""
     value = jnp.sum(jnp.where(state["set"], state["value"], 0.0), axis=0)
@@ -232,12 +240,14 @@ def merge_gauges_stacked_reset(state):
 
 
 @partial(jax.jit, donate_argnums=0)
+@device_scope("merge", "llhist")
 def merge_llhist_stacked_reset(stacked: jnp.ndarray):
     """Fused donated register-ADD merge: ((K, BINS_PAD) merged
     registers, fresh stacked generation)."""
     return jnp.sum(stacked, axis=0), _zeros_tree(stacked)
 
 @jax.jit
+@device_scope("merge", "counter")
 def merge_counters_stacked(state) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(n, K) Kahan pairs -> one (K,) pair. With digest-home routing
     exactly one shard holds nonzero state per row, so the sum is pure
@@ -247,6 +257,7 @@ def merge_counters_stacked(state) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
 
 @jax.jit
+@device_scope("merge", "gauge")
 def merge_gauges_stacked(state) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """(n, K) LWW values + set masks -> merged (value, set). Each row
     has one home shard, so `where(set, value, 0)` summed over shards IS
@@ -256,6 +267,7 @@ def merge_gauges_stacked(state) -> Tuple[jnp.ndarray, jnp.ndarray]:
 
 
 @jax.jit
+@device_scope("merge", "llhist")
 def merge_llhist_stacked(stacked: jnp.ndarray) -> jnp.ndarray:
     """(n, K, BINS_PAD) int32 -> (K, BINS_PAD): register ADD, the exact
     merge the family exists for (associative + commutative integer
@@ -264,12 +276,14 @@ def merge_llhist_stacked(stacked: jnp.ndarray) -> jnp.ndarray:
 
 
 @jax.jit
+@device_scope("merge", "set")
 def merge_hll_stacked(stacked: jnp.ndarray) -> jnp.ndarray:
     """(n, K, M) int8 -> (K, M) register max (all-reduce-max on SPMD)."""
     return jnp.max(stacked, axis=0)
 
 
 @jax.jit
+@device_scope("merge", "histogram")
 def merge_histo_stacked(stacked: Dict[str, jnp.ndarray]
                         ) -> Dict[str, jnp.ndarray]:
     """Per-shard t-digest states stacked on axis 0 -> one merged state.

@@ -139,27 +139,45 @@ class DatadogMetricSink(MetricSink):
     def flush_columnar(self, batch) -> None:
         """Columnar fast path: pre-encoded JSON series parts straight
         from the FlushBatch arrays (core/egress.py), gzip-POSTed as raw
-        bodies — no per-InterMetric dicts, no json.dumps of the flush."""
-        import time as _time
-
+        bodies — no per-InterMetric dicts, no json.dumps of the flush.
+        Timed into the round that delivers the batch (`batch.timing`):
+        `egress_encode`, `egress_join`, and `egress_post_wall` around
+        each body's `egress_gzip` and `egress_http` on the workers."""
         from veneur_tpu.core.egress import DatadogColumnarEncoder
 
-        t0 = _time.perf_counter()
-        enc = self._encoder
-        if enc is None:
-            enc = self._encoder = DatadogColumnarEncoder(self)
-        parts, checks = enc.encode(batch)
-        encode_s = _time.perf_counter() - t0
-        t1 = _time.perf_counter()
+        rnd = batch.timing
+        with rnd.phase("egress_encode", parent="sink") as encode:
+            enc = self._encoder
+            if enc is None:
+                enc = self._encoder = DatadogColumnarEncoder(self)
+            parts, checks = enc.encode(batch)
+        send_s = 0.0
         if parts:
-            bodies = [b'{"series":[' +
-                      b",".join(parts[i:i + self.flush_max_per_body]) +
-                      b"]}"
-                      for i in range(0, len(parts),
-                                     self.flush_max_per_body)]
-            self._post_parallel(bodies, self._post_series_body_safe)
+            with rnd.phase("egress_join", parent="sink"):
+                bodies = [b'{"series":[' +
+                          b",".join(parts[i:i + self.flush_max_per_body]) +
+                          b"]}"
+                          for i in range(0, len(parts),
+                                         self.flush_max_per_body)]
+            sent: List[dict] = []
+
+            def timed(name: str):
+                """vhttp.post's "gzip" and "http", as spans of the round."""
+                phase = rnd.phase("egress_" + name,
+                                  parent="egress_post_wall")
+                sent.append(phase.rec)
+                return phase
+
+            with rnd.phase("egress_post_wall", parent="sink") as wall:
+                self._post_parallel(
+                    bodies,
+                    lambda body: self._post_series_body_safe(body, timed))
+            wall.update(
+                bodies=len(bodies), bytes=sum(map(len, bodies)),
+                gzip_bytes=sum(r.get("bytes", 0) for r in sent))
+            send_s = wall["wall_s"]
         self._post_checks(checks)
-        self.note_egress(encode_s, _time.perf_counter() - t1)
+        self.note_egress(encode["wall_s"], send_s)
 
     def _post_parallel(self, chunks, post_one) -> None:
         # concurrency capped at num_workers POSTs (reference
@@ -193,10 +211,11 @@ class DatadogMetricSink(MetricSink):
                 "tags": list(self.tags) + list(check.tags),
             })
 
-    def _post_series_body_safe(self, body: bytes) -> None:
+    def _post_series_body_safe(self, body: bytes, phase=None) -> None:
         url = f"{self.api_url}/api/v1/series?api_key={self.api_key}"
         try:
-            vhttp.post(url, body, compress="gzip", timeout=self.timeout)
+            vhttp.post(url, body, compress="gzip", timeout=self.timeout,
+                       phase=phase)
         except Exception as e:
             logger.error("datadog POST /api/v1/series failed: %s", e)
 

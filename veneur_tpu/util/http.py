@@ -11,6 +11,7 @@ hermetic test environment without `requests`.
 
 from __future__ import annotations
 
+import contextlib
 import gzip
 import json
 import time
@@ -148,19 +149,31 @@ def _copy(out: bytearray, offset: int, length: int) -> None:
         out.append(out[-offset])
 
 
+@contextlib.contextmanager
+def _untimed(_name: str):
+    yield {}
+
+
 def post(url: str, body: bytes, *,
          content_type: str = "application/json",
          headers: Optional[Dict[str, str]] = None,
          compress: Optional[str] = None,
          timeout: float = 10.0, method: str = "POST",
-         proxy_url: str = "") -> Tuple[int, bytes]:
+         proxy_url: str = "", phase=None) -> Tuple[int, bytes]:
     """Send `body` (POST by default), optionally compressed
     ("gzip"/"deflate"), returning (status, response body). Raises
     HTTPError on non-2xx. proxy_url routes the request through an
-    explicit HTTP(S) proxy, overriding environment proxies."""
+    explicit HTTP(S) proxy, overriding environment proxies. `phase`,
+    the caller's, names a timer: `phase("gzip")` and `phase("http")`
+    are context managers around the compression and around the request
+    up to the last byte of the answer; the first yields a dict that
+    receives the compressed `bytes`."""
+    phase = phase or _untimed
     hdrs = {"Content-Type": content_type}
     if compress == "gzip":
-        body = gzip.compress(body, compresslevel=6)
+        with phase("gzip") as timed:
+            body = gzip.compress(body, compresslevel=6)
+        timed["bytes"] = len(body)
         hdrs["Content-Encoding"] = "gzip"
     elif compress == "deflate":
         body = zlib.compress(body, 6)
@@ -177,7 +190,7 @@ def post(url: str, body: bytes, *,
     from veneur_tpu.util import chaos as chaos_mod
     chaos_mod.inject("http_post")
     try:
-        with opener(req, timeout=timeout) as resp:
+        with phase("http"), opener(req, timeout=timeout) as resp:
             return resp.status, resp.read()
     except urllib.error.HTTPError as e:
         raise HTTPError(e.code, e.read(),
