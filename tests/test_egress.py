@@ -15,6 +15,7 @@ rows, and WAL-backfilled timestamp lines.
 from __future__ import annotations
 
 import json
+import threading
 
 import numpy as np
 import pytest
@@ -143,22 +144,173 @@ def test_datadog_flush_columnar_posts_same_series(monkeypatch):
     assert col_checks == leg_checks
 
 
-def test_datadog_columnar_fallback_on_encoder_error(monkeypatch):
+def _capture_posts(monkeypatch):
+    """Fake `vhttp.post` / `post_json` of the Datadog sink's module ->
+    the list of (kind, url, raw body, posting thread's name)."""
     from veneur_tpu.sinks import datadog as ddmod
 
-    calls = []
-    monkeypatch.setattr(ddmod.vhttp, "post",
-                        lambda *a, **k: calls.append("raw"))
-    monkeypatch.setattr(ddmod.vhttp, "post_json",
-                        lambda *a, **k: calls.append("json"))
+    posted = []
+
+    def fake_post(url, body, **kw):
+        posted.append(("raw", url, bytes(body),
+                       threading.current_thread().name))
+
+    def fake_post_json(url, payload, **kw):
+        posted.append(("json", url, json.dumps(payload).encode(),
+                       threading.current_thread().name))
+
+    monkeypatch.setattr(ddmod.vhttp, "post", fake_post)
+    monkeypatch.setattr(ddmod.vhttp, "post_json", fake_post_json)
+    return posted
+
+
+class _FailingEncoder(DatadogColumnarEncoder):
+    """Raises once `bodies_before` bodies were handed off (0: before
+    any), at the next body's hand-off or at the end of the encode."""
+
+    def __init__(self, sink, bodies_before):
+        super().__init__(sink)
+        self.bodies_before = bodies_before
+
+    def encode_bodies(self, batch, per_body, emit):
+        handed = []
+
+        def failing_emit(parts):
+            if len(handed) >= self.bodies_before:
+                raise RuntimeError("boom")
+            handed.append(parts)
+            emit(parts)
+
+        super().encode_bodies(batch, per_body, failing_emit)
+        raise RuntimeError("boom")
+
+
+def test_datadog_columnar_fallback_on_encoder_error(monkeypatch):
+    """An encoder that fails before any body was handed off: nothing
+    was posted, so the legacy path delivers the whole batch."""
+    posted = _capture_posts(monkeypatch)
     batch, _ = _mk_batch()
-    sink = _dd_sink(num_workers=1)
-    from veneur_tpu.core import egress as egmod
-    monkeypatch.setattr(
-        egmod.DatadogColumnarEncoder, "encode",
-        lambda self, b: (_ for _ in ()).throw(RuntimeError("boom")))
+    sink = _dd_sink(num_workers=1, flush_max_per_body=20)
+    sink._encoder = _FailingEncoder(sink, bodies_before=0)
     sink.flush_batch(batch)  # must not raise; legacy path delivers
-    assert "json" in calls
+    assert {kind for kind, *_ in posted} == {"json"}
+    series = [s for _, url, body, _ in posted if "/series" in url
+              for s in json.loads(body)["series"]]
+    assert len(series) == len(DatadogColumnarEncoder(sink).encode(batch)[0])
+
+
+@pytest.mark.parametrize("num_workers", [1, 4])
+def test_datadog_encoder_error_after_hand_off_posts_no_series_twice(
+        monkeypatch, num_workers):
+    """Once a body went to a POST worker the fallback would post its
+    series again: the flush fails instead, after the workers ended."""
+    from veneur_tpu.sinks.datadog import SeriesPartlySent
+
+    posted = _capture_posts(monkeypatch)
+    batch, _ = _mk_batch()
+    sink = _dd_sink(num_workers=num_workers, flush_max_per_body=20)
+    parts, _checks = DatadogColumnarEncoder(sink).encode(batch)
+    sink._encoder = _FailingEncoder(sink, bodies_before=2)
+    with pytest.raises(SeriesPartlySent):
+        sink.flush_batch(batch)
+    # what was handed off was sent, once, and nothing else was
+    assert sorted(body for _, _, body, _ in posted) == sorted(
+        _bodies(parts, 20)[:2])
+    assert not any(t.name.startswith("datadog-post-")
+                   for t in threading.enumerate())
+
+
+def _bodies(parts, per_body):
+    return [b'{"series":[' + b",".join(parts[i:i + per_body]) + b"]}"
+            for i in range(0, len(parts), per_body)]
+
+
+def _per_body(shape: str, n_parts: int) -> int:
+    per_body = {
+        "one_body": n_parts,
+        "exact_multiple": next(d for d in range(2, n_parts)
+                               if n_parts % d == 0),
+        "remainder": 20,
+        "larger_than_batch": n_parts + 14,
+        "one_series_a_body": 1,
+    }[shape]
+    if shape == "remainder":
+        assert n_parts % per_body and n_parts > 2 * per_body
+    return per_body
+
+
+@pytest.mark.parametrize("num_workers", [1, 4])
+@pytest.mark.parametrize("shape", [
+    "one_body", "exact_multiple", "remainder", "larger_than_batch",
+    "one_series_a_body"])
+def test_datadog_pipeline_posts_the_bodies_of_encode(monkeypatch, shape,
+                                                     num_workers):
+    """The pipeline sends exactly `encode()`'s parts cut every
+    `flush_max_per_body`: the same bytes whatever the number of bodies
+    and workers, and with one worker in the same order."""
+    posted = _capture_posts(monkeypatch)
+    batch, _ = _mk_batch(_extras())
+    parts, checks = DatadogColumnarEncoder(_dd_sink()).encode(batch)
+    per_body = _per_body(shape, len(parts))
+    sink = _dd_sink(num_workers=num_workers, flush_max_per_body=per_body)
+    sink.flush_batch(batch)
+    want = _bodies(parts, per_body)
+    got = [body for kind, url, body, _ in posted
+           if kind == "raw" and "/api/v1/series" in url]
+    assert sorted(got) == sorted(want)
+    if num_workers == 1:
+        assert got == want
+    # the checks leave after the last body, from the sink thread
+    assert [kind for kind, *_ in posted[-len(checks):]] == ["json"]
+    posters = {thread for kind, _, _, thread in posted if kind == "raw"}
+    me = threading.current_thread().name
+    if len(want) == 1:
+        assert posters == {me}
+    else:
+        assert me not in posters and len(posters) <= num_workers
+
+
+def test_datadog_pipeline_under_thread_stress(monkeypatch):
+    """More POST workers than cores and the interpreter switching
+    threads every 10 us: each body still leaves exactly once, and the
+    sink's own count of what it sent agrees."""
+    import sys
+
+    posted = _capture_posts(monkeypatch)
+    batch, _ = _mk_batch(_extras())
+    parts, _checks = DatadogColumnarEncoder(_dd_sink()).encode(batch)
+    sink = _dd_sink(num_workers=32, flush_max_per_body=1)
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for _ in range(5):
+            del posted[:]
+            batch.timing.spans.clear()
+            sink.flush_columnar(batch)
+            got = [body for kind, _, body, _ in posted if kind == "raw"]
+            assert sorted(got) == sorted(_bodies(parts, 1))
+            [wall] = [s for s in batch.timing.spans
+                      if s["name"] == "egress_post_wall"]
+            assert wall["bodies"] == len(parts)
+            assert wall["bytes"] == sum(map(len, got))
+    finally:
+        sys.setswitchinterval(interval)
+    assert not any(t.name.startswith("datadog-post-")
+                   for t in threading.enumerate())
+
+
+def test_datadog_encode_bodies_cuts_encodes_parts():
+    """`encode_bodies` emits only runs of `per_body` parts that have a
+    successor, and returns the rest: together, `encode()`'s parts."""
+    batch, _ = _mk_batch(_extras())
+    enc = DatadogColumnarEncoder(_dd_sink())
+    parts, checks = enc.encode(batch)
+    for per_body in (1, 2, 7, len(parts) // 2, len(parts), len(parts) + 1):
+        emitted = []
+        rest, checks_2 = enc.encode_bodies(batch, per_body, emitted.append)
+        assert emitted + [rest] == [parts[i:i + per_body] for i in
+                                    range(0, len(parts), per_body)]
+        assert [c.name for c in checks_2] == [c.name for c in checks]
 
 
 # -- Prometheus ------------------------------------------------------------

@@ -44,6 +44,8 @@ NEW_PHASES = (
     "assembly_set_s", "assembly_llhist_s", "recycle_s", "egress_start_s",
     "egress_encode_s", "egress_join_s", "egress_post_wall_s",
     "egress_gzip_s", "egress_http_s", "flush_cpu_s")
+# ISSUE 28: what of the send is left once the encode has ended
+NEW_PHASES += ("egress_post_tail_s",)
 KEPT_PHASES = (
     "swap_s", "join_s", "preflush_s", "store_flush_s", "dispatch_s",
     "device_sync_s", "assembly_s", "sink_join_s", "critical_path_s")
@@ -185,10 +187,20 @@ def test_family_blocks_make_up_assembly(flushed):
 
 
 def test_egress_spans_explain_sink_join(flushed):
+    """The sends run beside the encode, so what adds up on the sink
+    thread is start + encode (+ a join there: a one-body flush's) + the
+    tail, not the post wall."""
     p = flushed["round"]["phases"]
-    parts = (p["egress_start_s"] + p["egress_encode_s"]
-             + p["egress_join_s"] + p["egress_post_wall_s"])
+    spans = flushed["round"]["spans"]
+    [sink] = [s for s in spans if s.get("sink") == "metric:datadog"
+              and s["name"] == "sink"]
+    joined_here = sum(s["wall_s"] for s in spans
+                      if s["name"] == "egress_join"
+                      and s["thread"] == sink["thread"])
+    parts = (p["egress_start_s"] + p["egress_encode_s"] + joined_here
+             + p["egress_post_tail_s"])
     assert p["sink_join_s"] >= INTAKE_DELAY_S
+    assert p["egress_post_tail_s"] <= p["egress_post_wall_s"]
     assert abs(parts - p["sink_join_s"]) <= max(0.05 * p["sink_join_s"],
                                                 SWITCH_S), p
 
@@ -204,6 +216,11 @@ def test_gzip_and_http_run_inside_the_post_wall(flushed):
     assert rnd["phases"]["egress_http_s"] >= len(bodies) * INTAKE_DELAY_S
     sent = rnd["sinks"]["metric:datadog"]
     assert sent["bodies"] == wall["bodies"]
+    # bodies whose gzip began while the encoder ran: never the last one
+    [encode] = [s for s in spans if s["name"] == "egress_encode"]
+    assert sent["bodies_overlapped"] == wall["bodies_overlapped"] == sum(
+        s["start_s"] < encode["start_s"] + encode["wall_s"]
+        for s in spans if s["name"] == "egress_gzip") < wall["bodies"]
     assert 0 < sent["gzip_bytes"] < sent["bytes"]
     assert sent["gzip_bytes"] == sum(
         s["bytes"] for s in spans if s["name"] == "egress_gzip")
@@ -221,7 +238,7 @@ def test_flush_cpu_counts_each_thread_once(flushed):
         sink["thread"]}
     floor = root["cpu_s"] + sink["cpu_s"] + sum(
         s["cpu_s"] for s in by_name["egress_http"] + by_name["egress_gzip"]
-        if s["thread"] in workers)
+        + by_name["egress_join"] if s["thread"] in workers)
     cpu = rnd["phases"]["flush_cpu_s"]
     assert cpu == pytest.approx(floor, rel=0.02, abs=2e-4)
     # a thread's CPU seconds cannot pass the wall it was alive for

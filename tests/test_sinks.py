@@ -6,6 +6,7 @@ import gzip
 import json
 import socket
 import threading
+import time
 import urllib.parse
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
@@ -17,12 +18,16 @@ from veneur_tpu.util import http as vhttp
 
 
 class CapturingHTTPServer:
-    """Records every request (path, headers, body) and returns 200."""
+    """Records every request (path, headers, body) and returns 200,
+    after holding it for `delay_s`; `most_in_flight` is the most
+    requests it ever held at once."""
 
-    def __init__(self):
+    def __init__(self, delay_s=0.0):
         outer = self
         self.requests = []
         self.event = threading.Event()
+        self.lock = threading.Lock()
+        self.in_flight = self.most_in_flight = 0
 
         class Handler(BaseHTTPRequestHandler):
             def log_message(self, fmt, *args):
@@ -33,9 +38,16 @@ class CapturingHTTPServer:
                 body = self.rfile.read(length)
                 if self.headers.get("Content-Encoding") == "gzip":
                     body = gzip.decompress(body)
-                outer.requests.append(
-                    (self.path, dict(self.headers), body))
+                with outer.lock:
+                    outer.requests.append(
+                        (self.path, dict(self.headers), body))
+                    outer.in_flight += 1
+                    outer.most_in_flight = max(outer.most_in_flight,
+                                               outer.in_flight)
                 outer.event.set()
+                time.sleep(delay_s)
+                with outer.lock:
+                    outer.in_flight -= 1
                 self.send_response(200)
                 self.send_header("Content-Length", "2")
                 self.end_headers()
@@ -180,6 +192,117 @@ class TestDatadog:
         # second flush with nothing buffered: no POST
         sink.flush()
         assert len(fake.requests) == 1
+
+
+class TestDatadogPipeline:
+    """flush_columnar as a pipeline (sinks/datadog.py `_BodyPosts`):
+    judged on the round's spans and on what the intake saw."""
+
+    def _flush(self, url, per_body, num_workers, encoder=None):
+        from test_egress import _mk_batch
+        from veneur_tpu.sinks.datadog import DatadogMetricSink
+
+        batch, _ = _mk_batch()
+        sink = DatadogMetricSink("datadog", api_key="k", api_url=url,
+                                 hostname="dh", interval=10.0,
+                                 flush_max_per_body=per_body,
+                                 num_workers=num_workers)
+        if encoder is not None:
+            sink._encoder = encoder(sink, batch.timing)
+        with batch.timing.phase("sink", parent=None):
+            sink.flush_columnar(batch)
+        by_name = {}
+        for span in batch.timing.spans:
+            by_name.setdefault(span["name"], []).append(span)
+        return by_name
+
+    @staticmethod
+    def _waiting_encoder(sink, rnd):
+        """An encoder slowed per body: after each hand-off it waits
+        until that body's gzip has run (its span is in the round)."""
+        from veneur_tpu.core.egress import DatadogColumnarEncoder
+
+        class Waiting(DatadogColumnarEncoder):
+            def encode_bodies(self, batch, per_body, emit):
+                handed = []
+
+                def emit_and_wait(parts):
+                    emit(parts)
+                    handed.append(parts)
+                    deadline = time.time() + 10.0
+                    while (sum(s["name"] == "egress_gzip"
+                               for s in list(rnd.spans)) < len(handed)
+                           and time.time() < deadline):
+                        time.sleep(0.001)
+
+                return super().encode_bodies(batch, per_body,
+                                             emit_and_wait)
+
+        return Waiting(sink)
+
+    def test_bodies_are_sent_while_the_encoder_runs(self, fake):
+        spans = self._flush(fake.url, 20, 1, self._waiting_encoder)
+        [encode], [wall] = spans["egress_encode"], spans["egress_post_wall"]
+        [tail], [sink] = spans["egress_post_tail"], spans["sink"]
+        encode_end = encode["start_s"] + encode["wall_s"]
+        gzips = sorted(spans["egress_gzip"], key=lambda s: s["start_s"])
+        assert len(gzips) == wall["bodies"] == len(fake.requests) >= 3
+        # every body but the last was compressed while the encoder ran
+        assert gzips[0]["start_s"] < encode_end
+        assert [g["start_s"] < encode_end for g in gzips] == (
+            [True] * (len(gzips) - 1) + [False])
+        assert wall["bodies_overlapped"] == wall["bodies"] - 1
+        # ... on the one worker, not on the encoding thread
+        senders = {s["thread"] for s in gzips + spans["egress_http"]
+                   + spans["egress_join"]}
+        assert senders == {"datadog-post-0"} != {encode["thread"]}
+        # the wall spans the sends, the tail only what follows the encode
+        assert wall["start_s"] < encode_end <= tail["start_s"]
+        ends = [s["start_s"] + s["wall_s"] for s in spans["egress_http"]]
+        assert wall["start_s"] + wall["wall_s"] >= max(ends)
+        assert tail["start_s"] + tail["wall_s"] >= max(ends)
+        assert tail["wall_s"] < wall["wall_s"]
+        assert wall["thread"] == tail["thread"] == sink["thread"]
+        assert wall["bytes"] == sum(len(b) for _, _, b in fake.requests)
+
+    def test_a_one_body_flush_posts_from_the_sink_thread(self, fake,
+                                                         monkeypatch):
+        started = []
+        start = threading.Thread.start
+        monkeypatch.setattr(
+            threading.Thread, "start",
+            lambda thread: (started.append(thread.name), start(thread))[1])
+        spans = self._flush(fake.url, 25_000, 4)
+        assert not [name for name in started if "-post-" in name]
+        me = threading.current_thread().name
+        sending = [s for name in ("egress_join", "egress_gzip",
+                                  "egress_http", "egress_post_wall",
+                                  "egress_post_tail")
+                   for s in spans[name]]
+        assert len(sending) == 5 and {s["thread"] for s in sending} == {me}
+        [wall], [tail] = spans["egress_post_wall"], spans["egress_post_tail"]
+        assert wall["bodies"] == 1 and wall["bodies_overlapped"] == 0
+        assert len(fake.requests) == 1
+        # in turn on one thread: encode, join, then the tail around the post
+        [encode], [join] = spans["egress_encode"], spans["egress_join"]
+        assert (encode["start_s"] + encode["wall_s"] <= join["start_s"]
+                and join["start_s"] + join["wall_s"] <= tail["start_s"]
+                <= wall["start_s"])
+
+    @pytest.mark.parametrize("num_workers", [1, 2, 4])
+    def test_no_more_than_num_workers_requests_in_flight(self, num_workers):
+        intake = CapturingHTTPServer(delay_s=0.03)
+        try:
+            spans = self._flush(intake.url, 8, num_workers)
+        finally:
+            intake.close()
+        [wall] = spans["egress_post_wall"]
+        assert len(intake.requests) == wall["bodies"] >= 8
+        assert intake.most_in_flight <= num_workers
+        if num_workers > 1:   # and the cap is used, not only kept
+            assert intake.most_in_flight >= 2
+        assert len({s["thread"] for s in spans["egress_http"]}) \
+            <= num_workers
 
 
 class TestCortex:

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import json
 import struct
+import sys
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -130,6 +131,17 @@ class DatadogColumnarEncoder:
                                                  List[InterMetric]]:
         """-> (series body parts, status checks). Joining parts with
         b"," inside `{"series":[...]}` is the POST body."""
+        return self.encode_bodies(batch, sys.maxsize, None)
+
+    def encode_bodies(self, batch: FlushBatch, per_body: int,
+                      emit) -> Tuple[List[bytes], List[InterMetric]]:
+        """`encode`, handing the parts over body by body while it runs:
+        `emit(parts)` receives each run of `per_body` parts as soon as
+        one part more exists, so whatever is emitted has a successor.
+        -> (the last 1..`per_body` parts, status checks); a batch of at
+        most `per_body` series emits nothing. Emitted runs and the
+        returned rest, in order, are `encode`'s parts cut every
+        `per_body`."""
         sink = self.sink
         parts: List[bytes] = []
         checks: List[InterMetric] = []
@@ -138,19 +150,28 @@ class DatadogColumnarEncoder:
         for sec in batch.sections:
             is_counter = sec.mtype == MetricType.COUNTER
             vals = sec.values / interval if is_counter else sec.values
-            if np.isfinite(vals).all():
-                val_strs = [repr(v).encode() for v in vals.tolist()]
-            else:
-                val_strs = [_json_num(v).encode() for v in vals.tolist()]
+            finite = np.isfinite(vals).all()
+            vals = vals.tolist()
             names = sec.names.tolist()
             tagrows = sec.tags.tolist()
             frag = self._frag
-            for i, nm in enumerate(names):
-                _tags, prefix, _ht = frag(nm, tagrows[i], is_counter)
-                if prefix is None:
-                    continue
-                parts.append(prefix + b'],"points":[[' + ts_b + b","
-                             + val_strs[i] + b"]]}")
+            lo = 0
+            while lo < len(names):
+                # a row adds at most one part: up to one past the cut
+                hi = lo + per_body + 1 - len(parts)
+                if finite:
+                    val_strs = [repr(v).encode() for v in vals[lo:hi]]
+                else:
+                    val_strs = [_json_num(v).encode() for v in vals[lo:hi]]
+                for nm, tags, val in zip(names[lo:hi], tagrows[lo:hi],
+                                         val_strs):
+                    _tags, prefix, _ht = frag(nm, tags, is_counter)
+                    if prefix is None:
+                        continue
+                    parts.append(prefix + b'],"points":[[' + ts_b + b","
+                                 + val + b"]]}")
+                lo = hi
+                parts = _emit_full(parts, per_body, emit)
         if batch.bucket_sections:
             les = _dd_le_json()
             for bs in batch.bucket_sections:
@@ -170,6 +191,7 @@ class DatadogColumnarEncoder:
                         parts.append(prefix + sep + les[k]
                                      + b'],"points":[[' + ts_b + b","
                                      + _json_num(v).encode() + b"]]}")
+                    parts = _emit_full(parts, per_body, emit)
         for m in batch.extras:
             if sink.metric_name_prefix_drops and any(
                     m.name.startswith(p)
@@ -180,7 +202,17 @@ class DatadogColumnarEncoder:
             else:
                 parts.append(json.dumps(
                     sink._dd_metric(m), separators=(",", ":")).encode())
+                parts = _emit_full(parts, per_body, emit)
         return parts, checks
+
+
+def _emit_full(parts: List[bytes], per_body: int, emit) -> List[bytes]:
+    """Hand over every run of `per_body` parts that has a successor;
+    -> the parts still held (at most `per_body`)."""
+    while len(parts) > per_body:
+        emit(parts[:per_body])
+        parts = parts[per_body:]
+    return parts
 
 
 _DD_LE_JSON: Optional[List[bytes]] = None

@@ -2271,7 +2271,8 @@ class Server:
             if (span["name"] == "egress_post_wall"
                     and span["thread"] == sink_span["thread"]
                     and span["start_s"] >= sink_span["start_s"]):
-                for field in ("bodies", "bytes", "gzip_bytes"):
+                for field in ("bodies", "bytes", "gzip_bytes",
+                              "bodies_overlapped"):
                     outcome[field] = (outcome.get(field, 0)
                                       + span.get(field, 0))
         if was_timed_out:

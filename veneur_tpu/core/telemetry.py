@@ -435,8 +435,10 @@ class _Phase:
     """One timed interval of a FlushRound. A context manager; `start()`
     / `stop()` are the same thing for an interval that does not nest
     lexically (the round's root). `start(handoff=True)` is for an
-    interval that ends on another thread (a sink thread's start-up):
-    wall time only, no annotation and no CPU time."""
+    interval that ends on another thread (a sink thread's start-up) or
+    after spans of its own thread that began inside it (a sink's post
+    wall, opened while it encodes): wall time only, no annotation and
+    no CPU time."""
 
     __slots__ = ("_round", "rec", "_ann", "_t0", "_c0")
 

@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import collections
 import logging
+import queue
 import threading
 from typing import Any, Dict, List, Sequence
 
@@ -129,8 +130,19 @@ class DatadogMetricSink(MetricSink):
                          encoder="legacy")
 
     def flush_batch(self, batch) -> None:
+        """`flush_columnar`, with the legacy `flush(materialize())` as
+        the fallback while that is safe: only if the columnar flush
+        failed before it handed a body to a POST worker. After a
+        hand-off (`SeriesPartlySent`) a fallback would post those series
+        twice in one flush, so the error is raised instead and the flush
+        counts as failed: the sink's breaker, and the server's spill,
+        which offers a failed interval once more with the next one (the
+        bodies that did leave then arrive again, same timestamps and
+        values)."""
         try:
             self.flush_columnar(batch)
+        except SeriesPartlySent:
+            raise
         except Exception:
             logger.exception("datadog columnar flush failed; "
                              "falling back to materialize()")
@@ -140,48 +152,58 @@ class DatadogMetricSink(MetricSink):
         """Columnar fast path: pre-encoded JSON series parts straight
         from the FlushBatch arrays (core/egress.py), gzip-POSTed as raw
         bodies — no per-InterMetric dicts, no json.dumps of the flush.
+
+        A pipeline: this thread encodes, and each time
+        `flush_max_per_body` parts are ready and more are to come they
+        go to a POST worker, which joins, gzips and sends that body
+        while this thread encodes the next. At most `num_workers`
+        bodies are in gzip/POST at once. A flush of one body starts no
+        thread and sends it from here. Returns after the last body was
+        answered (or failed and was logged) and the checks were posted.
+
+        An error before any hand-off is raised as it is (`flush_batch`
+        falls back); after one, the workers are waited for and
+        `SeriesPartlySent` is raised: no series is posted twice.
+
         Timed into the round that delivers the batch (`batch.timing`):
-        `egress_encode`, `egress_join`, and `egress_post_wall` around
-        each body's `egress_gzip` and `egress_http` on the workers."""
+        `egress_encode` here; per body `egress_join`, `egress_gzip` and
+        `egress_http` on whichever thread sends it; `egress_post_wall`
+        from the first hand-off to the last answer (it overlaps the
+        encode; `bodies_overlapped` counts the bodies whose gzip began
+        before the encode ended); `egress_post_tail` from the end of
+        the encode to the last answer, the part of the send that this
+        thread still waits for."""
         from veneur_tpu.core.egress import DatadogColumnarEncoder
 
         rnd = batch.timing
-        with rnd.phase("egress_encode", parent="sink") as encode:
-            enc = self._encoder
-            if enc is None:
-                enc = self._encoder = DatadogColumnarEncoder(self)
-            parts, checks = enc.encode(batch)
-        send_s = 0.0
-        if parts:
-            with rnd.phase("egress_join", parent="sink"):
-                bodies = [b'{"series":[' +
-                          b",".join(parts[i:i + self.flush_max_per_body]) +
-                          b"]}"
-                          for i in range(0, len(parts),
-                                         self.flush_max_per_body)]
-            sent: List[dict] = []
-
-            def timed(name: str):
-                """vhttp.post's "gzip" and "http", as spans of the round."""
-                phase = rnd.phase("egress_" + name,
-                                  parent="egress_post_wall")
-                sent.append(phase.rec)
-                return phase
-
-            with rnd.phase("egress_post_wall", parent="sink") as wall:
-                self._post_parallel(
-                    bodies,
-                    lambda body: self._post_series_body_safe(body, timed))
-            wall.update(
-                bodies=len(bodies), bytes=sum(map(len, bodies)),
-                gzip_bytes=sum(r.get("bytes", 0) for r in sent))
-            send_s = wall["wall_s"]
+        enc = self._encoder
+        if enc is None:
+            enc = self._encoder = DatadogColumnarEncoder(self)
+        posts = _BodyPosts(self, rnd)
+        try:
+            with rnd.phase("egress_encode", parent="sink") as encode:
+                rest, checks = enc.encode_bodies(
+                    batch, self.flush_max_per_body, posts.hand_off)
+        except Exception as e:
+            if not posts.workers:
+                raise
+            posts.finish(encode, [])
+            raise SeriesPartlySent(
+                f"encode failed after {len(posts.sent)} bodies "
+                "were handed to the POST workers") from e
+        tail = posts.finish(encode, rest)
+        if posts.errors:
+            raise SeriesPartlySent(
+                f"{len(posts.errors)} of {len(posts.sent)} bodies failed "
+                "on a POST worker") from posts.errors[0]
         self._post_checks(checks)
-        self.note_egress(encode["wall_s"], send_s)
+        self.note_egress(encode["wall_s"], tail)
 
     def _post_parallel(self, chunks, post_one) -> None:
-        # concurrency capped at num_workers POSTs (reference
-        # datadog.go:182-207 chunks a flush across num_workers)
+        """The legacy flush's send: every chunk exists before the first
+        leaves, and nothing here overlaps the encode. Concurrency capped
+        at num_workers POSTs, this thread one of them (reference
+        datadog.go:182-207 chunks a flush across num_workers)."""
         it = iter(chunks)
 
         def worker():
@@ -252,6 +274,99 @@ class DatadogMetricSink(MetricSink):
             })
         if events:
             self._post_safe("/intake", {"events": {self._name: events}})
+
+
+class SeriesPartlySent(Exception):
+    """A columnar flush failed after some of its bodies had gone to the
+    POST workers: posting the batch again would post their series
+    twice."""
+
+
+class _BodyPosts:
+    """The sending half of one `flush_columnar`: bodies handed off as
+    lists of parts, each joined, gzipped and posted by one of up to
+    `num_workers` POST workers beside the encoding thread, or, when the
+    flush has one body, by the encoding thread itself."""
+
+    def __init__(self, sink: DatadogMetricSink, rnd):
+        self.sink = sink
+        self.rnd = rnd
+        self.queue: "queue.SimpleQueue" = queue.SimpleQueue()
+        self.workers: List[threading.Thread] = []
+        self.sent: List[dict] = []    # per body: bytes, gzip (its span)
+        self.errors: List[Exception] = []
+        self.wall = None              # the egress_post_wall phase
+
+    def hand_off(self, parts: List[bytes]) -> None:
+        """A full body with more parts to come (the encoder's `emit`,
+        on the encoding thread): queue it, and start one more worker
+        while fewer than `num_workers` run. Never waits."""
+        self._open_wall()
+        self.queue.put(parts)
+        if len(self.workers) < max(self.sink.num_workers, 1):
+            worker = threading.Thread(
+                target=self._work, daemon=True,
+                name=f"{self.sink.name()}-post-{len(self.workers)}")
+            worker.start()
+            self.workers.append(worker)
+
+    def finish(self, encode: dict, rest: List[bytes]) -> float:
+        """After the encode (its closed span): send the last body,
+        through the workers if any run and else from this thread, wait
+        for every answer and close the spans. -> the tail's seconds."""
+        body = self._join(rest) if rest and not self.workers else None
+        with self.rnd.phase("egress_post_tail", parent="sink") as tail:
+            if body is not None:
+                self._open_wall()
+                self._post(body)
+            else:
+                if rest:
+                    self.queue.put(rest)
+                for _ in self.workers:
+                    self.queue.put(None)
+                for worker in self.workers:
+                    worker.join()
+        if self.wall is not None:
+            encode_end_s = encode["start_s"] + encode["wall_s"]
+            gzips = [sent["gzip"] for sent in self.sent if "gzip" in sent]
+            self.wall.stop().update(
+                bodies=len(self.sent),
+                bytes=sum(sent["bytes"] for sent in self.sent),
+                gzip_bytes=sum(g.get("bytes", 0) for g in gzips),
+                bodies_overlapped=sum(
+                    1 for g in gzips if g["start_s"] < encode_end_s))
+        return tail["wall_s"]
+
+    def _open_wall(self) -> None:
+        if self.wall is None:
+            # ends after spans of this thread that began inside it
+            self.wall = self.rnd.phase(
+                "egress_post_wall", parent="sink").start(handoff=True)
+
+    def _work(self) -> None:
+        for parts in iter(self.queue.get, None):
+            try:
+                self._post(self._join(parts))
+            except Exception as e:
+                logger.exception("datadog POST worker failed on a body")
+                self.errors.append(e)
+
+    def _join(self, parts: List[bytes]) -> bytes:
+        with self.rnd.phase("egress_join", parent="sink"):
+            return b'{"series":[' + b",".join(parts) + b"]}"
+
+    def _post(self, body: bytes) -> None:
+        sent = {"bytes": len(body)}
+        self.sent.append(sent)
+
+        def timed(name: str):
+            """vhttp.post's "gzip" and "http", as spans of the round."""
+            phase = self.rnd.phase("egress_" + name,
+                                   parent="egress_post_wall")
+            sent[name] = phase.rec
+            return phase
+
+        self.sink._post_series_body_safe(body, timed)
 
 
 # timestamp plausibility window, adapted to this pipeline's nanosecond
