@@ -19,6 +19,14 @@ for the comparison to be exact.
     python chip_smoke.py             one chip (what the driver runs)
     python chip_smoke.py --chips 4   only the sharded phase and its control
 
+The timer-driven sharded flush (`tpu.shards: 4` under the flush loop's
+own ticks, a Datadog sink, compared with the references at 100k keys) is
+the benchmark's business since PR 29: cell `global100k-interval-4chip`
+(`python3 benchmark/run.py --workload global100k-interval-4chip ...`).
+`--chips 4` keeps what that cell does not do: each shard's arrays on a
+device of its own, and the mesh against a one-shard control fed the same
+lines in one process, flushes called by hand.
+
 It exits non-zero on any failed phase, when JAX finds no TPU, and where
 the rest of the repo is missing. On success its last line is
 `{"ok": true, "device": {"platform": "tpu", "kind": ..., "count": N}}`.
@@ -689,8 +697,9 @@ def run_four_chips(seed: int, sizes: dict = SIZES) -> dict:
     """The same lines into a `tpu.shards: 4` server and a `shards: 1`
     control in this process: flushed series identical (the PR 11 pin;
     t-digest percentiles to float32 rounding), each shard's arrays on a
-    device of its own. Flushes are called
-    by hand here — the flush loop is the one-chip run's business."""
+    device of its own. Flushes are called by hand here: the sharded
+    flush under the loop's own ticks is the benchmark cell
+    `global100k-interval-4chip`'s business."""
     from veneur_tpu.ops import batch_tdigest
 
     devices = require_devices(4)[:4]
@@ -699,7 +708,9 @@ def run_four_chips(seed: int, sizes: dict = SIZES) -> dict:
     work = Workload(seed, sizes)
     log(f"{len(devices)} x {devices[0].platform} "
         f"{devices[0].device_kind!r}; {sum(sizes.values())} keys {sizes}; "
-        f"seed {seed}")
+        f"seed {seed}; placement and mesh against control, flushes by "
+        "hand (the timer-driven sharded flush is measured by the benchmark "
+        "cell global100k-interval-4chip)")
     servers = []
     try:
         for name, shards in (("mesh", 4), ("control", 1)):

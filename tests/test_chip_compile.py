@@ -151,3 +151,38 @@ def test_main_path_program_compiles_for_v5e(
     # an executable for the described TPU, not for the CPU this runs on
     assert "tpu" in compiled.as_text().lower()
     assert compiled.memory_analysis().temp_size_in_bytes >= 0
+
+
+# -- the four-shard deployment's two heaviest collectives -------------------
+#
+# `global100k-shards4` (benchmark/configs) merges, every flush, four
+# per-device t-digest grids of 32,768 rows and four HLL banks of 16,384 x
+# 16,384 int8 over the shard axis. Both at the cell's own shapes, for the
+# four described chips, so that neither can stop compiling (or stop
+# fitting a chip's 16 GB) unseen. No more than these two (D18).
+
+@pytest.mark.parametrize("name,init,rows", [
+    ("merge_histo_stacked", batch_tdigest.init_state, 32768),
+    ("merge_hll_stacked", batch_hll.init_state, 16384)])
+def test_collective_merge_compiles_for_four_v5e_chips(
+        topo, tpu_segment_reduce, name, init, rows):
+    from veneur_tpu.parallel import collectives
+
+    n = len(topo.devices)
+    assert n == 4
+    stacked_on = collectives.shard_sharding(
+        collectives.local_mesh(topo.devices))
+    stacked = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct((n,) + a.shape, a.dtype,
+                                       sharding=stacked_on),
+        jax.eval_shape(lambda: init(rows)))
+    compiled = getattr(collectives, name).lower(stacked).compile()
+    text = compiled.as_text()
+    assert "tpu" in text.lower()
+    # a reduction over the shard axis: the chips exchange state
+    assert any(op in text for op in ("all-reduce", "all-gather",
+                                     "all-to-all", "collective-permute"))
+    mem = compiled.memory_analysis()
+    per_chip = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+                + mem.temp_size_in_bytes)
+    assert 0 < per_chip < 16 * 2**30, per_chip

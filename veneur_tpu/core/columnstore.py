@@ -461,11 +461,14 @@ class _BaseTable:
     def _fresh_state_at(self, capacity: int):
         raise NotImplementedError
 
-    def readout(self, snap: dict) -> dict:
+    def readout(self, snap: dict, timing=None) -> dict:
         """Background flush half: apply the snap's final pending columns
         to the captured generation and dispatch its readout kernels.
         Touches no live table state beyond monotonic telemetry counters,
-        so it needs no locks and may run concurrently with ingest."""
+        so it needs no locks and may run concurrently with ingest.
+        `timing` is the flush round whose `dispatch{family}` span the
+        call runs under: a sharded table hangs its `merge` span there
+        (core/sharded_tables.py); a one-device table has no use for it."""
         if "state" not in snap:
             return snap  # idle fast path: nothing was swapped
         state = snap.pop("state")

@@ -619,18 +619,18 @@ def readout_columnstore(
     # (the per-family dispatch spans are back-to-back, so their sum IS
     # the dispatch_s total)
     with dispatching("histogram"):
-        h_snap = store.histos.readout(swap["histogram"])
+        h_snap = store.histos.readout(swap["histogram"], timing)
     with dispatching("counter"):
-        c_snap = store.counters.readout(swap["counter"])
+        c_snap = store.counters.readout(swap["counter"], timing)
     with dispatching("gauge"):
-        g_snap = store.gauges.readout(swap["gauge"])
+        g_snap = store.gauges.readout(swap["gauge"], timing)
     with dispatching("llhist"):
-        ll_snap = store.llhists.readout(swap["llhist"])
+        ll_snap = store.llhists.readout(swap["llhist"], timing)
     # sets are host-dominant (the sparse set path only touches the
     # device when rows promoted this interval): the estimate realizes
     # eagerly inside readout
     with dispatching("set"):
-        set_snap = store.sets.readout(swap["set"])
+        set_snap = store.sets.readout(swap["set"], timing)
         estimates, registers, s_touched, s_meta = \
             store.sets.snapshot_finish(set_snap)
     with dispatching("status"):

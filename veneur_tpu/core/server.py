@@ -1560,6 +1560,24 @@ class Server:
             flush_columnstore_batch(
                 scratch, self.is_local, self.percentiles, self.aggregates,
                 collect_forward=self.forwarder is not None)
+            if scratch.shard_plane is not None:
+                # a sharded store's empty flush is idle and reaches no
+                # collective merge. Compile each family's masked apply,
+                # merge + readout and zeroing kernels here, where the
+                # flush watchdog does not count: cold, the t-digest
+                # merge alone compiles for longer than a watchdog allows
+                # a flush, and the first flush with data would otherwise
+                # do it under the flush lock
+                from veneur_tpu.core.flushexec import PREWARM_FAMILIES
+                full_ps = tuple(self.percentiles)
+                all_ps = tuple(sorted(set(full_ps) | {0.5}))
+                need_export = self.is_local and self.forwarder is not None
+                for family, table in scratch.tables():
+                    if family in PREWARM_FAMILIES:
+                        table.prewarm_rung(
+                            table.capacity,
+                            all_ps if family == "histogram" else full_ps,
+                            need_export=need_export)
         except Exception:
             logger.exception("kernel warmup failed")
         finally:

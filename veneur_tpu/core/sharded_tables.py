@@ -40,6 +40,7 @@ Enable with config `tpu.shards: N` (0/1 = single-device tables).
 
 from __future__ import annotations
 
+import contextlib
 import logging
 import time
 from typing import Dict, List, Optional, Tuple
@@ -52,6 +53,7 @@ from veneur_tpu.core.columnstore import (CounterTable, GaugeTable,
                                          HistoTable, LLHistTable, PAD_ROW,
                                          SetTable, _BaseTable,
                                          _SetRegisters, _zeros_like_spare)
+from veneur_tpu.core.telemetry import FlushRound
 from veneur_tpu.ops import batch_hll, batch_llhist, batch_tdigest, scalars
 from veneur_tpu.parallel import collectives
 from veneur_tpu.parallel.collectives import SHARD_AXIS
@@ -132,11 +134,13 @@ class _DigestRouted:
         # dispatch accounting — the batch is all-PAD throwaway
         return self._apply_cols_state(state, cols, note=False)
 
-    def _stacked_batch(self, rows: np.ndarray, value_cols: Tuple
-                       ) -> Tuple:
+    def _stacked_batch(self, rows: np.ndarray, value_cols: Tuple,
+                       note: bool = True) -> Tuple:
         """Masked (n, batch) row column + tiled value columns for one
-        fixed-shape stacked dispatch, plus the per-shard sample counts
-        for the plane's accounting."""
+        fixed-shape stacked dispatch. With `note` the plane books the
+        per-shard sample counts and the wall of this routing (mask,
+        tile, device_put): `ingest.shard.route_seconds_total`."""
+        t0 = time.perf_counter()
         home = self._home_of(np.asarray(rows))
         srows = collectives.mask_batch_for_shards(
             home, self._n_shards, np.asarray(rows))
@@ -144,9 +148,33 @@ class _DigestRouted:
             np.ascontiguousarray(
                 collectives.tile_batch(self._n_shards, np.asarray(c)))
             for c in value_cols)
-        return (self._put_sharded(srows),
-                tuple(self._put_sharded(t) for t in tiled),
-                self._shard_counts_of(home))
+        out = (self._put_sharded(srows),
+               tuple(self._put_sharded(t) for t in tiled))
+        if note:
+            self._plane.note_routed(self.family, self._shard_counts_of(home),
+                                    time.perf_counter() - t0)
+        return out
+
+    @contextlib.contextmanager
+    def _merging(self, snap: dict):
+        """One collective merge of a flush readout: a `merge{family}`
+        span of the round the readout runs for, child of its
+        `dispatch{family}`; the `device.kernel.merge_s` row is fed from
+        the span's own clock. A readout nobody times (a hand-called
+        `snapshot_and_reset`) gets a round of its own."""
+        timing = snap.pop("_timing", None) or FlushRound()
+        with timing.phase("merge", parent="dispatch",
+                          family=self.family) as span:
+            yield
+        obs = self._deviceobs
+        if obs is not None:
+            obs.note_kernel("merge", self.family, span["wall_s"])
+        self._plane.note_merge_round()
+
+    def readout(self, snap: dict, timing=None) -> dict:
+        if timing is not None and "state" in snap:
+            snap["_timing"] = timing
+        return super().readout(snap)
 
     # -- elastic resharding (parallel/reshard.py) ------------------------
 
@@ -174,12 +202,6 @@ class _DigestRouted:
                 obs.drop(tok)
             return
         super().recycle(snap)
-
-    def _devobs_note_merge(self, seconds: float) -> None:
-        """Kernel-registry row for one collective merge dispatch."""
-        obs = self._deviceobs
-        if obs is not None:
-            obs.note_kernel("merge", self.family, seconds)
 
     def reshard_swap(self, new_plane: ShardedServingPlane, **kw) -> dict:
         """The per-family cutover primitive: ONE critical section that
@@ -323,21 +345,18 @@ class ShardedCounterTable(_DigestRouted, CounterTable):
 
     def _apply_cols_state(self, state, cols, note: bool = True):
         rows, vals, rates = cols
-        srows, (svals, srates), counts = self._stacked_batch(
-            rows, (vals, rates))
-        if note:
-            self._plane.note_routed(self.family, counts)
+        srows, (svals, srates) = self._stacked_batch(
+            rows, (vals, rates), note)
         return collectives.apply_counters_sharded(
             state, srows, svals, srates)
 
     def _readout_device(self, state, snap) -> None:
         """Fused donated collective merge: the drained stacked
         generation's buffers come back as the next interval's spare."""
-        t0 = time.perf_counter()
-        snap["dev"], snap["_spare"] = \
-            collectives.merge_counters_stacked_reset(state)
-        self._devobs_note_merge(time.perf_counter() - t0)
-        self._plane.note_merge_round()
+        with self._merging(snap):
+            snap["dev"], snap["_spare"] = \
+                collectives.merge_counters_stacked_reset(
+                    state, self._shard_sharding)
 
     def _query_readout_device(self, state, snap) -> None:
         # read-only merge over the LIVE stacked generation: the fused
@@ -348,7 +367,8 @@ class ShardedCounterTable(_DigestRouted, CounterTable):
         self._plane.note_merge_round()
 
     def _prewarm_readout(self, state, capacity, ps, need_export):
-        return collectives.merge_counters_stacked_reset(state)
+        return collectives.merge_counters_stacked_reset(
+            state, self._shard_sharding)
 
     def _reshard_capture_device(self, state, snap: dict) -> None:
         # psum selection, non-donating: (sum, comp) per row, the exact
@@ -388,9 +408,7 @@ class ShardedGaugeTable(_DigestRouted, GaugeTable):
 
     def _apply_cols_state(self, state, cols, note: bool = True):
         rows, vals = cols
-        srows, (svals,), counts = self._stacked_batch(rows, (vals,))
-        if note:
-            self._plane.note_routed(self.family, counts)
+        srows, (svals,) = self._stacked_batch(rows, (vals,), note)
         return collectives.apply_gauges_sharded(state, srows, svals)
 
     def merge_batch(self, stubs, values) -> None:
@@ -407,20 +425,20 @@ class ShardedGaugeTable(_DigestRouted, GaugeTable):
             self.apply_lock.acquire()
         try:
             if rows.size:
-                srows, (svals,), _counts = self._stacked_batch(
-                    rows, (np.asarray(values, np.float32)[ok],))
+                srows, (svals,) = self._stacked_batch(
+                    rows, (np.asarray(values, np.float32)[ok],),
+                    note=False)
                 self.state = collectives.merge_gauges_sharded(
                     self.state, srows, svals)
         finally:
             self.apply_lock.release()
 
     def _readout_device(self, state, snap) -> None:
-        t0 = time.perf_counter()
-        (dev, _set), snap["_spare"] = \
-            collectives.merge_gauges_stacked_reset(state)
-        self._devobs_note_merge(time.perf_counter() - t0)
+        with self._merging(snap):
+            (dev, _set), snap["_spare"] = \
+                collectives.merge_gauges_stacked_reset(
+                    state, self._shard_sharding)
         snap["dev"] = dev
-        self._plane.note_merge_round()
 
     def _query_readout_device(self, state, snap) -> None:
         # non-donating LWW merge (see ShardedCounterTable note)
@@ -429,7 +447,8 @@ class ShardedGaugeTable(_DigestRouted, GaugeTable):
         self._plane.note_merge_round()
 
     def _prewarm_readout(self, state, capacity, ps, need_export):
-        return collectives.merge_gauges_stacked_reset(state)
+        return collectives.merge_gauges_stacked_reset(
+            state, self._shard_sharding)
 
     def _reshard_capture_device(self, state, snap: dict) -> None:
         # home-shard LWW selection, non-donating; the set mask rides
@@ -472,10 +491,8 @@ class ShardedLLHistTable(_DigestRouted, LLHistTable):
 
     def _apply_cols_state(self, state, cols, note: bool = True):
         rows, bins, wts = cols
-        srows, (sbins, swts), counts = self._stacked_batch(
-            rows, (bins, wts))
-        if note:
-            self._plane.note_routed(self.family, counts)
+        srows, (sbins, swts) = self._stacked_batch(
+            rows, (bins, wts), note)
         return collectives.apply_llhist_sharded(state, srows, sbins, swts)
 
     def merge_batch(self, stubs, in_bins) -> None:
@@ -504,11 +521,10 @@ class ShardedLLHistTable(_DigestRouted, LLHistTable):
             self.apply_lock.release()
 
     def _readout_device(self, state, snap) -> None:
-        t0 = time.perf_counter()
-        merged, snap["_spare"] = \
-            collectives.merge_llhist_stacked_reset(state)
-        self._devobs_note_merge(time.perf_counter() - t0)
-        self._plane.note_merge_round()
+        with self._merging(snap):
+            merged, snap["_spare"] = \
+                collectives.merge_llhist_stacked_reset(
+                    state, self._shard_sharding)
         packed = batch_llhist.flush_packed(merged, snap["ps"])
         rows = np.flatnonzero(snap["touched"])
         bins_dev = None
@@ -533,7 +549,8 @@ class ShardedLLHistTable(_DigestRouted, LLHistTable):
         snap["bins_dev"] = bins_dev
 
     def _prewarm_readout(self, state, capacity, ps, need_export):
-        merged, fresh = collectives.merge_llhist_stacked_reset(state)
+        merged, fresh = collectives.merge_llhist_stacked_reset(
+            state, self._shard_sharding)
         return (batch_llhist.flush_packed(merged, ps), fresh)
 
     def _reshard_capture_device(self, state, snap: dict) -> None:
@@ -619,11 +636,12 @@ class ShardedHistoTable(_PerDeviceStates, _DigestRouted, HistoTable):
                 for d in self._devices]
 
     def _apply_to_shard(self, states, shard_counts, i: int, rows, vals,
-                        wts) -> None:
+                        wts) -> float:
         """One shard's masked fixed-shape batch apply over an explicit
         (states, staging-occupancy) generation — the live path passes
         the table's own, the flush readout the captured one; handles
-        the per-shard staging compact."""
+        the per-shard staging compact. Returns the wall of placing the
+        batch on the shard's device (routing, not apply)."""
         dev = self._devices[i]
         slots, overflow = batch_tdigest.host_slots(
             rows, vals, wts, shard_counts[i])
@@ -632,10 +650,11 @@ class ShardedHistoTable(_PerDeviceStates, _DigestRouted, HistoTable):
             shard_counts[i][:] = 0
             slots, _ = batch_tdigest.host_slots(
                 rows, vals, wts, shard_counts[i])
-        states[i] = batch_tdigest.apply_batch(
-            states[i], jax.device_put(rows, dev),
-            jax.device_put(vals, dev), jax.device_put(wts, dev),
-            jax.device_put(slots, dev))
+        t0 = time.perf_counter()
+        placed = [jax.device_put(c, dev) for c in (rows, vals, wts, slots)]
+        route_s = time.perf_counter() - t0
+        states[i] = batch_tdigest.apply_batch(states[i], *placed)
+        return route_s
 
     def _apply_cols_states(self, states, shard_counts, cols) -> None:
         rows, vals, wts = cols
@@ -645,15 +664,19 @@ class ShardedHistoTable(_PerDeviceStates, _DigestRouted, HistoTable):
             self._rr_next = (i + 1) % self._n_shards
             self._apply_to_shard(states, shard_counts, i, rows, vals, wts)
             return
+        t0 = time.perf_counter()
         home = self._home_of(rows)
         counts = self._shard_counts_of(home)
+        route_s = time.perf_counter() - t0
         for i in np.flatnonzero(counts).tolist():
             # masked, not split: the kernels' compiled (batch_cap,)
             # shape is preserved; non-home rows scatter-drop
+            t0 = time.perf_counter()
             rows_i = np.where(home == i, rows, PAD_ROW)
-            self._apply_to_shard(states, shard_counts, i, rows_i, vals,
-                                 wts)
-        self._plane.note_routed(self.family, counts)
+            route_s += time.perf_counter() - t0
+            route_s += self._apply_to_shard(states, shard_counts, i,
+                                            rows_i, vals, wts)
+        self._plane.note_routed(self.family, counts, route_s)
 
     def _apply_cols(self, cols):
         self._apply_cols_states(self.states, self._shard_counts, cols)
@@ -716,9 +739,8 @@ class ShardedHistoTable(_PerDeviceStates, _DigestRouted, HistoTable):
         return states
 
     def _readout_device(self, states, snap: dict) -> None:
-        t0 = time.perf_counter()
-        merged = self._merged_state(states)
-        self._devobs_note_merge(time.perf_counter() - t0)
+        with self._merging(snap):
+            merged = self._merged_state(states, note=False)
         ps = snap["ps"]
         if snap.pop("need_export"):
             # fused flush+export: one dispatch, two transfers (the
@@ -739,6 +761,11 @@ class ShardedHistoTable(_PerDeviceStates, _DigestRouted, HistoTable):
         rows, vals, wts = cols
         for i in range(self._n_shards):
             self._apply_to_shard(states, counts, i, rows, vals, wts)
+            # the staging compact too: an all-padding batch overflows
+            # nothing, and the first hot key would compile it (tens of
+            # seconds cold at 32k rows) under the apply lock, or under
+            # the flush lock when the readout's last batch overflows
+            states[i] = batch_tdigest.compact(states[i])
         return states
 
     def _prewarm_readout(self, states, capacity: int, ps: tuple,
@@ -848,15 +875,18 @@ class ShardedSetTable(_PerDeviceStates, _DigestRouted, SetTable):
             r, ix, rh = (jax.device_put(c, dev) for c in cols)
             states[i] = batch_hll.apply_batch(states[i], r, ix, rh)
             return
+        t0 = time.perf_counter()
         home = self._home_of(rows)
         counts = self._shard_counts_of(home)
+        route_s = time.perf_counter() - t0
         for i in np.flatnonzero(counts).tolist():
             dev = self._devices[i]
-            rows_i = np.where(home == i, rows, PAD_ROW)
-            states[i] = batch_hll.apply_batch(
-                states[i], jax.device_put(rows_i, dev),
-                jax.device_put(idxs, dev), jax.device_put(rhos, dev))
-        self._plane.note_routed(self.family, counts)
+            t0 = time.perf_counter()
+            placed = [jax.device_put(c, dev) for c in
+                      (np.where(home == i, rows, PAD_ROW), idxs, rhos)]
+            route_s += time.perf_counter() - t0
+            states[i] = batch_hll.apply_batch(states[i], *placed)
+        self._plane.note_routed(self.family, counts, route_s)
 
     def _apply_cols(self, cols):
         self._apply_cols_states(self.states, cols)
@@ -898,9 +928,8 @@ class ShardedSetTable(_PerDeviceStates, _DigestRouted, SetTable):
         return collectives.merge_hll_stacked(stacked)
 
     def _readout_device(self, states, snap: dict) -> None:
-        t0 = time.perf_counter()
-        merged = self._merged_state(states)
-        self._devobs_note_merge(time.perf_counter() - t0)
+        with self._merging(snap):
+            merged = self._merged_state(states, note=False)
         snap["estimates"] = np.asarray(batch_hll.estimate(merged))
         # lazy per-row provider (columnstore._SetRegisters): the
         # merged (K, M) bank only crosses the device link if a

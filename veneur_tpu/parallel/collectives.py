@@ -218,33 +218,43 @@ def merge_llhist_rows_at(regs, shard_ids, rows, in_rows):
 # and the merged readout leaves the swapped-out state's HBM in place.
 
 
-def _zeros_tree(state):
-    return jax.tree.map(jnp.zeros_like, state)
+def _zeros_tree(state, sharding: NamedSharding):
+    """The fresh generation, laid out as the donated one was: one shard
+    per device. Left to the compiler, a zero constant comes back
+    replicated: every device would then hold and scatter all n shards
+    from the second interval on, under a recompiled apply, and the
+    interval merge would reduce nothing across devices."""
+    return jax.tree.map(
+        lambda leaf: jax.lax.with_sharding_constraint(
+            jnp.zeros_like(leaf), sharding), state)
 
 
-@partial(jax.jit, donate_argnums=0)
+@partial(jax.jit, donate_argnums=0, static_argnums=1)
 @device_scope("merge", "counter")
-def merge_counters_stacked_reset(state):
+def merge_counters_stacked_reset(state, sharding: NamedSharding):
     """Fused donated interval merge: (merged Kahan pair, fresh zeroed
-    stacked generation aliasing the donated input)."""
+    stacked generation aliasing the donated input). `sharding` is the
+    stacked layout (`shard_sharding(mesh)`), static."""
     merged = (jnp.sum(state["sum"], axis=0), jnp.sum(state["comp"], axis=0))
-    return merged, _zeros_tree(state)
+    return merged, _zeros_tree(state, sharding)
 
 
-@partial(jax.jit, donate_argnums=0)
+@partial(jax.jit, donate_argnums=0, static_argnums=1)
 @device_scope("merge", "gauge")
-def merge_gauges_stacked_reset(state):
+def merge_gauges_stacked_reset(state, sharding: NamedSharding):
     """Fused donated LWW merge: ((value, set), fresh generation)."""
     value = jnp.sum(jnp.where(state["set"], state["value"], 0.0), axis=0)
-    return (value, jnp.any(state["set"], axis=0)), _zeros_tree(state)
+    return ((value, jnp.any(state["set"], axis=0)),
+            _zeros_tree(state, sharding))
 
 
-@partial(jax.jit, donate_argnums=0)
+@partial(jax.jit, donate_argnums=0, static_argnums=1)
 @device_scope("merge", "llhist")
-def merge_llhist_stacked_reset(stacked: jnp.ndarray):
+def merge_llhist_stacked_reset(stacked: jnp.ndarray,
+                               sharding: NamedSharding):
     """Fused donated register-ADD merge: ((K, BINS_PAD) merged
     registers, fresh stacked generation)."""
-    return jnp.sum(stacked, axis=0), _zeros_tree(stacked)
+    return jnp.sum(stacked, axis=0), _zeros_tree(stacked, sharding)
 
 @jax.jit
 @device_scope("merge", "counter")

@@ -11,9 +11,13 @@ is what keeps the llhist/HLL registers bit-identical to a single-device
 table — the PR-5 exactness pin generalized to the mesh.
 
 The plane is also the mesh's self-telemetry root: `mesh.*` rows
-describe the topology, `shard.*` rows the per-shard routing volume, so
-an operator can see a skewed key space (one hot shard) or a dead chip
-(a shard's routed-sample counter flatlining) straight off /metrics.
+describe the topology (`mesh.merge_rounds`: one per family per flush
+that had a generation to merge), `shard.*` rows the per-shard routing
+volume, so an operator can see a skewed key space (one hot shard) or a
+dead chip (a shard's routed-sample counter flatlining) straight off
+/metrics; `ingest.shard.route_seconds_total{family}` is the host wall of
+routing batches to shards (mask, tile, `device_put`), which one device
+never pays.
 """
 
 from __future__ import annotations
@@ -61,6 +65,9 @@ class ShardedServingPlane:
         # numpy read-modify-write adds now need their own leaf lock
         # (scrapes stay lock-free point reads — one row stale at worst)
         self._samples: Dict[str, np.ndarray] = {}
+        # wall seconds spent routing batches to shards (mask, tile,
+        # device_put), per family: host work one device never pays
+        self._route_s: Dict[str, float] = {}
         self._acc_lock = threading.Lock()
         self.batches_dispatched = 0
         self.merge_rounds = 0
@@ -80,15 +87,18 @@ class ShardedServingPlane:
 
     # -- accounting ------------------------------------------------------
 
-    def note_routed(self, family: str, per_shard_counts) -> None:
-        """Fold one dispatch's per-shard sample counts (len n array).
-        Thread-safe: called from ingest (under table locks) AND from
-        the background flush readout (lock-free by design)."""
+    def note_routed(self, family: str, per_shard_counts,
+                    route_s: float) -> None:
+        """Fold one dispatch's per-shard sample counts (len n array)
+        and the wall its routing took. Thread-safe: called from ingest
+        (under table locks) AND from the background flush readout
+        (lock-free by design)."""
         with self._acc_lock:
             acc = self._samples.get(family)
             if acc is None:
                 acc = self._samples[family] = np.zeros(self.n, np.int64)
             acc += np.asarray(per_shard_counts, np.int64)
+            self._route_s[family] = self._route_s.get(family, 0.0) + route_s
             self.batches_dispatched += 1
 
     def note_merge_round(self) -> None:
@@ -118,6 +128,9 @@ class ShardedServingPlane:
                 rows.append(("shard.samples_routed", "counter",
                              float(count),
                              [f"family:{family}", f"shard:{shard}"]))
+        for family, seconds in list(self._route_s.items()):
+            rows.append(("ingest.shard.route_seconds_total", "counter",
+                         seconds, [f"family:{family}"]))
         return rows
 
 
