@@ -1554,22 +1554,22 @@ def run_scenario_egress(duration_s: float, num_keys: int = 100_000):
 
     sec_c = section("c", n_half, MetricType.COUNTER)
     sec_g = section("g", num_keys - n_half, MetricType.GAUGE)
-    # llhist bucket matrix: 2% of keys are histograms, ~16 occupied bins
-    # each — the cumsum table the encoders splice `le:` rows from
+    # llhist buckets: 2% of keys are histograms, 16 occupied bins each
+    # — the CSR entries the encoders splice `le:` rows from
     n_hist = max(num_keys // 50, 1)
     bins = len(llhist_ref.UPPER_SORTED)
-    counts = np.zeros((n_hist, bins))
-    for i in range(n_hist):
-        occ = rng.choice(bins, size=16, replace=False)
-        counts[i, occ] = rng.integers(1, 50, size=16)
+    occ = np.stack([rng.choice(bins, size=16, replace=False)
+                    for _ in range(n_hist)])
+    indptr, le_idx, cum, total = llhist_ref.cumulative_entries(
+        np.repeat(np.arange(n_hist), 16), occ.ravel(),
+        rng.integers(1, 50, size=n_hist * 16), n_hist)
     bnames = np.empty(n_hist, object)
     btags = np.empty(n_hist, object)
     for i in range(n_hist):
         bnames[i] = f"bench.ll.{i}.bucket"
         btags[i] = [f"env:prod", f"shard:{i % 64}"]
-    bucket = BucketSection(bnames, btags,
-                           np.cumsum(counts, axis=1, dtype=np.float64),
-                           counts != 0)
+    bucket = BucketSection(bnames, btags, indptr, le_idx,
+                           cum.astype(np.float64), total.astype(np.float64))
     batch = FlushBatch(int(time.time()), [sec_c, sec_g], [], [bucket])
     lines = len(batch)
 

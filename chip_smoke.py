@@ -467,7 +467,8 @@ def compare_interval(work: Workload, truth: dict, got: dict) -> dict:
             off_by_one += 1
 
     # llhist against ops/llhist_ref.py: registers are integers, so the
-    # count, the midpoint sum and the +Inf bucket are exact; quantiles
+    # count and the +Inf bucket are exact, and so is the midpoint sum
+    # (one definition, llhist_ref.entry_sums, on both sides); quantiles
     # at tests/test_llhist.py's rtol
     for i, vals in truth["llhists"].items():
         name = names["llhist"][i]
@@ -476,7 +477,7 @@ def compare_interval(work: Workload, truth: dict, got: dict) -> dict:
         check(value(f"{name}.count") == float(ref.count())
               and value(f"{name}.bucket|le:+Inf") == float(ref.count()),
               f"interval {k}: {name} count differs")
-        check(np.isclose(value(f"{name}.sum"), ref.sum(), rtol=1e-12),
+        check(value(f"{name}.sum") == ref.sum(),
               f"interval {k}: {name} sum differs")
         for p, want in zip(PERCENTILES, ref.quantiles(PERCENTILES)):
             have = value(f"{name}.{int(p * 100)}percentile")
@@ -776,9 +777,9 @@ def run_four_chips(seed: int, sizes: dict = SIZES) -> dict:
             check(arrived, f"round {k - 1}: datagrams lost: sent "
                   f"{truth['lines']} lines, received {lines}")
             # counters, gauges, sets and llhists merge by selection or
-            # integer addition: bit for bit (an llhist's .sum is a
-            # host-side float64 dot over those registers, whose
-            # summation order follows the array's layout: 1e-12). A
+            # integer addition: bit for bit (an llhist's .sum too: it
+            # is llhist_ref.entry_sums over those registers, in an
+            # order that no layout changes). A
             # t-digest's cross-shard merge re-compresses, so its
             # percentiles are held to a float32 tolerance
             # (tests/test_reshard.py's kind of pin) — except the keys hot
@@ -804,8 +805,6 @@ def run_four_chips(seed: int, sizes: dict = SIZES) -> dict:
                 elif (n.startswith("smoke.timer.")
                       and n.endswith("percentile")):
                     same = np.isclose(got_mesh[n], v, rtol=1e-5, atol=0.0)
-                elif n.startswith("smoke.llhist.") and n.endswith(".sum"):
-                    same = np.isclose(got_mesh[n], v, rtol=1e-12, atol=0.0)
                 else:
                     same = got_mesh[n] == v
                 if not same:

@@ -15,6 +15,7 @@ rows, and WAL-backfilled timestamp lines.
 from __future__ import annotations
 
 import json
+import struct
 import threading
 
 import numpy as np
@@ -25,7 +26,9 @@ from veneur_tpu.core.egress import (
     CortexColumnarEncoder, DatadogColumnarEncoder,
     PrometheusColumnarRenderer,
 )
-from veneur_tpu.core.flusher import flush_columnstore_batch
+from veneur_tpu.core.flusher import (
+    flush_columnstore, flush_columnstore_batch)
+from veneur_tpu.ops import llhist_ref
 from veneur_tpu.samplers.metrics import (
     HistogramAggregates, InterMetric, MetricType,
 )
@@ -556,3 +559,129 @@ def test_egress_parity_soak():
             [cx._series(m) for m in legacy
              if m.type != MetricType.STATUS])
         assert b"".join(frames) == want
+
+
+# -- llhist registers as their nonzero bins, at every density --------------
+
+def _feed_lines(store, lines):
+    p = Parser()
+    for line in lines:
+        p.parse_metric_fast(line, store.process)
+    store.apply_all_pending()
+
+
+def _feed_six_samples_a_row(store):
+    rng = np.random.default_rng(11)
+    _feed_lines(store, [b"six.%d:%r|l|#env:t,k:%d" % (i, float(v), i)
+                        for i in range(40)
+                        for v in rng.lognormal(1.0, 2.0, 6)])
+
+
+def _feed_one_dense_row(store):
+    # a value in each of 2,300 bins (1,300 positive, 1,000 negative) for
+    # one key, among keys with a bin or two
+    mids = np.concatenate([
+        llhist_ref.BIN_MID[llhist_ref.POS_BASE + 400:][:1300],
+        llhist_ref.BIN_MID[llhist_ref.NEG_BASE + 700:][:1000]])
+    lines = [b"dense:%r|l|#env:t" % float(v) for v in mids]
+    lines += [b"dense:%r|l|#env:t" % float(v) for v in mids[::7]]
+    for i in range(12):
+        lines += [b"thin.%d:%d|l|#env:t" % (i, i + 1),
+                  b"thin.%d:%d.5|l|#env:t" % (i, 10 * i)]
+    _feed_lines(store, lines)
+
+
+def _feed_negative_zero_clamped(store):
+    _feed_lines(store, [
+        b"neg:-3.5|l|#env:t", b"neg:-3.5|l|#env:t", b"neg:-120|l|#env:t",
+        b"neg:7|l|#env:t|@0.25",
+        b"zero:0|l", b"zero:-0.0|l", b"zero:1e-12|l", b"zero:-1e-14|l",
+        b"top:1e20|l|#env:t", b"top:-1e20|l|#env:t", b"top:9.99e15|l|#env:t",
+        b"rate:2.5|l|@0.001", b"rate:-2.5|l|@0.5"])
+
+
+def _feed_touched_but_reclaimed(store):
+    # a straggler batch lands on a row that idle reclamation has already
+    # recycled: touched, with registers, and no meta to name it
+    _feed_lines(store, [b"gone:4|l|#env:t", b"kept:5|l|#env:t"])
+    [gone] = [r for r, m in enumerate(store.llhists.meta)
+              if m is not None and m.name == "gone"]
+    flush_columnstore_batch(store, False, PCTS, AGGS)
+    for _ in range(3):
+        _feed_lines(store, [b"kept:5|l|#env:t"])
+        store.llhists.reclaim_idle(1)
+        flush_columnstore_batch(store, False, PCTS, AGGS)
+    assert store.llhists.meta[gone] is None
+    _feed_lines(store, [b"kept:6|l|#env:t", b"kept:60|l|#env:t"])
+    store.llhists.add_batch(np.array([gone, gone], np.int32),
+                            np.array([4.0, 44.0]), np.ones(2))
+    store.apply_all_pending()
+
+
+def _feed_local_mixed_scope(store):
+    lines = []
+    for i in range(8):
+        lines += [b"mixed.%d:%d|l|#env:t" % (i, i + 1),
+                  b"mixed.%d:%d|l|#env:t" % (i, 100 * (i + 1)),
+                  b"mine.%d:%d.25|l|#env:t,veneurlocalonly" % (i, i),
+                  b"mine.%d:-%d|l|#env:t,veneurlocalonly" % (i, i + 2)]
+    lines += [b"theirs:8.5|l|#veneurglobalonly"]
+    _feed_lines(store, lines)
+
+
+def _exact(m):
+    return (m.name, tuple(m.tags), struct.pack("<d", m.value), int(m.type))
+
+
+@pytest.mark.parametrize("feed", [
+    _feed_six_samples_a_row, _feed_one_dense_row,
+    _feed_negative_zero_clamped, _feed_touched_but_reclaimed,
+    _feed_local_mixed_scope], ids=lambda f: f.__name__[len("_feed_"):])
+def test_llhist_nonzero_bins_match_the_per_row_oracle(feed):
+    """What the columnar flush builds from an llhist's nonzero registers
+    is what the per-row oracle builds from the whole row: names, tags,
+    `le:` bounds and every value bit for bit; and each columnar
+    encoder's bytes are the legacy encoder's."""
+    is_local = feed is _feed_local_mixed_scope
+    stores = [ColumnStore(llhist_capacity=64, batch_cap=256)
+              for _ in range(2)]
+    for store in stores:
+        feed(store)
+    final, fwd_l = flush_columnstore(stores[0], is_local, PCTS, AGGS,
+                                     collect_forward=is_local)
+    batch, fwd_b = flush_columnstore_batch(stores[1], is_local, PCTS, AGGS,
+                                           collect_forward=is_local)
+    legacy = batch.materialize()
+    assert len(batch) == len(legacy) == len(final)
+    assert sorted(map(_exact, legacy)) == sorted(map(_exact, final))
+    counts = {(m.name[:-len(".count")], tuple(m.tags)): m.value
+              for m in legacy if m.name.endswith(".count")}
+    infs = {(m.name[:-len(".bucket")], tuple(m.tags[:-1])): m.value
+            for m in legacy if m.tags[-1:] == ["le:+Inf"]}
+    assert counts == infs and counts
+    [section] = batch.bucket_sections
+    assert section.line_count() == sum(
+        m.name.endswith(".bucket") for m in final)
+    if feed is _feed_one_dense_row:
+        assert np.diff(section.indptr).max() >= 2000
+    # a local forwards whole rows, widened for the global's merge
+    assert [(meta.name, bins.dtype, bins.tolist())
+            for meta, bins in fwd_b.llhists] == [
+        (meta.name, np.dtype(np.int64), bins.tolist())
+        for meta, bins in fwd_l.llhists]
+    assert bool(fwd_b.llhists) == is_local
+
+    dd = _dd_sink()
+    parts, _checks = DatadogColumnarEncoder(dd).encode(batch)
+    assert [json.loads(p) for p in parts] == json.loads(json.dumps(
+        [dd._dd_metric(m) for m in legacy]))
+    assert PrometheusColumnarRenderer().render(batch) == \
+        render_exposition(legacy)
+    for mono in (False, True):
+        col, leg = (CortexMetricSink("cortex", "http://c/api", "myhost",
+                                     convert_counters_to_monotonic=mono)
+                    for _ in range(2))
+        frames, _max_ts = CortexColumnarEncoder(col).encode(batch)
+        assert b"".join(frames) == encode_write_request(
+            _cortex_series(leg, legacy))
+        assert col._monotonic == leg._monotonic

@@ -249,6 +249,18 @@ def test_flush_cpu_counts_each_thread_once(flushed):
     assert cpu <= root["wall_s"] * (2 + len(workers))
 
 
+@pytest.mark.parametrize("field", ["llhist_rows", "llhist_nonzero_bins"])
+def test_debug_flush_counts_the_llhists_nonzero_bins(flushed, field):
+    """The 60 llhist keys take one sample a round each: 60 rows leave
+    the readout as 60 nonzero registers (of 270,060), and the counter
+    on /metrics adds the same up with every readout delivered."""
+    assert flushed["round"][field] == 60
+    row = "veneur_flush_llhist_nonzero_bins_total"
+    first, second = flushed["scrapes"]
+    assert first[row] in (2 * 60, 3 * 60)  # `flush_async`: one behind
+    assert second[row] == first[row] + 60
+
+
 # -- (b) the spans form a tree on one clock --------------------------------
 
 def test_every_span_has_a_parent_that_exists(flushed):

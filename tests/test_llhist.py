@@ -403,6 +403,117 @@ class TestFlushEmission:
                 assert np.array_equal(b1, b2)
 
 
+def _sparse_table(rows: int, width: int, seed: int = 5) -> np.ndarray:
+    """An int32 register table as the device hands it over: six
+    lognormal samples a row (the benchmark's llhist traffic), some
+    negative."""
+    rng = np.random.default_rng(seed)
+    table = np.zeros((rows, width), np.int32)
+    vals = rng.lognormal(1.0, 2.0, (rows, 6)) * rng.choice(
+        [1.0, 1.0, -1.0], (rows, 6))
+    np.add.at(table, (np.repeat(np.arange(rows), 6),
+                      llhist_ref.bin_index(vals.ravel())), 1)
+    return table
+
+
+class TestSumDefinition:
+    """One definition of `.sum` (llhist_ref.entry_sums): it reads the
+    registers alone (ROADMAP D16)."""
+
+    @pytest.mark.parametrize("variant", [
+        "permuted_rows", "padded_width", "int64_registers", "row_by_row",
+        "split_over_shards"])
+    def test_sum_depends_on_the_registers_alone(self, variant):
+        table = _sparse_table(200, llhist_ref.BINS)
+        # one row with 1,125 live registers, of one sign: the BLAS
+        # comparison below cannot hold where +-1e15 bins cancel
+        table[7, 1:llhist_ref.NEG_BASE:2] = 9
+        want = llhist_ref.entry_sums(
+            *llhist_ref.nonzero_entries(table), table.shape[0])
+        if variant == "permuted_rows":
+            perm = np.random.default_rng(1).permutation(table.shape[0])
+            got = np.empty_like(want)
+            got[perm] = llhist_ref.entry_sums(
+                *llhist_ref.nonzero_entries(table[perm]), table.shape[0])
+        elif variant == "padded_width":
+            padded = batch_llhist.pad_rows_to_device(table)
+            assert padded.shape[1] == batch_llhist.BINS_PAD
+            got = llhist_ref.entry_sums(
+                *llhist_ref.nonzero_entries(padded), table.shape[0])
+        elif variant == "int64_registers":
+            got = llhist_ref.entry_sums(
+                *llhist_ref.nonzero_entries(table.astype(np.int64)),
+                table.shape[0])
+        elif variant == "row_by_row":
+            got = np.array([llhist_ref.approx_sum(row) for row in table])
+        else:
+            # registers merge exactly, so whatever parts a row was
+            # summed from, its sum is the whole row's
+            parts = np.random.default_rng(2).integers(0, 4, table.shape)
+            merged = sum((table * (parts == k)).astype(np.int64)
+                         for k in range(4))
+            got = llhist_ref.entry_sums(
+                *llhist_ref.nonzero_entries(merged), table.shape[0])
+        assert got.tobytes() == want.tobytes()
+        np.testing.assert_allclose(
+            want, table.astype(np.float64) @ llhist_ref.BIN_MID,
+            rtol=1e-12)
+
+    def test_cumulative_entries_are_the_rows_cumulative_buckets(self):
+        table = _sparse_table(50, batch_llhist.BINS_PAD)
+        table[3, :llhist_ref.BINS:2] = 4
+        table[9] = 0  # touched, nothing left: no entry, an +Inf of 0
+        rows, bins, counts = llhist_ref.nonzero_entries(table)
+        indptr, rank, cum, total = llhist_ref.cumulative_entries(
+            rows, bins, counts, table.shape[0])
+        assert cum.dtype == total.dtype == np.int64
+        for i, row in enumerate(table[:, :llhist_ref.BINS]):
+            upper, csum = llhist_ref.LLHist(row).cumulative_buckets()
+            lo, hi = indptr[i], indptr[i + 1]
+            assert llhist_ref.UPPER_SORTED[rank[lo:hi]].tolist() == \
+                upper.tolist()
+            assert cum[lo:hi].tolist() == csum.tolist()
+            assert total[i] == row.sum()
+
+    def test_assembly_allocates_no_dense_table(self):
+        """1,024 touched llhist rows flush without a (rows, BINS) array
+        beside the transferred int32 table: a float64 or int64 one is
+        36.9 MB, a bool one 4.6 MB."""
+        import tracemalloc
+
+        rows = 1024
+        store = ColumnStore(llhist_capacity=rows, batch_cap=8192)
+        table = store.llhists
+        p = Parser()
+        ids = []
+        for i in range(rows):
+            p.parse_metric_fast(b"ll.%d:1|l" % i,
+                                lambda mm: ids.append(table.intern(mm)))
+        ids = np.repeat(np.asarray(ids, np.int32), 6)
+        vals = np.random.default_rng(3).lognormal(1.0, 2.0, ids.size)
+
+        def one_flush():
+            table.add_batch(ids, vals, np.ones(ids.size))
+            store.apply_all_pending()
+            return flush_columnstore_batch(store, False, PCTS, AGGS)
+
+        one_flush()  # compile, fill the name and tag caches
+        transferred = rows * batch_llhist.BINS_PAD * 4
+        tracemalloc.start()
+        try:
+            batch, _fwd = one_flush()
+            _now, peak = tracemalloc.get_traced_memory()
+        finally:
+            tracemalloc.stop()
+        [section] = batch.bucket_sections
+        assert section.names.shape[0] == rows
+        assert 5000 < section.le_idx.shape[0] <= 6 * rows
+        # the transferred table itself is traced only where np.asarray
+        # of the device array copies (on the CPU backend it does not)
+        room = 3 * 2**20
+        assert peak < room or transferred <= peak < transferred + room, peak
+
+
 class TestCarryover:
     def test_merge_forwardable_llhists_sum(self):
         from veneur_tpu.core.columnstore import RowMeta

@@ -1994,18 +1994,21 @@ class LLHistTable(_BaseTable):
 
     @staticmethod
     def snapshot_finish(snap: dict):
-        """Returns (readout dict of np arrays over all rows, bins int64
+        """Returns (readout dict of np arrays over all rows, bins
         (n_touched, BINS) aligned with the touched rows in ascending
-        order, touched, meta)."""
+        order, touched, meta). The bins are the device's int32
+        registers as transferred (a view that leaves the padding out),
+        mostly zeros: the flush reads their nonzero entries
+        (`llhist_ref.nonzero_entries`), and a reader that sums or merges
+        whole rows widens the rows it takes."""
         if snap["packed"] is None:  # idle-family fast path
-            return ({}, np.zeros((0, llhist_ref.BINS), np.int64),
+            return ({}, np.zeros((0, llhist_ref.BINS), np.int32),
                     snap["touched"], snap["meta"])
         out = {k: np.asarray(v) for k, v in snap["packed"].items()}
         if snap["bins_dev"] is not None:
             bins = np.asarray(snap["bins_dev"])[:, :llhist_ref.BINS]
-            bins = bins.astype(np.int64)
         else:
-            bins = np.zeros((0, llhist_ref.BINS), np.int64)
+            bins = np.zeros((0, llhist_ref.BINS), np.int32)
         return out, bins, snap["touched"], snap["meta"]
 
     def snapshot_and_reset(self, percentiles: Tuple[float, ...],

@@ -10,8 +10,8 @@ Python was the last measured wall (BENCH_r05: `counter` 9.3k/s vs `hll`
   the tags-list object ref that RowMeta shares with every FlushSection —
   so a steady-state flush pays only value formatting + `b"".join`;
 * value columns format in bulk off the float64 arrays;
-* llhist cumulative buckets ride the BucketSection cumsum matrix — no
-  per-line recomputation.
+* llhist cumulative buckets ride the BucketSection's CSR entries (one
+  per nonzero register, already cumulative) — no per-line recomputation.
 
 Parity is pinned byte-for-byte against the legacy materialize() path by
 tests/test_egress.py (JSON key-order-normalized for Datadog, byte-identical
@@ -175,20 +175,13 @@ class DatadogColumnarEncoder:
         if batch.bucket_sections:
             les = _dd_le_json()
             for bs in batch.bucket_sections:
-                names = bs.names.tolist()
-                tagrows = bs.tags.tolist()
-                csum, nz = bs.csum, bs.nz
-                for i, nm in enumerate(names):
-                    _tags, prefix, has_tags = \
-                        self._frag(nm, tagrows[i], True)
+                for nm, tags, idxs, values in bs.rows(interval):
+                    _tags, prefix, has_tags = self._frag(nm, tags, True)
                     if prefix is None:
                         continue
-                    sep = b"," if has_tags else b""
-                    row = csum[i] / interval
-                    idxs = np.flatnonzero(nz[i]).tolist()
-                    vals_k = row[idxs].tolist() + [float(row[-1])]
-                    for k, v in zip(idxs + [-1], vals_k):
-                        parts.append(prefix + sep + les[k]
+                    head = prefix + b"," if has_tags else prefix
+                    for k, v in zip(idxs, values):
+                        parts.append(head + les[k]
                                      + b'],"points":[[' + ts_b + b","
                                      + _json_num(v).encode() + b"]]}")
                     parts = _emit_full(parts, per_body, emit)
@@ -291,22 +284,15 @@ class PrometheusColumnarRenderer:
             les = _prom_le_labels()
             le_tag_strs = le_tags()
             for bs in batch.bucket_sections:
-                names = bs.names.tolist()
-                tagrows = bs.tags.tolist()
-                csum, nz = bs.csum, bs.nz
-                for i, nm in enumerate(names):
+                for nm, tags, idxs, values in bs.rows():
                     sname = self._name(nm)
-                    interior = self._label_interior(tagrows[i])
+                    interior = self._label_interior(tags)
                     pre = "{" + interior + "," if interior else "{"
-                    row = csum[i]
-                    idxs = np.flatnonzero(nz[i]).tolist()
-                    vals_k = row[idxs].tolist() + [float(row[-1])]
-                    for k, v in zip(idxs + [-1], vals_k):
+                    for k, v in zip(idxs, values):
                         clause = ""
                         if exemplars is not None:
                             clause = exemplar_clause_for(
-                                _ExemplarProbe(
-                                    nm, tagrows[i] + [le_tag_strs[k]]),
+                                _ExemplarProbe(nm, tags + [le_tag_strs[k]]),
                                 exemplars, exemplified)
                         lines.append(f"{sname}{pre}{les[k]}}} "
                                      f"{v}{clause}")
@@ -472,31 +458,21 @@ class CortexColumnarEncoder:
             for bs in batch.bucket_sections:
                 if bs.names.shape[0] and ts > max_ts:
                     max_ts = ts
-                names = bs.names.tolist()
-                tagrows = bs.tags.tolist()
-                csum, nz = bs.csum, bs.nz
-                for i, nm in enumerate(names):
+                for nm, tags, idxs, values in bs.rows():
                     if mono:
-                        base = tagrows[i]
-                        row = csum[i]
-                        for k in np.flatnonzero(nz[i]).tolist():
-                            key = (nm, tuple(sorted(base + [le_strs[k]])),
+                        for k, v in zip(idxs, values):
+                            key = (nm, tuple(sorted(tags + [le_strs[k]])),
                                    "")
-                            monotonic[key] = (monotonic.get(key, 0.0)
-                                              + float(row[k]))
-                        key = (nm, tuple(sorted(base + ["le:+Inf"])), "")
-                        monotonic[key] = (monotonic.get(key, 0.0)
-                                          + float(row[-1]))
+                            monotonic[key] = monotonic.get(key, 0.0) + v
                         continue
-                    pre, post = self._bucket_block(nm, tagrows[i])
-                    row = csum[i]
-                    vrow = row.astype("<f8").tobytes()
-                    for k in np.flatnonzero(nz[i]).tolist() + [-1]:
+                    pre, post = self._bucket_block(nm, tags)
+                    vrow = struct.pack("<%dd" % len(values), *values)
+                    for j, k in enumerate(idxs):
                         body = (pre + les[k] + post + sample_hdr + b"\x09"
-                                + vrow[8 * k:8 * k + 8 or None] + ts_tail)
+                                + vrow[8 * j:8 * j + 8] + ts_tail)
                         if check_ex:
                             ex = self._exemplar(
-                                nm, tagrows[i] + [le_strs[k]], exemplified)
+                                nm, tags + [le_strs[k]], exemplified)
                             if ex is not None:
                                 body += _field_bytes(
                                     3, _encode_exemplar(*ex))

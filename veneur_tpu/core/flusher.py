@@ -107,23 +107,25 @@ def _flush_llhist_family(store, is_local: bool, percentiles, now: int,
     if rows.size == 0:
         return
     quants = out["quantiles"][rows]
-    # count and sum are derived from the HOST-side int64 bins, not the
-    # device readout: the count must equal the le:+Inf bucket exactly
-    # (both are the same registers), and f64 midpoint math keeps the
-    # sum consistent with what a downstream re-aggregation would get
-    counts = bins.sum(axis=1)
-    sums = bins.astype(np.float64) @ llhist_ref.BIN_MID
     order = llhist_ref.ORDER
     upper = llhist_ref.UPPER_SORTED
     for i, row in enumerate(rows.tolist()):
         meta = meta_list[row]
         if meta is None:  # recycled mid-interval (reclaim straggler)
             continue
+        regs = bins[i].astype(np.int64)  # the transferred row is int32
         scope = meta.scope
         if is_local and scope != MetricScope.LOCAL_ONLY:
             if need_export:
-                fwd.llhists.append((meta, bins[i]))
+                fwd.llhists.append((meta, regs))
             continue
+        # count and sum are derived from the HOST-side registers, not
+        # the device readout: the count must equal the le:+Inf bucket
+        # exactly (both are the same registers), and f64 midpoint math
+        # keeps the sum consistent with what a downstream
+        # re-aggregation would get
+        count = int(regs.sum())
+        total = llhist_ref.approx_sum(regs)
         names = meta.flush_names
         if names is None:
             names = meta.flush_names = {}
@@ -136,8 +138,8 @@ def _flush_llhist_family(store, is_local: bool, percentiles, now: int,
                 name=nm, timestamp=now, value=float(quants[i, j]),
                 tags=list(tags), type=MetricType.GAUGE))
         for suffix, value, mtype in (
-                ("sum", float(sums[i]), MetricType.GAUGE),
-                ("count", float(counts[i]), MetricType.COUNTER)):
+                ("sum", total, MetricType.GAUGE),
+                ("count", float(count), MetricType.COUNTER)):
             nm = names.get(suffix)
             if nm is None:
                 nm = names[suffix] = f"{meta.name}.{suffix}"
@@ -147,7 +149,7 @@ def _flush_llhist_family(store, is_local: bool, percentiles, now: int,
         bname = names.get("bucket")
         if bname is None:
             bname = names["bucket"] = f"{meta.name}.bucket"
-        c_sorted = bins[i][order]
+        c_sorted = regs[order]
         csum = np.cumsum(c_sorted)
         for k in np.flatnonzero(c_sorted).tolist():
             final.append(InterMetric(
@@ -397,7 +399,7 @@ _LE_TAGS: Optional[List[str]] = None
 
 def le_tags() -> List[str]:
     """`le:<bound>` tag strings for every sorted llhist bin plus the
-    final `le:+Inf`, index-aligned with BucketSection.csum columns."""
+    final `le:+Inf`, indexed by BucketSection.le_idx."""
     global _LE_TAGS
     if _LE_TAGS is None:
         from veneur_tpu.ops import llhist_ref
@@ -408,21 +410,36 @@ def le_tags() -> List[str]:
 
 @dataclass
 class BucketSection:
-    """Cumulative llhist bucket columns: one row per emitted llhist, the
-    full `np.cumsum` over its value-sorted bins. A row materializes as
-    COUNTER `<name>` lines tagged `le:<bound>` for every NONZERO sorted
-    bin (mask `nz`) plus an unconditional `le:+Inf` line carrying
-    `csum[:, -1]` — exactly `_flush_llhist_family`'s per-row loop. The
-    `le:` tag strings are shared and index-aligned via `le_tags()`;
-    `tags` rows are base tag-list refs (copy before mutating)."""
+    """Cumulative llhist buckets, CSR over the emitted llhists: row `i`
+    owns the entries `indptr[i]:indptr[i + 1]`, one per NONZERO
+    register in value-ascending order (`llhist_ref.cumulative_entries`).
+    A row materializes as COUNTER `<name>` lines tagged
+    `le_tags()[le_idx[k]]` with value `cum[k]` for each of its entries,
+    plus an unconditional `le:+Inf` line carrying `total[i]` — exactly
+    `_flush_llhist_family`'s per-row loop. `tags` rows are base
+    tag-list refs (copy before mutating)."""
 
-    names: np.ndarray  # object ndarray of str ("<base>.bucket")
-    tags: np.ndarray   # object ndarray of List[str] (base tags, no le:)
-    csum: np.ndarray   # (rows, bins) float64 cumulative counts
-    nz: np.ndarray     # (rows, bins) bool — sorted bin is nonzero
+    names: np.ndarray   # object ndarray of str ("<base>.bucket")
+    tags: np.ndarray    # object ndarray of List[str] (base tags, no le:)
+    indptr: np.ndarray  # (rows + 1,) int64 entry offsets
+    le_idx: np.ndarray  # (nnz,) position of the bin in value order
+    cum: np.ndarray     # (nnz,) float64 cumulative count at that bin
+    total: np.ndarray   # (rows,) float64 the row's count (le:+Inf)
 
     def line_count(self) -> int:
-        return int(self.nz.sum()) + self.names.shape[0]
+        return self.le_idx.shape[0] + self.names.shape[0]
+
+    def rows(self, scale: float = 1.0):
+        """Row by row: (name, base tags, `le_tags()` indices, values),
+        the row's entries and then -1 (`le:+Inf`) with its total; the
+        values divided by `scale` (a sink's interval)."""
+        indptr, le_idx = self.indptr.tolist(), self.le_idx.tolist()
+        cum = (self.cum / scale).tolist()
+        totals = (self.total / scale).tolist()
+        for i, (name, tags) in enumerate(zip(self.names.tolist(),
+                                             self.tags.tolist())):
+            lo, hi = indptr[i], indptr[i + 1]
+            yield name, tags, le_idx[lo:hi] + [-1], cum[lo:hi] + [totals[i]]
 
 
 class FlushBatch:
@@ -464,20 +481,12 @@ class FlushBatch:
                                            sec.tags.tolist()))
                 les = le_tags()
                 for bs in self.bucket_sections:
-                    nz, csum = bs.nz, bs.csum
-                    for i, (nm, base) in enumerate(zip(bs.names.tolist(),
-                                                       bs.tags.tolist())):
-                        row = csum[i]
-                        tags = list(base)
-                        for k in np.flatnonzero(nz[i]).tolist():
-                            out.append(InterMetric(
-                                name=nm, timestamp=ts, value=float(row[k]),
-                                tags=tags + [les[k]],
-                                type=MetricType.COUNTER))
-                        out.append(InterMetric(
-                            name=nm, timestamp=ts, value=float(row[-1]),
-                            tags=tags + ["le:+Inf"],
-                            type=MetricType.COUNTER))
+                    for nm, base, idxs, values in bs.rows():
+                        out.extend(
+                            InterMetric(name=nm, timestamp=ts, value=v,
+                                        tags=base + [les[k]],
+                                        type=MetricType.COUNTER)
+                            for k, v in zip(idxs, values))
                 out.extend(self.extras)
                 self._materialized = out
             return self._materialized
@@ -828,10 +837,13 @@ def readout_columnstore(
 
     # ---- log-linear histograms ------------------------------------------
     # percentiles/sum/count columnarize like every other family; the
-    # variable-length cumulative buckets become a BucketSection — one
-    # vectorized cumsum over the value-sorted bin table plus a nonzero
-    # mask, exploded per-row only by materialize() and the legacy
-    # `_flush_llhist_family` oracle (parity pinned by tests)
+    # variable-length cumulative buckets become a BucketSection. The
+    # transferred register table is mostly zeros (six samples a key
+    # leave 6 of 4,501 registers live), so it is scanned ONCE for its
+    # nonzero (row, bin, count) entries and everything is derived from
+    # those: nothing here allocates a (rows, BINS) array. Exploded per
+    # row only by materialize() and the legacy `_flush_llhist_family`
+    # oracle (parity pinned by tests)
     with timing.phase("assembly_llhist", parent="assembly"):
         extras: List[InterMetric] = []
         bucket_sections: List[BucketSection] = []
@@ -841,23 +853,25 @@ def readout_columnstore(
             from veneur_tpu.ops import llhist_ref
 
             lltab = store.llhists
-            # ll_bins is compact over the touched rows in `llr` order; keep
-            # the compact index aligned while dropping reclaim stragglers
-            keep = np.fromiter((ll_meta[r] is not None for r in llr.tolist()),
+            # ll_bins is compact over the touched rows in `llr` order;
+            # `emit` marks the compact rows this server emits: no
+            # reclaim stragglers and, on a local, no row it forwards
+            emit = np.fromiter((ll_meta[r] is not None for r in llr.tolist()),
                                bool, llr.size)
-            llr, bins_sel = llr[keep], ll_bins[keep]
-            emit = np.ones(llr.size, bool)
-            if is_local and llr.size:
-                fwd_mask = lltab.scope_code[llr] != local_code
+            if is_local:
+                fwd_mask = emit & (lltab.scope_code[llr] != local_code)
                 if fwd_mask.any():
                     if need_export:
-                        for j, row in zip(np.flatnonzero(fwd_mask).tolist(),
-                                          llr[fwd_mask].tolist()):
-                            fwd.llhists.append((ll_meta[row], bins_sel[j]))
-                    emit = ~fwd_mask
+                        # the global merges whole rows: widen the ones
+                        # that leave (a compact copy, so the forward
+                        # send does not pin the transferred table)
+                        fwd_bins = ll_bins[fwd_mask].astype(np.int64)
+                        fwd.llhists.extend(
+                            (ll_meta[row], fwd_bins[j]) for j, row
+                            in enumerate(llr[fwd_mask].tolist()))
+                    emit &= ~fwd_mask
             er = llr[emit]
             if er.size:
-                ebins = bins_sel[emit]
                 quants = np.asarray(ll_out["quantiles"], np.float64)[er]
                 tags_er = lltab.flush_tags(er, ll_meta)
                 for j, p in enumerate(full_ps):
@@ -866,25 +880,30 @@ def readout_columnstore(
                             p, er, ll_meta,
                             lambda m, p=p: _percentile_name(m.name, p)),
                         quants[:, j], tags_er, MetricType.GAUGE))
-                # count and sum from the HOST-side int64 bins (see the
+                e_rows, e_bins, e_counts = \
+                    llhist_ref.nonzero_entries(ll_bins)
+                kept = emit[e_rows]
+                e_bins, e_counts = e_bins[kept], e_counts[kept]
+                # compact row -> emitted row
+                e_rows = (np.cumsum(emit) - 1)[e_rows[kept]]
+                # count and sum from the HOST-side registers (see the
                 # legacy helper: count must equal the le:+Inf bucket)
                 sections.append(FlushSection(
                     lltab.flush_names("sum", er, ll_meta,
                                       lambda m: f"{m.name}.sum"),
-                    ebins.astype(np.float64) @ llhist_ref.BIN_MID,
+                    llhist_ref.entry_sums(e_rows, e_bins, e_counts, er.size),
                     tags_er, MetricType.GAUGE))
+                indptr, le_idx, cum, total = llhist_ref.cumulative_entries(
+                    e_rows, e_bins, e_counts, er.size)
+                total = total.astype(np.float64)
                 sections.append(FlushSection(
                     lltab.flush_names("count", er, ll_meta,
                                       lambda m: f"{m.name}.count"),
-                    ebins.sum(axis=1).astype(np.float64),
-                    tags_er, MetricType.COUNTER))
-                c_sorted = ebins[:, llhist_ref.ORDER]
+                    total, tags_er, MetricType.COUNTER))
                 bucket_sections.append(BucketSection(
                     lltab.flush_names("bucket", er, ll_meta,
                                       lambda m: f"{m.name}.bucket"),
-                    tags_er,
-                    np.cumsum(c_sorted, axis=1, dtype=np.float64),
-                    c_sorted != 0))
+                    tags_er, indptr, le_idx, cum.astype(np.float64), total))
 
     # ---- status checks --------------------------------------------------
     for row in np.flatnonzero(st_touched).tolist():

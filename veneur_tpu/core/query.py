@@ -328,8 +328,9 @@ class LiveQueryPlane:
                 i = tpos.get(row)
                 if i is None:
                     continue
-                total = float(bins[i].sum())
-                in_range = float(bins[i][mask].sum())
+                regs = bins[i].astype(np.int64)  # transferred as int32
+                total = float(regs.sum())
+                in_range = float(regs[mask].sum())
                 frac = in_range / total if total > 0 else 0.0
                 out.append(row_entry(row, frac))
                 in_total += in_range

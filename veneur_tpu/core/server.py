@@ -1847,6 +1847,16 @@ class Server:
                     phases["mesh"] = rec["mesh"]
             # the sinks time their encode and sends into this round
             batch.timing = rnd
+            if batch.bucket_sections:
+                # llhist registers leave the readout as their nonzero
+                # bins: how many rows, and how many entries for them
+                ll_bins = sum(b.le_idx.shape[0]
+                              for b in batch.bucket_sections)
+                self.statsd.count("flush.llhist.nonzero_bins", ll_bins)
+                if primary:
+                    round_info["llhist_rows"] = sum(
+                        b.names.shape[0] for b in batch.bucket_sections)
+                    round_info["llhist_nonzero_bins"] = ll_bins
             self.stats.inc("metrics_flushed", len(batch))
             # flush-stage ledger rows (informational): what the
             # delivered interval's snapshot produced

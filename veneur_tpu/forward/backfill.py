@@ -303,8 +303,7 @@ class BackfillPlane:
                 qs = llhist_ref.quantiles(bins, ps)
                 for p, q in zip(ps, qs):
                     emit(_percentile_name(name, p), q, tags)
-            emit(f"{name}.sum",
-                 float(bins.astype(np.float64) @ llhist_ref.BIN_MID), tags)
+            emit(f"{name}.sum", llhist_ref.approx_sum(bins), tags)
             emit(f"{name}.count", float(bins.sum()), tags,
                  MetricType.COUNTER)
             c_sorted = bins[order]

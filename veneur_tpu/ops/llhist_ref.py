@@ -73,6 +73,9 @@ WIDTH_SORTED = BIN_WIDTH[ORDER]
 MID_SORTED = BIN_MID[ORDER]
 # upper edge of each bin in sorted order (the Prometheus `le` bound)
 UPPER_SORTED = LEFT_SORTED + WIDTH_SORTED
+# bin id -> its position in that traversal (the inverse of ORDER)
+RANK = np.empty(BINS, np.int32)
+RANK[ORDER] = np.arange(BINS, dtype=np.int32)
 
 
 def bin_index(values) -> np.ndarray:
@@ -134,9 +137,52 @@ def quantiles(bins: np.ndarray, ps: Sequence[float]) -> np.ndarray:
     return out
 
 
+def nonzero_entries(table) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """A register table `(rows, >= BINS)` as its nonzero entries
+    `(row, bin id, count int64)`, row by row and by ascending bin id
+    within a row: one scan, whatever the table's integer width."""
+    table = np.asarray(table)
+    rows, bins = np.nonzero(table)
+    return rows, bins, table[rows, bins].astype(np.int64)
+
+
+def entry_sums(rows, bins, counts, n_rows: int) -> np.ndarray:
+    """THE definition of an llhist's `.sum`, for `n_rows` rows at once:
+    float64 `count * BIN_MID[bin]` over a row's nonzero registers,
+    added one after another in ascending bin id (the order
+    `nonzero_entries` yields; `np.bincount` accumulates its weights in
+    input order). Every `.sum` the program emits comes through here, so
+    it depends on the registers alone: not on a BLAS kernel's blocking,
+    the table's width or padding, the row's place in it, or how many
+    shards merged into it."""
+    return np.bincount(
+        rows, weights=np.asarray(counts, np.float64) * BIN_MID[bins],
+        minlength=n_rows)
+
+
+def cumulative_entries(rows, bins, counts, n_rows: int):
+    """Every row's cumulative buckets from its nonzero entries, as CSR:
+    -> (indptr (n_rows + 1,), rank (nnz,), cum (nnz,) int64, total
+    (n_rows,) int64). Row `i` owns `indptr[i]:indptr[i + 1]`: its
+    nonzero registers in value-ascending order, `rank` their positions
+    in that traversal (an index into UPPER_SORTED) and `cum` the count
+    up to and including each; `total[i]` is the row's count, the
+    `+Inf` bucket. What `LLHist.cumulative_buckets` gives row by row."""
+    rows = np.asarray(rows, np.int64)
+    order = np.argsort(rows * BINS + RANK[bins], kind="stable")
+    per_row = np.bincount(rows, minlength=n_rows)
+    indptr = np.concatenate([[0], np.cumsum(per_row)])
+    run = np.concatenate([[0], np.cumsum(counts[order])])
+    before = run[indptr[:-1]]  # the running count at each row's start
+    return (indptr, RANK[bins[order]], run[1:] - np.repeat(before, per_row),
+            run[indptr[1:]] - before)
+
+
 def approx_sum(bins: np.ndarray) -> float:
-    """Midpoint-weighted sum (the Circllhist sum approximation)."""
-    return float(np.asarray(bins, np.float64) @ BIN_MID)
+    """Midpoint-weighted sum of one register row (the Circllhist sum
+    approximation), by `entry_sums`."""
+    rows, idx, counts = nonzero_entries(np.asarray(bins)[None, :])
+    return float(entry_sums(rows, idx, counts, 1)[0])
 
 
 def count(bins: np.ndarray) -> float:
