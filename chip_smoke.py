@@ -262,8 +262,8 @@ def write_config(name: str, sizes: dict, interval_s: float,
                  shards: int = 1) -> str:
     """examples/example.yaml with loopback port-0 listeners, a channel
     sink to observe flushes, and capacities that hold every key without
-    a resize. Everything else stays at its shipped default (flush_async
-    and prewarm_ladder off, native parser on, ledger on)."""
+    a resize. Everything else stays at its shipped default
+    (prewarm_ladder off, native parser on, ledger on)."""
     import yaml
 
     with open(os.path.join(HERE, "examples", "example.yaml")) as f:

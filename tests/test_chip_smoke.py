@@ -48,18 +48,6 @@ def test_phase_passes_at_small_size(smoke, phase):
     assert stamp["platform"] == "cpu" and stamp["count"] >= 4
 
 
-def test_bench_refuses_a_cpu_it_did_not_ask_for(monkeypatch):
-    """bench.py has no CPU fallback: JAX finding only the CPU is an
-    error unless JAX_PLATFORMS names the CPU on purpose."""
-    import bench
-
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    with pytest.raises(SystemExit, match="JAX found platform 'cpu'"):
-        bench.initialize_backend()
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    assert bench.initialize_backend() == "cpu"
-
-
 def test_more_shards_than_devices_is_an_error():
     """No virtual-device substitution, no clamping: both numbers named."""
     import jax

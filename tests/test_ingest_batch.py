@@ -408,13 +408,13 @@ class TestSwapIngestRace:
         assert total_seen == float(sum(wrote))
 
     def test_server_flush_hammer_strict_ledger_clean(self):
-        """Whole-pipeline hammer under flush_async + ledger_strict:
-        python-path ingest races overlapped flushes; counters conserve
-        exactly across every delivered interval and no flush raises a
-        conservation imbalance."""
+        """Whole-pipeline hammer under ledger_strict: python-path
+        ingest races the flush thread's swaps and readouts; counters
+        conserve exactly across every delivered interval and no flush
+        raises a conservation imbalance."""
         import threading
 
-        server, ch = make_server(flush_async=True, ledger_strict=True)
+        server, ch = make_server(ledger_strict=True)
         try:
             writers = 3
             per_writer = 400
@@ -435,9 +435,7 @@ class TestSwapIngestRace:
             for t in threads:
                 t.join()
             server.store.apply_all_pending()
-            server.flush()  # swap the tail interval
-            server.flush()  # deliver it (pipeline depth 1)
-            server.flush()  # and the (empty) one after
+            server.flush()  # the tail interval, delivered this tick
             total = sum(m.value for m in ch.drain()
                         if m.name.startswith("flood."))
             assert total == float(writers * per_writer)

@@ -169,16 +169,28 @@ class TestMigrationCells:
 
 
 class TestElasticCutover:
-    def test_live_split_bit_identity_vs_control(self, tmp_path):
+    @pytest.mark.parametrize("tick_first", [False, True])
+    def test_live_split_bit_identity_vs_control(self, tmp_path,
+                                                tick_first):
         """2 -> 3 under sustained ingest: rows fed before, DURING, and
         after the reshard all land; the post-cutover flush is
         bit-identical to a never-resharded 2-shard control; strict
-        ledger green end to end."""
+        ledger green end to end. With `tick_first` the reshard begins
+        right after a flush tick: the tick delivered its interval and
+        released the flush lock, so the cutover finds recycled
+        generations and nothing of that interval to wait for."""
         server, obs = mk_server(**{"tpu.shards": 2},
                                 reshard_spool_dir=str(tmp_path / "wal"))
         control, cobs = mk_server(**{"tpu.shards": 2})
         assert server.store.shard_plane is not None, "virtual mesh missing"
         try:
+            if tick_first:
+                _feed(server, corpus(4))
+                _feed(control, corpus(4))
+                server.flush()
+                control.flush()
+                _assert_bit_identical(_flushed(obs.drain()),
+                                      _flushed(cobs.drain()))
             _feed(server, corpus(0))
             _feed(control, corpus(0))
 

@@ -282,20 +282,7 @@ class Config:
     # (total cost is pinned under 2% of flush wall time by a soak);
     # false hands out plain queues and skips all attribution.
     latency_observatory: bool = True
-    # -- asynchronous flush & shape ladder (core/flushexec.py) ----------
-    # flush_async overlaps the flush with the next interval's ingest:
-    # the flush tick swaps every family's device generation out (O(1)
-    # per table) and runs the readout kernels — dispatch, device sync,
-    # transfer, assembly — on a background executor with donated
-    # buffers; each tick DELIVERS the previous interval's readout, so
-    # the ~seconds of device work leave the interval critical path
-    # entirely (flush.critical_path_s) at the cost of one interval of
-    # delivery latency. Off by default: synchronous delivery is the
-    # conservative default for small deployments and keeps test
-    # topologies same-tick; the sustained/overlap bench gates run with
-    # it on. Shutdown (and the SIGUSR2 handoff drain) always joins and
-    # delivers the in-flight snapshot, so nothing is lost at the seam.
-    flush_async: bool = False
+    # -- shape ladder (core/flushexec.py) -------------------------------
     # prewarm_ladder compiles each family's NEXT capacity rung's
     # kernels (apply + readout + zeroing) in a background thread — at
     # startup and again on every resize event — so a capacity doubling

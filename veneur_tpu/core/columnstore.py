@@ -337,14 +337,10 @@ class _BaseTable:
             self.apply_lock.release()
             self.lock.acquire()
 
-    # -- two-phase flush: critical-path swap / background readout --------
+    # -- two-phase flush: generation swap / readout -----------------------
     #
-    # The flush used to be one synchronous pass: swap pending columns,
-    # dispatch the readout kernels, sync, transfer — all on the flush
-    # loop's critical path, with ingest applies blocked on apply_lock
-    # for the full dispatch window (~1.7s of `dispatch_s` at the 100k
-    # shape, BENCH_r05). The split below makes the interval boundary a
-    # pure generation swap:
+    # The interval boundary is a pure generation swap, so ingest applies
+    # never wait on apply_lock for the readout's dispatch window:
     #
     #   swap_out()   O(1) under the table locks: swap the pending
     #                columns out, capture touched/meta, capture the live
@@ -354,8 +350,8 @@ class _BaseTable:
     #                generation the moment the locks drop.
     #   readout()    lock-free on the CAPTURED generation (it is private
     #                to the snapshot): apply the final pending columns,
-    #                dispatch the readout kernels. Runs on the server's
-    #                background flush executor when `flush_async` is on.
+    #                dispatch the readout kernels. Runs on the flush
+    #                thread while ingest applies to the fresh generation.
     #   snapshot_finish()  transfer + host assembly (unchanged).
     #   recycle()    after the transfer: donate the drained generation
     #                to the zeroing kernel and park it as the spare —

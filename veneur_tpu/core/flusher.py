@@ -50,9 +50,9 @@ class ForwardableState:
     # (meta, llhist bins int64) — exact-merge family: registers ADD
     llhists: List[Tuple[RowMeta, np.ndarray]] = field(default_factory=list)
     # pre-serialized metricpb frames (forward/convert.forwardable_to_wire),
-    # populated on the flush-readout executor so serialization overlaps
-    # sink delivery; MUST be dropped whenever the state lists mutate
-    # (carryover stash/drain call invalidate_wire)
+    # populated by the readout on the flush thread, so the forward
+    # thread goes straight to the POST; MUST be dropped whenever the
+    # state lists mutate (carryover stash/drain call invalidate_wire)
     wire: Optional[List[bytes]] = None
 
     def __len__(self):
@@ -564,11 +564,6 @@ def _swap_columnstore(store: ColumnStore, is_local: bool,
         "set": store.sets.swap_out(),
         "status": store.statuses.snapshot_and_reset(),
     }
-    # conservative in-flight snapshot size (touched rows across the
-    # device families): the ledger books this as the overlap stock
-    swap["rows"] = int(sum(
-        np.count_nonzero(swap[f].get("touched", ()))
-        for f in ("histogram", "counter", "gauge", "llhist", "set")))
     return swap
 
 
@@ -930,11 +925,10 @@ def flush_columnstore_batch(
     timing: Optional[FlushRound] = None,
     attribute: bool = False,
 ) -> Tuple[FlushBatch, ForwardableState]:
-    """Synchronous columnar flush: swap + readout in one call (the
-    pre-overlap shape; the server composes the two halves itself so the
-    readout can run on the background flush executor when `flush_async`
-    is on). Semantics identical to the legacy flush_columnstore — the
-    parity tests pin the two equal."""
+    """Columnar flush: swap + readout in one call (the server calls the
+    two halves itself, each under its own span). Semantics identical to
+    the legacy flush_columnstore — the parity tests pin the two
+    equal."""
     swap = swap_columnstore(store, is_local, percentiles,
                             collect_forward=collect_forward,
                             timing=timing)

@@ -1,18 +1,13 @@
-"""Background flush execution & the pre-warmed shape ladder.
+"""The query plane's readout worker & the pre-warmed shape ladder.
 
-Two pieces of the overlapped flush cycle (ROADMAP item 3; DrJAX-style
-device-resident aggregation with donated buffers, per PAPERS.md):
-
-**FlushReadoutExecutor** — a single background worker that drains the
-readout half of the flush (`core/flusher.readout_columnstore`: kernel
-dispatch, device sync, host transfer, numpy assembly) off the interval
-critical path. With `flush_async` on, the server's flush loop swaps the
-interval out (O(1) per table), submits the readout here, and only JOINS
-the *previous* interval's readout — so `dispatch_s` + `device_sync_s`
-never block the flush loop or ingest. The worker heartbeats the
-pipeline supervisor (component ``flush-readout``), so a wedged readout
-(a hung device link mid-transfer) trips the same stall ladder as a
-wedged flush loop — see the README runbook.
+**FlushReadoutExecutor** — a single background worker that runs the
+readouts of the query plane's live reads (`core/query.py`
+`QueryPlane.capture`: `query_readout` of a read-only capture, for
+`/query` and the alert engine), one at a time, off the caller's thread.
+The flush itself reads out on the flush thread. The worker heartbeats
+the pipeline supervisor (component ``flush-readout``), so a wedged
+readout (a hung device link mid-transfer) trips the same stall ladder
+as a wedged flush loop — see the README runbook.
 
 **ShapeLadderPrewarmer** — a background compiler for the capacity
 ladder. Every jitted kernel specializes on table capacity, so a
@@ -45,12 +40,9 @@ PREWARM_FAMILIES = ("counter", "gauge", "histogram", "llhist", "set")
 
 
 class FlushReadoutExecutor:
-    """Single background worker draining flush readouts in submit order
-    (one interval is in flight at a time by construction — the flush
-    loop joins N-1 before submitting N, so the queue never grows past
-    one). submit() returns a stdlib concurrent.futures.Future: the
-    joiner's `result(timeout)` re-raises a readout failure exactly
-    where a synchronous flush would have raised, and times out with
+    """Single background worker running readouts in submit order.
+    submit() returns a stdlib concurrent.futures.Future: the caller's
+    `result(timeout)` re-raises a readout failure, and times out with
     concurrent.futures.TimeoutError. The worker thread is what a plain
     ThreadPoolExecutor can't give us: supervisor heartbeats between
     (and around) tasks, so a wedged readout trips the stall ladder."""
@@ -90,7 +82,7 @@ class FlushReadoutExecutor:
                 result = fn()
             except BaseException as e:  # re-raised at result()
                 pending.set_exception(e)
-                logger.exception("background flush readout failed")
+                logger.exception("background readout failed")
             else:
                 pending.set_result(result)
             finally:

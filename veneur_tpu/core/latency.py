@@ -476,10 +476,7 @@ def family_tree(readout) -> dict:
 def family_segments_sum(families: dict) -> float:
     """Sum of every attributed segment in one round's family tree —
     the number the acceptance test pins against the recorded
-    `dispatch_s` + `device_sync_s` totals. Holds for overlapped rounds
-    too: an async round's family segments AND its dispatch/sync phase
-    totals are both measured inside the same background readout, so
-    the identity survives the move off the critical path."""
+    `dispatch_s` + `device_sync_s` totals."""
     total = 0.0
     for rec in (families or {}).values():
         total += rec.get("dispatch_s", 0.0) + rec.get("transfer_s", 0.0)
@@ -492,27 +489,13 @@ def waterfall_rounds(rounds: List[dict]) -> List[dict]:
     """Transform FlushRecorder rounds into waterfall segment trees for
     `/debug/flush?waterfall=1`: per round, the phase totals, the
     per-family/per-device device segments (with retrace tags), and the
-    per-sink delivery segments — newest last.
-
-    Overlapped rounds (`flush_async`) carry the async shape: the round
-    is marked `async_readout`, `delivered_flush` names the interval
-    whose readout this tick joined and delivered, each family segment
-    carries `lane: "async"` (it ran on the background executor,
-    parallel to the next interval's ingest — render it as a parallel
-    lane, not on the critical path), and `critical_path_s` is the
-    join-only wall time that remained on the flush loop."""
+    per-sink delivery segments — newest last."""
     out = []
     for r in rounds:
         phases = r.get("phases", {}) or {}
         families = r.get("families") or {}
         tree = {
             "flush": r.get("flush"),
-            **({"async_readout": True} if r.get("async") else {}),
-            **({"delivered_flush": r["delivered_flush"]}
-               if r.get("delivered_flush") is not None else {}),
-            **({"critical_path_s": phases["critical_path_s"]}
-               if isinstance(phases.get("critical_path_s"),
-                             (int, float)) else {}),
             # the interval's self-trace id (hex): the waterfall row
             # cross-links to /debug/traces?trace_id= directly
             **({"trace_id": r["trace_id"]} if r.get("trace_id") else {}),

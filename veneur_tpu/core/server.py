@@ -159,13 +159,6 @@ class _SpanSinkWorker:
 
 
 class Server:
-    # consecutive flush ticks a background readout may miss its join
-    # grace before being dropped: a transient device stall carries the
-    # completed interval forward to later ticks instead of losing it,
-    # while a truly wedged readout is bounded (and the supervisor's
-    # flush-readout deadline escalates it independently)
-    READOUT_MISS_LIMIT = 3
-
     def __init__(self, config: Config,
                  extra_metric_sinks: Optional[List] = None,
                  extra_span_sinks: Optional[List] = None):
@@ -362,14 +355,6 @@ class Server:
             "forward_tier", inputs=("forward.acked_reported",),
             outputs=("forward.remote_merged", "forward.remote_rejected",
                      "forward.remote_deduped"))
-        # the overlapped flush's in-flight snapshot (flush_async): an
-        # interval swapped out of the tables but not yet delivered is
-        # INVENTORY, not loss — booked as a stock so conservation stays
-        # provable through the overlap (it is informational — the
-        # ingest/forward identities note at apply/delivery time, which
-        # both land inside one ledger interval)
-        self.ledger.stock("flush_inflight_snapshot",
-                          lambda: float(self._inflight_rows))
         self.latency.ledger = self.ledger if self.ledger.enabled else None
         self.ledger.trace_source = self.trace_plane.active_trace_hex
         self.telemetry.registry.add_collector(self.ledger.telemetry_rows)
@@ -429,20 +414,9 @@ class Server:
         self._warmup_thread = None  # set in start()
         self._listeners: List[networking.Listener] = []
         self._flush_lock = threading.Lock()
-        # asynchronous flush pipeline (core/flushexec.py, flush_async):
-        # in-flight interval records — swapped out, readouts running on
-        # the background executor in submit order, joined+delivered by
-        # subsequent flush ticks. Normally at most one deep; a wedged
-        # readout lets it grow (bounded) so a transient device stall
-        # carries completed intervals forward instead of dropping them.
-        # All mutated under _flush_lock (plus shutdown's drain, which
-        # flushes under the same lock).
-        self._inflight_flushes: List[dict] = []
-        self._flush_executor = None  # created on the first async flush
-        # touched-row count of the in-flight snapshot: the ledger books
-        # the swapped-but-undelivered interval as an inventory stock so
-        # the overlap stays visible in /debug/ledger
-        self._inflight_rows = 0
+        # the query plane's readout worker (core/flushexec.py), created
+        # by the first live read
+        self._flush_executor = None
         self.prewarmer = None  # set in start() when prewarm_ladder
         # last flush thread per sink: a sink whose previous flush is still
         # running gets skipped — the hard cap is ONE concurrent flush
@@ -1436,23 +1410,10 @@ class Server:
         for worker in self._span_sink_workers:
             worker.stop()
         if self.config.flush_on_shutdown:
-            # full final flush: _flush_locked runs synchronously here
-            # (shutdown is set), so the in-flight async readout AND the
-            # final partial interval both deliver before exit
+            # final flush: the open partial interval delivers before
+            # exit (a tick still running holds _flush_lock until its
+            # own interval is delivered, so this one queues behind it)
             self.flush()
-        elif self.config.flush_async:
-            # flush_on_shutdown is OFF (the operator opted out of
-            # partial-interval emission), but an interval already
-            # SWAPPED for async readout is complete, committed data —
-            # join and deliver it (WAL append + forward + sinks)
-            # without opening a new interval boundary. The SIGUSR2
-            # handoff relies on this to stay loss-free. Gated on
-            # flush_async itself, not a racy _inflight_flush read: a
-            # ticker tick mid-swap right now submits its readout
-            # before releasing _flush_lock, and the deliver-only pass
-            # serializes behind it there and joins what it submitted.
-            with self._flush_lock:
-                self._flush_locked(deliver_only=True)
         if self._flush_executor is not None:
             self._flush_executor.stop()
         if self.prewarmer is not None:
@@ -1589,7 +1550,7 @@ class Server:
         with self._flush_lock:
             self._flush_locked()
 
-    def _flush_locked(self, deliver_only: bool = False) -> None:
+    def _flush_locked(self) -> None:
         from veneur_tpu import trace as trace_mod
         from veneur_tpu.trace.store import trace_id_hex
         # the round's one span source: phases, spans and the profiler's
@@ -1661,20 +1622,11 @@ class Server:
             # the interval's /debug/traces entry
             round_info["trace_id"] = trace_id_hex(flush_span.trace_id)
 
-        def _start_sink_thread(key: str, target, *args,
-                               parent_span=None,
-                               span_traced=None) -> bool:
+        def _start_sink_thread(key: str, target, *args) -> bool:
             """Dispatch one sink flush thread; returns False when the
             interval was NOT dispatched (skip or open breaker) so the
             forward path can stash its state into carryover instead of
-            dropping it. `parent_span`/`span_traced` re-home the sink's
-            child span under the interval trace whose data is being
-            delivered (an async round delivers the PREVIOUS interval's
-            readout — its spans must parent there, not here)."""
-            if parent_span is None:
-                parent_span = flush_span
-            if span_traced is None:
-                span_traced = traced
+            dropping it."""
             prev = self._sink_flush_threads.get(key)
             if prev is not None and prev.is_alive():
                 # hard cap: one concurrent flush thread per sink. The
@@ -1718,7 +1670,7 @@ class Server:
                 return False
             t = threading.Thread(
                 target=self._timed_sink_flush,
-                args=(key, parent_span, span_traced, round_info, rnd,
+                args=(key, flush_span, traced, round_info, rnd,
                       target) + args,
                 daemon=True, name=f"flush-{key}")
             t.start()
@@ -1730,224 +1682,92 @@ class Server:
             _start_sink_thread(
                 f"span:{sink.name()}", self._flush_span_sink_safe, sink)
 
-        # per-phase wall clock for flush-latency attribution; read by
-        # the bench's sustained gate (one flush at a time: _flush_lock)
-        phases = self.flush_phase_timings = rnd.phases
+        phases = rnd.phases
         # sample-age watermarks roll at the same boundary the column
         # store snapshots: everything stamped before this flush's
         # snapshot is aged through to sink ack below
         watermarks = self.latency.take_watermarks()
-        # flush_async: swap the interval out (O(1) per table), hand the
-        # readout to the background executor, and DELIVER the previous
-        # interval's joined readout — dispatch/sync/transfer leave the
-        # critical path entirely. Shutdown drains synchronously so the
-        # in-flight snapshot and the final interval both land.
-        async_on = (bool(self.config.flush_async)
-                    and not self._shutdown.is_set()
-                    and not deliver_only)
         preflush.stop()
-        store_flush = rnd.phase("store_flush", parent="flush").start()
-        record = None
-        if not deliver_only:
+        # the interval boundary is a generation swap (O(1) per table):
+        # ingest continues into the fresh generation while this thread
+        # reads the captured one out
+        with rnd.phase("store_flush", parent="flush"):
             swap = swap_columnstore(
                 self.store, self.is_local, self.percentiles,
                 collect_forward=self.forwarder is not None,
                 timing=rnd)
-            record = {
-                "swap": swap,
-                "flush": self.flush_count,
-                "interval_start": interval_start,
-                "watermarks": watermarks,
-                "span": flush_span,
-                "traced": traced,
-            }
-        # join the in-flight readouts, oldest first: the head had a
-        # whole interval to finish, so this is normally a no-op wait —
-        # the only store wall time left on the critical path. A head
-        # that is NOT done (transient device stall) is CARRIED to the
-        # next tick after a short grace rather than dropped — its data
-        # is a completed, committed interval; only a readout that stays
-        # wedged past READOUT_MISS_LIMIT ticks (or fails outright) is
-        # dropped, loudly. Shutdown drains with the full timeout.
-        from concurrent.futures import TimeoutError as _JoinTimeout
-        join = rnd.phase("join", parent="store_flush").start()
-        drain = deliver_only or self._shutdown.is_set()
-        inflight = self._inflight_flushes
-        delivered = []
-        while inflight:
-            head = inflight[0]
-            head["async"] = True
-            try:
-                head["result"] = head["pending"].result(
-                    timeout=(max(self.interval, 60.0) if drain
-                             else min(5.0, max(1.0, self.interval / 4))))
-            except _JoinTimeout:
-                if not drain:
-                    misses = head["join_misses"] = \
-                        head.get("join_misses", 0) + 1
-                    if misses < self.READOUT_MISS_LIMIT:
-                        # carry to the next tick; deliver nothing more
-                        break
-                logger.error(
-                    "flush readout for interval %s wedged%s; dropping "
-                    "it", head.get("flush"),
-                    " at shutdown" if drain else
-                    f" for {head['join_misses']} ticks")
-                self.statsd.count("flush.readout_failed_total", 1)
-                inflight.pop(0)
-                continue
-            except Exception:
-                logger.exception(
-                    "in-flight flush readout failed; interval %s lost",
-                    head.get("flush"))
-                self.statsd.count("flush.readout_failed_total", 1)
-                inflight.pop(0)
-                continue
-            inflight.pop(0)
-            delivered.append(head)
-        join.stop()
-        inline_device_s = 0.0
-        if deliver_only:
-            pass  # shutdown drain: no new interval boundary is opened
-        elif async_on:
-            record["pending"] = self._readout_executor().submit(
-                lambda rec=record: self._run_readout(rec))
-            inflight.append(record)
-        else:
-            record["result"] = self._run_readout(record,
-                                                 parent="store_flush")
-            r_phases = record["result"][2].phases
-            # device work that DID run inline this tick — subtracted
-            # from the critical-path row below
-            inline_device_s = sum(
-                r_phases.get(k, 0.0)
-                for k in ("dispatch_s", "device_sync_s", "assembly_s"))
-            delivered.append(record)
-        # the ledger's overlap stock: touched rows across every swapped-
-        # but-undelivered interval still in the pipeline
-        self._inflight_rows = sum(r["swap"]["rows"] for r in inflight)
-        store_flush.stop()
-        round_info["async"] = async_on
+            with rnd.phase("readout", parent="store_flush"):
+                batch, fwd = self._run_readout(swap, rnd)
+        # every family was synced on its own (attribute): the
+        # waterfall's per-family segment tree
+        families = family_tree(rnd) if self.latency.enabled else None
 
-        def _deliver_round(rec, other_samples, primary: bool) -> int:
-            """Fan one joined/inline readout out to the forward plane
-            and the metric sinks; returns its metric count. Only the
-            PRIMARY (first) round's readout phases land in this tick's
-            series — a drain tick delivering two intervals must not mix
-            one interval's phase totals with another's family segments
-            in the recorded round."""
-            batch, fwd, readout = rec["result"]
-            rec_span, rec_traced = rec["span"], rec["traced"]
-            # readout spans and phases land in this round's series (one
-            # interval late under overlap — the bench gate reads
-            # distributions)
-            if primary:
-                rnd.merge(readout)
-                if rec.get("mesh") is not None:
-                    phases["mesh"] = rec["mesh"]
-            # the sinks time their encode and sends into this round
-            batch.timing = rnd
-            if batch.bucket_sections:
-                # llhist registers leave the readout as their nonzero
-                # bins: how many rows, and how many entries for them
-                ll_bins = sum(b.le_idx.shape[0]
-                              for b in batch.bucket_sections)
-                self.statsd.count("flush.llhist.nonzero_bins", ll_bins)
-                if primary:
-                    round_info["llhist_rows"] = sum(
-                        b.names.shape[0] for b in batch.bucket_sections)
-                    round_info["llhist_nonzero_bins"] = ll_bins
-            self.stats.inc("metrics_flushed", len(batch))
-            # flush-stage ledger rows (informational): what the
-            # delivered interval's snapshot produced
-            self.ledger.note("flush.emitted", len(batch))
-            self.ledger.note("flush.forward_rows", len(fwd))
+        # deliver: fan the readout out to the forward plane and the
+        # metric sinks, which time their encode and sends into this round
+        batch.timing = rnd
+        if batch.bucket_sections:
+            # llhist registers leave the readout as their nonzero
+            # bins: how many rows, and how many entries for them
+            ll_bins = sum(b.le_idx.shape[0] for b in batch.bucket_sections)
+            self.statsd.count("flush.llhist.nonzero_bins", ll_bins)
+            round_info["llhist_rows"] = sum(
+                b.names.shape[0] for b in batch.bucket_sections)
+            round_info["llhist_nonzero_bins"] = ll_bins
+        self.stats.inc("metrics_flushed", len(batch))
+        # flush-stage ledger rows (informational): what the interval's
+        # snapshot produced
+        self.ledger.note("flush.emitted", len(batch))
+        self.ledger.note("flush.forward_rows", len(fwd))
 
-            # dispatch even with an empty snapshot when a previous
-            # interval's failed state is pending (in carryover OR the
-            # durable spool) — otherwise a quiet interval would strand
-            # it until new traffic arrives
-            pending_carryover = (
-                self.forward_client is not None
-                and (self.forward_client.carryover.depth > 0
-                     or (self.forward_client.spool is not None
-                         and self.forward_client.spool.depth > 0)))
-            if self.is_local and self.forwarder is not None and (
-                    len(fwd) or pending_carryover):
-                # flow ledger: everything snapshotted for the forward
-                # plane is owed an outcome (ack / merge-away / shed /
-                # inventory)
-                self.ledger.note("forward.snapshot", len(fwd))
-                if not _start_sink_thread(
-                        "forward", self._forward_safe, fwd,
-                        rec["interval_start"], parent_span=rec_span,
-                        span_traced=rec_traced) \
-                        and self.forward_client is not None and len(fwd):
-                    # undispatched interval (previous forward still
-                    # hung): the snapshot is mergeable state, so it
-                    # carries over exactly like a failed send instead
-                    # of being dropped
-                    self.forward_client.carryover.stash(fwd)
-                    self.statsd.count("flush.forward_undispatched_total",
-                                      1)
+        # dispatch even with an empty snapshot when a previous
+        # interval's failed state is pending (in carryover OR the
+        # durable spool) — otherwise a quiet interval would strand
+        # it until new traffic arrives
+        pending_carryover = (
+            self.forward_client is not None
+            and (self.forward_client.carryover.depth > 0
+                 or (self.forward_client.spool is not None
+                     and self.forward_client.spool.depth > 0)))
+        if self.is_local and self.forwarder is not None and (
+                len(fwd) or pending_carryover):
+            # flow ledger: everything snapshotted for the forward
+            # plane is owed an outcome (ack / merge-away / shed /
+            # inventory)
+            self.ledger.note("forward.snapshot", len(fwd))
+            if not _start_sink_thread(
+                    "forward", self._forward_safe, fwd, interval_start) \
+                    and self.forward_client is not None and len(fwd):
+                # undispatched interval (previous forward still
+                # hung): the snapshot is mergeable state, so it
+                # carries over exactly like a failed send instead
+                # of being dropped
+                self.forward_client.carryover.stash(fwd)
+                self.statsd.count("flush.forward_undispatched_total", 1)
 
-            if self._routing is not None:
-                # routing annotates per-metric sink sets, so it needs
-                # objects; materialize once here and every sink thread
-                # shares the list
-                for metric in batch.materialize():
-                    route = set()
-                    for rule in self._routing:
-                        route.update(rule.route(metric.name, metric.tags))
-                    metric.sinks = route
+        if self._routing is not None:
+            # routing annotates per-metric sink sets, so it needs
+            # objects; materialize once here and every sink thread
+            # shares the list
+            for metric in batch.materialize():
+                route = set()
+                for rule in self._routing:
+                    route.update(rule.route(metric.name, metric.tags))
+                metric.sinks = route
 
-            for sink in self.metric_sinks:
-                key = f"metric:{sink.name()}"
-                # per-sink gate: another sink's pending spill must not
-                # dispatch this one — a no-op flush would still
-                # thread-spawn and (worse) count as a probe against
-                # this sink's breaker
-                if len(batch) or other_samples or key in self._sink_spill:
-                    # from here to the sink's own flush call: thread
-                    # start, events, spill, routing
-                    starting = rnd.phase("egress_start", parent="flush",
-                                         sink=key).start(handoff=True)
-                    _start_sink_thread(
-                        key, self._flush_sink_safe, key, sink, batch,
-                        other_samples, starting, parent_span=rec_span,
-                        span_traced=rec_traced)
-            return len(batch)
-
-        delivered_metrics = 0
-        for i, rec in enumerate(delivered):
-            # events/service checks belong to THIS tick: they ride the
-            # first delivery round only (a drain tick delivers two)
-            delivered_metrics += _deliver_round(
-                rec, samples if i == 0 else (), primary=(i == 0))
-            if i + 1 < len(delivered):
-                # drain tick delivering two intervals: the one-thread-
-                # per-sink cap means round 2 must wait for round 1's
-                # threads — ONE shared grace across all of them, not a
-                # fresh timeout per thread (N wedged sinks would
-                # otherwise stall shutdown for N x grace)
-                inter_deadline = (time.perf_counter()
-                                  + max(self.interval, 30.0))
-                for t in threads:
-                    remaining = inter_deadline - time.perf_counter()
-                    if remaining <= 0:
-                        break
-                    t.join(remaining)
-        if not delivered and (samples or self._sink_spill):
-            # empty-delivery tick (first async tick, or a failed/timed-
-            # out readout join): events/service checks still deliver on
-            # time, and sinks with a pending one-interval spill get
-            # their retry — an empty tick must not starve either
-            empty = FlushBatch(int(self.last_flush_unix), [], [])
-            for sink in self.metric_sinks:
-                key = f"metric:{sink.name()}"
-                if samples or key in self._sink_spill:
-                    _start_sink_thread(key, self._flush_sink_safe, key,
-                                       sink, empty, samples)
+        for sink in self.metric_sinks:
+            key = f"metric:{sink.name()}"
+            # per-sink gate: another sink's pending spill must not
+            # dispatch this one — a no-op flush would still
+            # thread-spawn and (worse) count as a probe against
+            # this sink's breaker
+            if len(batch) or samples or key in self._sink_spill:
+                # from here to the sink's own flush call: thread
+                # start, events, spill, routing
+                starting = rnd.phase("egress_start", parent="flush",
+                                     sink=key).start(handoff=True)
+                _start_sink_thread(
+                    key, self._flush_sink_safe, key, sink, batch,
+                    samples, starting)
 
         # bounded wait: one interval from flush start, minus time already
         # spent; stragglers keep running on their daemon threads and are
@@ -1991,80 +1811,48 @@ class Server:
             self.import_server.rpc_stats.emit(self.statsd, prefix="import.rpc")
         # sink joins are the ack point: everything dispatched this round
         # has been delivered (or timed out, recorded above) — the moment
-        # the DELIVERED interval's samples stop aging. Under overlap the
-        # delivered watermarks are the previous interval's, so the age
-        # honestly includes the pipeline's one-interval delivery delay.
+        # the interval's samples stop aging
         ack_unix = time.time()
-        # retrace tags drain ONCE per tick and land on the first
-        # delivered families tree (on a drain tick delivering two
-        # intervals, that is the async/previous one — the interval the
-        # pending recompile actually preceded)
+        self.latency.observe_sample_age(watermarks, ack_unix)
+        if traced and watermarks:
+            # anchor the interval's worst-case staleness to its trace:
+            # the pipeline.sample_age rows in /metrics carry an
+            # OpenMetrics exemplar pointing at this flush
+            oldest = min(mark[0] for mark in watermarks.values())
+            self.trace_plane.exemplars.capture(
+                "pipeline.sample_age", max(0.0, ack_unix - oldest),
+                flush_span.trace_id, ts=ack_unix)
+        # retrace tags drain once per tick, onto the families tree of
+        # the interval the pending recompile preceded
         retraces = self.latency.drain_retraces()
-        families = None
-        for rec in delivered:
-            self.latency.observe_sample_age(rec["watermarks"], ack_unix)
-            if rec["traced"] and rec["watermarks"]:
-                # anchor the delivered interval's worst-case staleness
-                # to ITS trace: the pipeline.sample_age rows in /metrics
-                # carry an OpenMetrics exemplar pointing at that flush
-                oldest = min(mark[0] for mark in rec["watermarks"].values())
-                self.trace_plane.exemplars.capture(
-                    "pipeline.sample_age", max(0.0, ack_unix - oldest),
-                    rec["span"].trace_id, ts=ack_unix)
-            rec_families = rec.get("families")
-            if rec_families:
-                for family, (secs, cache) in retraces.items():
-                    frec = rec_families.get(family)
-                    if frec is not None:
-                        frec["retrace"] = True
-                        frec["recompile_s"] = round(secs, 6)
-                        if cache:
-                            frec["compile_cache"] = cache
-                retraces = {}
-                if rec.get("async"):
-                    # waterfall: these segments ran on the background
-                    # executor — render as the parallel (async) lane
-                    for frec in rec_families.values():
-                        frec["lane"] = "async"
-                # async readout spans still parent under the ORIGINATING
-                # interval's flush span, on the readout's own wall clock
-                # (not this tick's)
-                self._record_family_spans(rec["span"], rec_families)
-                if families is None:
-                    # the round's waterfall tree shows the FIRST
-                    # delivered interval's segments (the async one on a
-                    # drain tick), paired with its flush id — never a
-                    # mix of two intervals' evidence
-                    families = rec_families
-                    if rec.get("async"):
-                        round_info["delivered_flush"] = rec["flush"]
+        if families:
+            for family, (secs, cache) in retraces.items():
+                frec = families.get(family)
+                if frec is not None:
+                    frec["retrace"] = True
+                    frec["recompile_s"] = round(secs, 6)
+                    if cache:
+                        frec["compile_cache"] = cache
+            self._record_family_spans(flush_span, families)
         flush_span.finish()
         duration = flush_phase.stop()["wall_s"]
         phases["flush_cpu_s"] = rnd.cpu_s()
-        # the join-only critical path: total wall minus whatever device
-        # readout ran INLINE this tick (zero under flush_async — the
-        # acceptance row proving dispatch/sync/transfer left the path)
-        critical_path = max(0.0, duration - inline_device_s)
-        phases["critical_path_s"] = critical_path
-        self.statsd.timing("flush.critical_path_s", critical_path)
         self.statsd.gauge("flush.total_duration_ns", int(duration * 1e9))
         self.statsd.timing("flush.total_duration", duration)
         for phase, secs in phases.items():
-            if isinstance(secs, (int, float)):
-                self.statsd.timing("flush.phase_duration", secs,
-                                   tags=[f"phase:{phase}"])
-        self.statsd.count("flush.metrics_total", delivered_metrics)
+            self.statsd.timing("flush.phase_duration", secs,
+                               tags=[f"phase:{phase}"])
+        self.statsd.count("flush.metrics_total", len(batch))
         round_info["duration_s"] = round(duration, 6)
-        round_info["metrics_flushed"] = delivered_metrics
-        round_info["phases"] = {k: round(v, 6) for k, v in phases.items()
-                                if isinstance(v, (int, float))}
+        round_info["metrics_flushed"] = len(batch)
+        round_info["phases"] = {k: round(v, 6) for k, v in phases.items()}
         if families:
             round_info["families"] = _round_family_tree(families)
         self.telemetry.flushes.record(round_info)
         self.telemetry.record_event(
             "flush", flush=round_info["flush"],
             duration_s=round_info["duration_s"],
-            metrics=delivered_metrics,
+            metrics=len(batch),
             phases=round_info["phases"],
             sinks={k: v.get("status", "running")
                    for k, v in round_info["sinks"].items()})
@@ -2101,35 +1889,15 @@ class Server:
         self.trace_plane.roll(
             [rec["name"] for rec in self.cardinality.top(16)])
 
-    def _run_readout(self, record: dict, parent: Optional[str] = None):
-        """The background half of one flush (runs on the flush-readout
-        executor under flush_async, inline otherwise): drain the swapped
-        generations — kernel dispatch, device sync, transfer, assembly —
-        plus the backfill drain, whose metrics carry their ORIGINAL
-        timestamps and so lose nothing by riding the next delivery.
-        Returns (batch, fwd, the readout's own FlushRound): the round
-        that delivers it merges the spans under `parent` (None under
-        overlap, where the readout ran before that round began)."""
-        readout = FlushRound()
-        with readout.phase("readout", parent=parent):
-            batch, fwd = self._readout_timed(record, readout)
-        if self.latency.enabled:
-            # every family was synced on its own (attribute): the
-            # waterfall's tree, on the readout's own wall clock
-            record["families"] = family_tree(readout)
-        if self.store.shard_plane is not None:
-            # mesh topology alongside the phase numbers (a dict, so the
-            # per-phase statsd emission loop skips it): the bench's
-            # mesh-scaling scenario and the waterfall view read the
-            # shard width the measured flush actually merged over
-            record["mesh"] = self.store.shard_plane.describe()
-        return batch, fwd, readout
-
-    def _readout_timed(self, record: dict, readout: FlushRound):
+    def _run_readout(self, swap: dict, rnd: FlushRound):
+        """The readout half of one flush, on the flush thread: drain
+        the swapped generations (kernel dispatch, device sync, transfer,
+        assembly) plus the backfill drain, whose metrics carry their
+        ORIGINAL timestamps, and pre-encode the forward payload."""
         batch, fwd = readout_columnstore(
-            self.store, record["swap"], self.is_local, self.aggregates,
+            self.store, swap, self.is_local, self.aggregates,
             collect_forward=self.forwarder is not None,
-            timing=readout, attribute=self.latency.enabled)
+            timing=rnd, attribute=self.latency.enabled)
         if self.backfill is not None:
             # closed historical buckets flush alongside the live
             # interval, each series timestamped at its ORIGINAL
@@ -2140,12 +1908,12 @@ class Server:
                 self.statsd.count("flush.backfilled_series_total",
                                   len(backfilled))
         if self.is_local and self.forwarder is not None and len(fwd):
-            # wire-encode the forward payload HERE, on the readout
-            # executor, so serialization overlaps sink delivery — the
-            # forward thread later finds fwd.wire pre-built and skips
-            # straight to the POST. Carryover merges invalidate it.
+            # wire-encode the forward payload HERE, on the flush
+            # thread, under the round's own span — the forward thread
+            # finds fwd.wire pre-built and skips straight to the POST.
+            # Carryover merges invalidate it.
             from veneur_tpu.forward.convert import forwardable_to_wire
-            with readout.phase("forward_encode", parent="readout"):
+            with rnd.phase("forward_encode", parent="readout"):
                 try:
                     fwd.wire = forwardable_to_wire(fwd)
                 except Exception:
@@ -2154,9 +1922,10 @@ class Server:
         return batch, fwd
 
     def _readout_executor(self):
-        """Get-or-create the background flush executor (flush_async),
-        supervised like the flush loop itself — a wedged readout (hung
-        device link mid-transfer) trips the same stall ladder."""
+        """Get-or-create the worker the query plane's live reads run
+        their readouts on (core/query.py), supervised as `flush-readout`
+        like the flush loop itself — a wedged readout (hung device link
+        mid-transfer) trips the same stall ladder."""
         if self._flush_executor is None:
             from veneur_tpu.core.flushexec import FlushReadoutExecutor
             self.overload.supervisor.register(

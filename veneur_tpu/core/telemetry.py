@@ -473,14 +473,12 @@ class _Phase:
 
 
 class FlushRound:
-    """The span source of one flush round (or of one readout, which
-    under `flush_async` runs a tick ahead of the round that delivers it
-    and is `merge`d in there). Handed to whatever works for the round:
-    the readout executor, the sink threads through their `FlushBatch`,
-    the POST workers. `spans` entries are {name, parent, thread,
-    start_s, wall_s, cpu_s, ...tags}, `start_s` counted from the
-    round's start; `phases[name + "_s"]` sums the wall of every span of
-    that name."""
+    """The span source of one flush round. Handed to whatever works
+    for the round: the readout, the sink threads through their
+    `FlushBatch`, the POST workers. `spans` entries are {name, parent,
+    thread, start_s, wall_s, cpu_s, ...tags}, `start_s` counted from
+    the round's start; `phases[name + "_s"]` sums the wall of every
+    span of that name."""
 
     def __init__(self):
         self.start_unix = time.time()
@@ -500,19 +498,6 @@ class FlushRound:
         with self._lock:
             self.spans.append(rec)
             self.phases[key] = self.phases.get(key, 0.0) + wall_s
-
-    def merge(self, other: "FlushRound") -> None:
-        """Take over another round's spans (re-based on this round's
-        start) and phase totals: a delivered readout's."""
-        shift = other.t0 - self.t0
-        with other._lock:
-            spans = [dict(s, start_s=s["start_s"] + shift)
-                     for s in other.spans]
-            phases = dict(other.phases)
-        with self._lock:
-            self.spans.extend(spans)
-            for key, secs in phases.items():
-                self.phases[key] = self.phases.get(key, 0.0) + secs
 
     def cpu_s(self) -> float:
         """CPU seconds of every thread that worked for the round: each

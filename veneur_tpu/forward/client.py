@@ -268,9 +268,9 @@ class ForwardClient:
                     "forward breaker %s to %s: carrying %d metrics over",
                     self.breaker.state, self.address, len(fwd))
             return 0
-        # prefer the frames the readout executor pre-encoded (overlapped
-        # with sink delivery); carryover merges invalidate the cache, so
-        # a non-None wire is always current
+        # prefer the frames the flush's readout pre-encoded; carryover
+        # merges invalidate the cache, so a non-None wire is always
+        # current
         if len(fwd):
             protos = (fwd.wire if fwd.wire is not None
                       else forwardable_to_wire(fwd))

@@ -2357,7 +2357,7 @@ int64_t vnt_route_parse(const uint8_t* buf, int64_t len,
 // reference cmd/veneur-emit/main.go:169): pre-rendered datagrams are sent
 // to a connected UDP socket in sendmmsg bursts from native threads, so
 // load generation never competes with the server for the GIL. Used by
-// bench.py; not part of the serving path.
+// tests/test_stress.py; not part of the serving path.
 
 namespace {
 

@@ -11,8 +11,8 @@ apply/readout/merge kernels through the shape-ladder prewarmer
 (core/flushexec.py) against throwaway M-shard tables, so the cutover
 never pays a cold XLA retrace.
 
-**cutover** — at a flush boundary (under the server's flush lock, with
-every in-flight background readout joined first): atomically
+**cutover** — at a flush boundary (under the server's flush lock, which
+no flush's readout outlives): atomically
 `reshard_swap` each family's old generation, capture the merged
 per-row state, WAL-append it as metricpb wire — one spool segment per
 migrating digest-range cell — *before* any state moves, then merge the
@@ -347,9 +347,9 @@ class ReshardController:
     # -- cutover ---------------------------------------------------------
 
     def cutover(self, plane: ShardedServingPlane) -> None:
-        """The atomic topology swap. Everything — join, swap, capture,
-        WAL append, merge-back, segment pop — happens under the
-        server's flush lock, so no flush can deliver half-migrated
+        """The atomic topology swap. Everything — swap, capture, WAL
+        append, merge-back, segment pop — happens under the server's
+        flush lock (no readout outlives it), so no flush can deliver half-migrated
         state downstream and the popped-segment invariant holds (see
         module docstring)."""
         server = self._server
@@ -359,21 +359,6 @@ class ReshardController:
             self.state = "cutover"
         try:
             with server._flush_lock:
-                # join in-flight background readouts first: a pending
-                # readout applies its staged columns through the LIVE
-                # routing attributes, which the retopo is about to
-                # replace. Futures cache results, so the flush loop's
-                # own later join is a cheap re-read.
-                for rec in list(server._inflight_flushes):
-                    pending = rec.get("pending")
-                    if pending is not None:
-                        try:
-                            pending.result(timeout=120.0)
-                        except Exception:
-                            logger.exception(
-                                "reshard: in-flight readout join "
-                                "failed; its interval rides the "
-                                "readout-miss carry path")
                 store = server.store
                 n_old = store.shard_plane.n
                 n_new = plane.n

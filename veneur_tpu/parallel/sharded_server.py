@@ -59,11 +59,12 @@ class ShardedServingPlane:
         self.n = len(self.devices)
         self.routing = routing
         self.mesh = collectives.local_mesh(self.devices)
-        # per-shard routed-sample counters, keyed by family. Writers
-        # used to all sit under a table's apply lock; the overlapped
-        # flush's background readout folds counts lock-free, so the
-        # numpy read-modify-write adds now need their own leaf lock
-        # (scrapes stay lock-free point reads — one row stale at worst)
+        # per-shard routed-sample counters, keyed by family. The
+        # flush thread's readout folds its last pending batch's counts
+        # outside the table's apply lock while the ingest thread
+        # applies, so the numpy read-modify-write adds need their own
+        # leaf lock (scrapes stay lock-free point reads — one row stale
+        # at worst)
         self._samples: Dict[str, np.ndarray] = {}
         # wall seconds spent routing batches to shards (mask, tile,
         # device_put), per family: host work one device never pays

@@ -1,5 +1,5 @@
 """Where JAX's persistent compilation cache lives — one rule for the
-server, bench.py, chip_smoke.py and the soak drivers.
+server, chip_smoke.py and the soak drivers.
 
 The directory is part of every cache key's lookup, so it must not move
 between runs: never a temporary name, a pid or a timestamp.
