@@ -16,6 +16,7 @@ from __future__ import annotations
 
 import json
 import struct
+import sys
 import threading
 
 import numpy as np
@@ -302,18 +303,308 @@ def test_datadog_pipeline_under_thread_stress(monkeypatch):
                    for t in threading.enumerate())
 
 
-def test_datadog_encode_bodies_cuts_encodes_parts():
-    """`encode_bodies` emits only runs of `per_body` parts that have a
-    successor, and returns the rest: together, `encode()`'s parts."""
+@pytest.mark.parametrize("encoder", ["native", "python"])
+def test_datadog_encode_bodies_cuts_encodes_parts(encoder):
+    """`encode_bodies` emits only runs of `per_body` series that have a
+    successor, and returns the rest: together, `encode()`'s parts (the
+    native encoder's parts hold several series, joined as the body
+    joins them)."""
     batch, _ = _mk_batch(_extras())
-    enc = DatadogColumnarEncoder(_dd_sink())
+    enc = _dd_encoder(_dd_sink(), encoder)
     parts, checks = enc.encode(batch)
     for per_body in (1, 2, 7, len(parts) // 2, len(parts), len(parts) + 1):
         emitted = []
         rest, checks_2 = enc.encode_bodies(batch, per_body, emitted.append)
-        assert emitted + [rest] == [parts[i:i + per_body] for i in
-                                    range(0, len(parts), per_body)]
+        assert rest
+        assert [b",".join(run) for run in emitted + [rest]] == [
+            b",".join(parts[i:i + per_body])
+            for i in range(0, len(parts), per_body)]
+        if encoder == "python":
+            assert emitted + [rest] == [parts[i:i + per_body] for i in
+                                        range(0, len(parts), per_body)]
         assert [c.name for c in checks_2] == [c.name for c in checks]
+
+
+# -- the native encoder and its prefix arena -------------------------------
+
+
+def _dd_encoder(sink, encoder: str) -> DatadogColumnarEncoder:
+    """The sink's encoder on the named path: "native" where the
+    library builds (skips where it does not), "python" with the
+    library taken away."""
+    enc = DatadogColumnarEncoder(sink)
+    if encoder == "python":
+        enc._lib = None
+    elif enc._lib is None:
+        pytest.skip("the native series encoder did not build")
+    assert enc.name == encoder
+    return enc
+
+
+def _cut_bodies(enc, batch, per_body):
+    """-> the bodies `encode_bodies` cuts, as the sink would post them."""
+    emitted = []
+    rest, _checks = enc.encode_bodies(batch, per_body, emitted.append)
+    return [b'{"series":[' + b",".join(run) + b"]}"
+            for run in emitted + [rest]]
+
+
+def _section_series(enc, batch) -> int:
+    """How many of `encode()`'s parts come from `batch.sections`."""
+    parts, _checks = enc.encode(batch)
+    return (len(parts) - sum(bs.line_count() for bs in batch.bucket_sections)
+            - sum(m.type != MetricType.STATUS for m in batch.extras))
+
+
+@pytest.mark.parametrize("cut", [
+    "one_body", "exact_multiple", "remainder", "larger_than_batch",
+    "one_series_a_body", "inside_a_section", "at_a_section_boundary",
+    "inside_the_bucket_rows", "at_the_first_extra"])
+def test_native_bodies_are_the_python_loops(cut):
+    """The native encoder's bodies are the Python loop's, byte for
+    byte, wherever the cut falls."""
+    batch, _ = _mk_batch(_extras())
+    sink = _dd_sink()
+    loop = _dd_encoder(sink, "python")
+    parts, _checks = loop.encode(batch)
+    in_sections = _section_series(loop, batch)
+    first = batch.sections[0].names.shape[0]
+    assert batch.sections[0].names.tolist().count("dropme.x") == 1
+    per_body = {
+        # the first section has one dropped row: its series end at
+        # first - 1, and the next section's begin there
+        "inside_a_section": first - 3,
+        "at_a_section_boundary": first - 1,
+        "inside_the_bucket_rows": in_sections + 2,
+        "at_the_first_extra": len(parts) - 3,
+    }.get(cut) or _per_body(cut, len(parts))
+    enc = _dd_encoder(sink, "native")
+    for _ in range(2):   # arena cold, then warm
+        assert _cut_bodies(enc, batch, per_body) == _bodies(parts, per_body)
+        assert enc.native_rows == in_sections
+    assert enc.prefix_renders == 0
+    assert loop.native_rows == 0
+
+
+class _Flushes:
+    """One column store flushed again and again: its sections' names
+    and tags are the tables' cached objects, the same from flush to
+    flush for a row's lifetime."""
+
+    def __init__(self):
+        self.store = ColumnStore(counter_capacity=64, gauge_capacity=64,
+                                 histo_capacity=64, set_capacity=32,
+                                 batch_cap=256)
+        self.parser = Parser()
+
+    def flush(self, lines, reclaim_idle=0):
+        for line in lines:
+            self.parser.parse_metric_fast(line, self.store.process)
+        self.store.apply_all_pending()
+        batch, _fwd = flush_columnstore_batch(self.store, False, PCTS, AGGS)
+        if reclaim_idle:
+            self.store.counters.reclaim_idle(reclaim_idle)
+        return batch
+
+
+def _key_lines(counters=range(10), tail=True):
+    lines = [b"k.c%d:%d|c|#env:t,i:%d" % (i, i + 1, i) for i in counters]
+    for i in range(6):
+        lines += [b"k.g%d:%d.5|g|#env:t" % (i, i),
+                  b"k.t%d:%d|ms|#env:t" % (i, 10 + i),
+                  b"k.t%d:%d|ms|#env:t" % (i, 30 + i),
+                  b"k.s%d:u%d|s" % (i, i), b"k.l%d:%d|l|#svc:x" % (i, i + 1)]
+    if tail:   # the last rows of the counter and of the gauge section
+        lines += [b"hosted:4|c|#host:other,device:sda,env:t",
+                  b"dropme.x:1|c|#env:t", b"bare:3|g"]
+    return lines
+
+
+def _section_rows(batch) -> int:
+    return sum(sec.names.shape[0] for sec in batch.sections)
+
+
+def _assert_native_is_the_loop(enc, sink, batch, per_body=7):
+    loop = _dd_encoder(sink, "python")
+    want = _bodies(loop.encode(batch)[0], per_body)
+    assert _cut_bodies(enc, batch, per_body) == want
+    return want
+
+
+def test_arena_is_reused_whole_by_a_second_flush_of_the_same_keys():
+    flushes, sink = _Flushes(), _dd_sink()
+    enc = _dd_encoder(sink, "native")
+    first = flushes.flush(_key_lines())
+    _assert_native_is_the_loop(enc, sink, first)
+    assert enc.prefix_renders == _section_rows(first)
+    for _ in range(2):
+        again = flushes.flush(_key_lines())
+        bodies = _assert_native_is_the_loop(enc, sink, again)
+        assert enc.prefix_renders == 0
+        # the dropped row renders nothing and counts toward no body
+        assert enc.native_rows == _section_rows(again) - 1
+        assert enc.native_rows == _section_series(enc, again)
+    joined = b"".join(bodies)
+    assert b"dropme.x" not in joined and b'"i:' not in joined
+    assert b'"host":"other"' in joined and b'"device":"sda"' in joined
+
+
+def test_arena_rows_after_a_key_that_comes_or_goes_are_looked_up_again():
+    """A key that stops reporting shifts the rows behind it in its
+    section, and so does its return: those rows miss the arena (their
+    prefixes come from `_frags`), the rest of the flush is reused."""
+    flushes, sink = _Flushes(), _dd_sink()
+    enc = _dd_encoder(sink, "native")
+    _assert_native_is_the_loop(enc, sink, flushes.flush(_key_lines()))
+    without = [i for i in range(10) if i != 5]
+    _assert_native_is_the_loop(enc, sink,
+                               flushes.flush(_key_lines(without)))
+    [counters] = [s for s in flushes.flush(_key_lines(without)).sections
+                  if "k.c0" in s.names.tolist()]
+    behind = counters.names.shape[0] - counters.names.tolist().index("k.c6")
+    assert behind >= 4
+    _assert_native_is_the_loop(enc, sink,
+                               flushes.flush(_key_lines(without)))
+    assert enc.prefix_renders == 0
+    _assert_native_is_the_loop(enc, sink, flushes.flush(_key_lines()))
+    assert enc.prefix_renders == behind + 1   # k.c5 and the rows behind it
+    _assert_native_is_the_loop(enc, sink, flushes.flush(_key_lines()))
+    assert enc.prefix_renders == 0
+
+
+def test_arena_serves_a_section_that_is_its_first_rows():
+    """Keys at a section's end that skip an interval (here the counter
+    section's last two rows, one of them dropped by its name, and the
+    gauge section's last) cost nothing when they go nor when they
+    return: the kept arena's first rows serve the shorter section."""
+    flushes, sink = _Flushes(), _dd_sink()
+    enc = _dd_encoder(sink, "native")
+    _assert_native_is_the_loop(enc, sink, flushes.flush(_key_lines()))
+    for tail in (False, True, False, False, True):
+        batch = flushes.flush(_key_lines(tail=tail))
+        bodies = _assert_native_is_the_loop(enc, sink, batch)
+        assert enc.prefix_renders == 0
+        assert enc.native_rows == _section_rows(batch) - tail
+        assert (b'"hosted"' in b"".join(bodies)) == tail
+
+
+def test_arenas_outlive_a_flush_of_other_sections_and_age_out(monkeypatch):
+    """An interval with one stray series (a server's own, alone in its
+    flush) leaves the arenas of the sections it lacks in place; a
+    section that stays away longer than `ARENA_IDLE_FLUSHES` flushes
+    renders anew."""
+    from veneur_tpu.core import egress
+
+    flushes, sink = _Flushes(), _dd_sink()
+    enc = _dd_encoder(sink, "native")
+    full = flushes.flush(_key_lines())
+    _assert_native_is_the_loop(enc, sink, full)
+    arenas = len(enc._arenas)
+    assert arenas == len(full.sections)
+    for _ in range(egress.ARENA_IDLE_FLUSHES):
+        _assert_native_is_the_loop(
+            enc, sink, flushes.flush([b"stray.unique:u1|s|#service:me"]))
+    assert enc.prefix_renders <= 1 and len(enc._arenas) == arenas + 1
+    _assert_native_is_the_loop(enc, sink, flushes.flush(_key_lines()))
+    assert enc.prefix_renders == 0
+    for _ in range(egress.ARENA_IDLE_FLUSHES + 1):
+        stray = flushes.flush([b"stray.unique:u1|s|#service:me"])
+        _assert_native_is_the_loop(enc, sink, stray)
+    assert len(enc._arenas) == 1
+    again = flushes.flush(_key_lines())
+    _assert_native_is_the_loop(enc, sink, again)
+    assert enc.prefix_renders == _section_rows(again)
+
+
+def test_arena_sees_a_row_recycled_to_another_key():
+    flushes, sink = _Flushes(), _dd_sink()
+    enc = _dd_encoder(sink, "native")
+    table = flushes.store.counters
+    _assert_native_is_the_loop(enc, sink, flushes.flush(_key_lines()))
+    [gone] = [r for r, m in enumerate(table.meta)
+              if m is not None and m.name == "k.c9"]
+    for _ in range(3):
+        _assert_native_is_the_loop(
+            enc, sink, flushes.flush(_key_lines(range(9)), reclaim_idle=1))
+    assert table.meta[gone] is None and enc.prefix_renders == 0
+    batch = flushes.flush(_key_lines(range(9)) + [b"k.new:7|c|#env:t"])
+    assert table.meta[gone].name == "k.new"
+    bodies = _assert_native_is_the_loop(enc, sink, batch)
+    assert b"k.new" in b"".join(bodies) and b"k.c9" not in b"".join(bodies)
+    assert 1 <= enc.prefix_renders <= 3   # k.new; hosted, dropme behind it
+
+
+def test_arena_sees_a_tags_list_that_is_another_object():
+    flushes, sink = _Flushes(), _dd_sink()
+    enc = _dd_encoder(sink, "native")
+    _assert_native_is_the_loop(enc, sink, flushes.flush(_key_lines()))
+    batch = flushes.flush(_key_lines())
+    section = batch.sections[1]
+    section.tags[2] = list(section.tags[2]) + ["late:tag"]
+    bodies = _assert_native_is_the_loop(enc, sink, batch)
+    assert enc.prefix_renders == 1
+    assert b"".join(bodies).count(b'"late:tag"') == 1
+
+
+def test_arena_survives_a_frag_cache_reset_mid_flush(monkeypatch):
+    from veneur_tpu.core import egress
+
+    monkeypatch.setattr(egress, "FRAG_CACHE_CAP", 5)
+    flushes, sink = _Flushes(), _dd_sink()
+    enc = _dd_encoder(sink, "native")
+    first = flushes.flush(_key_lines())
+    _assert_native_is_the_loop(enc, sink, first)
+    assert len(enc._frags) <= 5 < _section_rows(first)
+    _assert_native_is_the_loop(enc, sink, flushes.flush(_key_lines()))
+    assert enc.prefix_renders == 0
+
+
+def test_arena_of_a_section_whose_type_changed_is_not_reused():
+    """An arena is found by its first row and its type: a gauge section
+    of the rows a counter section had renders anew (`"type":"gauge"`)."""
+    flushes, sink = _Flushes(), _dd_sink()
+    enc = _dd_encoder(sink, "native")
+    batch = flushes.flush(_key_lines())
+    _assert_native_is_the_loop(enc, sink, batch)
+    first = batch.sections[0]
+    assert first.mtype == MetricType.COUNTER
+    first.mtype = MetricType.GAUGE
+    _assert_native_is_the_loop(enc, sink, batch)
+    assert enc.prefix_renders == first.names.shape[0]
+
+
+def test_sink_without_the_library_posts_the_same_bodies(monkeypatch):
+    """With the library unavailable the Python loop is the sink's
+    encoder, chosen by that alone: same bodies, `encoder: "python"`."""
+    from veneur_tpu import native
+
+    posted = _capture_posts(monkeypatch)
+    batch, _ = _mk_batch(_extras())
+    with_library = _dd_sink(num_workers=1, flush_max_per_body=20)
+    if with_library._encoder.name != "native":
+        pytest.skip("the native series encoder did not build")
+    with_library.flush_columnar(batch)
+    want = [body for kind, _, body, _ in posted if kind == "raw"]
+    spans = batch.timing.spans
+    [encode] = [s for s in spans if s["name"] == "egress_encode"]
+    assert encode["encoder"] == "native" and encode["native_rows"] > 0
+    assert encode["prefix_renders"] == _section_rows(batch)
+
+    def no_compiler(*args, **kwargs):
+        raise FileNotFoundError("g++")
+
+    monkeypatch.setattr(native, "_SERIES", native._Unit(
+        "ddseries.cc", "libvntddseries-absent", native._declare_series))
+    monkeypatch.setattr(native._SERIES, "_compile", no_compiler)
+    without = _dd_sink(num_workers=1, flush_max_per_body=20)
+    assert without._encoder.name == "python"
+    assert "g++" in native._SERIES.err
+    del posted[:], spans[:]
+    without.flush_columnar(batch)
+    assert [body for kind, _, body, _ in posted if kind == "raw"] == want
+    [encode] = [s for s in spans if s["name"] == "egress_encode"]
+    assert encode["encoder"] == "python" and encode["native_rows"] == 0
+    assert encode["prefix_renders"] == _section_rows(batch)
 
 
 # -- Prometheus ------------------------------------------------------------
@@ -530,6 +821,83 @@ def test_sink_note_egress_reports_and_tags_span():
     assert lat.calls == [("prometheus", 0.5, 0.25)]
 
 
+def test_datadog_flush_tags_its_span_with_the_encoder(monkeypatch):
+    """`note_egress` of a columnar Datadog flush names the encoder that
+    ran it on the ambient `flush.sink` span."""
+    from veneur_tpu.trace import context as trace_ctx
+
+    class _Span:
+        def __init__(self):
+            self.tags = {}
+
+        def set_tag(self, key, value):
+            self.tags[key] = value
+
+    _capture_posts(monkeypatch)
+    batch, _ = _mk_batch()
+    sink = _dd_sink()
+    span = _Span()
+    token = trace_ctx._current_span.set(span)
+    try:
+        sink.flush_columnar(batch)
+    finally:
+        trace_ctx._current_span.reset(token)
+    assert span.tags["egress.encoder"] == sink._encoder.name
+    assert sink._encoder.name in ("native", "python")
+
+
+@pytest.mark.parametrize("encoder, lost", [
+    ("native", 0), ("python", 0), ("native", 3), ("python", 3)])
+def test_datadog_flush_counts_its_own_series(monkeypatch, caplog, encoder,
+                                             lost):
+    """The flush checks its own count: the series put into bodies and
+    the rows that render to none (a dropped name prefix, a status check)
+    are the batch's rows. An encoder doctored to lose `lost` series on
+    the way to a body is counted and logged; nothing else changes."""
+    from veneur_tpu.core import egress
+
+    posted = _capture_posts(monkeypatch)
+    batch, _ = _mk_batch(_extras())
+    sink = _dd_sink(num_workers=1, flush_max_per_body=20)
+    sink._encoder = _dd_encoder(sink, encoder)
+    if lost:
+        real_add = egress._BodyCut.add
+        budget = [lost]
+
+        def losing_add(self, part, series=1):
+            if budget[0] and self.total > 30:
+                budget[0] -= 1
+                if series == 1:
+                    return      # a whole series never reaches a body
+                part = part[:bytes(part).rindex(b',{"metric"')]
+                series -= 1     # ... or the last of a native run
+            real_add(self, part, series)
+
+        def losing_extend(self, parts):
+            for part in parts:
+                self.add(part)
+
+        monkeypatch.setattr(egress._BodyCut, "add", losing_add)
+        monkeypatch.setattr(egress._BodyCut, "extend", losing_extend)
+    with caplog.at_level("ERROR", logger="veneur_tpu.sinks.datadog"):
+        sink.flush_columnar(batch)
+    [encode] = [s for s in batch.timing.spans if s["name"] == "egress_encode"]
+    assert encode["count_mismatch"] == lost
+    series = [s for kind, _, body, _ in posted if kind == "raw"
+              for s in json.loads(body)["series"]]
+    whole = len(DatadogColumnarEncoder(sink).encode(batch)[0])
+    assert len(series) == whole - lost
+    # one row is a dropped prefix and one a status check: neither is
+    # missed, and neither is a series
+    assert whole == len(batch) - 2
+    errors = [r for r in caplog.records if "datadog encode wrote" in
+              r.getMessage()]
+    assert len(errors) == (1 if lost else 0)
+    if lost:
+        assert f"wrote {whole - lost} series of a batch of {len(batch)}" \
+            in errors[0].getMessage()
+
+
 # -- sustained churn soak --------------------------------------------------
 
 
@@ -540,6 +908,7 @@ def test_egress_parity_soak():
     bytes) stay byte-exact against the legacy renderers."""
     dd = _dd_sink()
     dd_enc = DatadogColumnarEncoder(dd)
+    dd_native = _dd_encoder(dd, "native")
     prom = PrometheusColumnarRenderer()
     cx = CortexMetricSink("cortex", "http://c/api", "myhost")
     cx_enc = CortexColumnarEncoder(cx)
@@ -553,6 +922,7 @@ def test_egress_parity_soak():
             if m.type != MetricType.STATUS
             and not m.name.startswith("dropme.")]))
         assert [json.loads(p) for p in parts] == leg
+        assert _cut_bodies(dd_native, batch, 13) == _bodies(parts, 13)
         assert prom.render(batch) == render_exposition(legacy)
         frames, _ = cx_enc.encode(batch)
         want = encode_write_request(
@@ -675,6 +1045,9 @@ def test_llhist_nonzero_bins_match_the_per_row_oracle(feed):
     parts, _checks = DatadogColumnarEncoder(dd).encode(batch)
     assert [json.loads(p) for p in parts] == json.loads(json.dumps(
         [dd._dd_metric(m) for m in legacy]))
+    for per_body in (sys.maxsize, 50):
+        assert _cut_bodies(_dd_encoder(dd, "native"), batch,
+                           per_body) == _bodies(parts, per_body)
     assert PrometheusColumnarRenderer().render(batch) == \
         render_exposition(legacy)
     for mono in (False, True):

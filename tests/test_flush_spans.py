@@ -155,8 +155,10 @@ def flushed(tmp_path_factory):
         finally:
             jax.profiler.stop_trace()
         scrape_2 = _prom(base)
+        rounds = json.loads(
+            vhttp.get(base + "/debug/flush?n=4")[1])["rounds"]
         yield {"round": measured, "scrapes": (scrape_1, scrape_2),
-               "trace_dir": trace_dir, "pumped": pumped}
+               "rounds": rounds, "trace_dir": trace_dir, "pumped": pumped}
     finally:
         sock.close()
         server.shutdown()
@@ -269,6 +271,64 @@ def test_debug_flush_counts_the_llhists_nonzero_bins(flushed, field):
     first, second = flushed["scrapes"]
     assert first[row] == 3 * 60
     assert second[row] == first[row] + 60
+
+
+def _section_series(rnd: dict) -> int:
+    """Series of a round that come from `batch.sections`: all but the
+    llhists' bucket lines (a line per nonzero bin and `le:+Inf`)."""
+    return (rnd["metrics_flushed"] - rnd["llhist_nonzero_bins"]
+            - rnd["llhist_rows"])
+
+
+@pytest.mark.parametrize("field", ["encoder", "native_rows",
+                                   "prefix_renders", "count_mismatch"])
+def test_debug_flush_names_the_series_encoder(flushed, field):
+    """Every round reports the same keys, so the measured one (the
+    third) finds each section's prefix arena as the second left it:
+    every section row went through the native encoder, none through
+    `_frag` (but `ssf.names_unique`, the server's own, in the round it
+    happens to land in)."""
+    if native.load_series() is None:
+        pytest.skip("the native series encoder did not build")
+    sent = flushed["round"]["sinks"]["metric:datadog"]
+    [encode] = [s for s in flushed["round"]["spans"]
+                if s["name"] == "egress_encode"]
+    assert encode[field] == sent[field]
+    if field == "prefix_renders":
+        assert sent[field] <= 1
+    elif field == "count_mismatch":
+        # the flush checked its own count: every row is in a body
+        assert sent[field] == 0
+    else:
+        assert sent[field] == {
+            "encoder": "native",
+            "native_rows": _section_series(flushed["round"])}[field]
+
+
+@pytest.mark.parametrize("row, field", [
+    ("veneur_sink_datadog_encode_native_rows_total", "native_rows"),
+    ("veneur_sink_datadog_encode_prefix_renders_total", "prefix_renders"),
+    ("veneur_sink_datadog_encode_count_mismatch_total", "count_mismatch")])
+def test_metrics_count_the_series_encoders_rows(flushed, row, field):
+    """Both counters add up what each round's `sinks.<key>` says:
+    `native_rows` a flush's section series, `prefix_renders` those of
+    the first flush and next to nothing since."""
+    if native.load_series() is None:
+        pytest.skip("the native series encoder did not build")
+    rounds = flushed["rounds"]
+    assert len(rounds) == 4 and rounds[2]["flush"] == flushed["round"]["flush"]
+    per_round = [r["sinks"]["metric:datadog"][field] for r in rounds]
+    first, second = flushed["scrapes"]
+    assert first[row] == sum(per_round[:3])
+    assert second[row] == sum(per_round)
+    series = [_section_series(r) for r in rounds]
+    if field == "native_rows":
+        assert per_round == series
+    elif field == "count_mismatch":
+        assert per_round == [0, 0, 0, 0]
+    else:
+        assert per_round[0] == series[0] >= 2520
+        assert max(per_round[1:]) <= 1
 
 
 # -- (b) the spans form a tree on one clock --------------------------------

@@ -36,6 +36,10 @@ from veneur_tpu.samplers.metrics import InterMetric, MetricType
 # sink's cache without limit; at the cap the cache resets (one cold
 # flush) rather than evicting piecemeal
 FRAG_CACHE_CAP = 1 << 20
+# a section's prefix arena is dropped once this many flushes in a row
+# did without it: a quiet interval or two must not cost the next full
+# one its arenas, and a section that is gone must not be kept for ever
+ARENA_IDLE_FLUSHES = 2
 
 _MASK64 = (1 << 64) - 1
 _INF = float("inf")
@@ -71,12 +75,43 @@ class DatadogColumnarEncoder:
     splice their `le:` tag into the open tags array first). Key order
     inside a series object differs from the legacy `_dd_metric` dict
     (tags rendered last); the parity suite compares key-order
-    normalized, which is also the JSON object contract."""
+    normalized, which is also the JSON object contract.
+
+    `encode_bodies` does that per-row work for `batch.sections` in the
+    native library (native/ddseries.cc), one GIL-free call per section
+    slice of a body, from a prefix arena kept per section
+    (`_SectionArena`); the Python loop is the fallback where the
+    library cannot be had, and what `encode` runs: the reference the
+    parity suite holds the native bytes to."""
 
     def __init__(self, sink):
+        from veneur_tpu import native
+
         self.sink = sink
         # (name, id(tags), kind) -> (tags_ref, prefix_bytes|None, has_tags)
         self._frags: Dict[tuple, tuple] = {}
+        # compiled if need be here, where the sink is built: never
+        # inside a flush
+        self._lib = native.load_series()
+        # a section's arena, found again by the identity of its first
+        # row: (id(name), id(tags), kind); the arena pins both objects
+        self._arenas: Dict[tuple, _SectionArena] = {}
+        self._flushes = 0  # `encode_bodies` calls: what arenas age by
+        # of the last `encode_bodies`: series the native encoder wrote,
+        # and section rows whose prefix came from `_frag` (arena misses;
+        # on the Python loop, every row)
+        self.native_rows = 0
+        self.prefix_renders = 0
+        # and its account: series put into bodies, and rows of the batch
+        # that render to none (a dropped name prefix, a status check);
+        # `len(batch)` is their sum, which the sink checks
+        self.series_written = 0
+        self.series_skipped = 0
+
+    @property
+    def name(self) -> str:
+        """Which encoder `encode_bodies` runs: "native" or "python"."""
+        return "python" if self._lib is None else "native"
 
     def _prefix(self, name: str, tags: list,
                 is_counter: bool) -> Tuple[Optional[bytes], bool]:
@@ -129,83 +164,245 @@ class DatadogColumnarEncoder:
 
     def encode(self, batch: FlushBatch) -> Tuple[List[bytes],
                                                  List[InterMetric]]:
-        """-> (series body parts, status checks). Joining parts with
-        b"," inside `{"series":[...]}` is the POST body."""
-        return self.encode_bodies(batch, sys.maxsize, None)
+        """-> (series body parts, one per series, from the Python loop;
+        status checks). Joining parts with b"," inside `{"series":[...]}`
+        is the POST body."""
+        return self._encode_bodies(batch, sys.maxsize, None, None)
 
     def encode_bodies(self, batch: FlushBatch, per_body: int,
                       emit) -> Tuple[List[bytes], List[InterMetric]]:
-        """`encode`, handing the parts over body by body while it runs:
-        `emit(parts)` receives each run of `per_body` parts as soon as
-        one part more exists, so whatever is emitted has a successor.
-        -> (the last 1..`per_body` parts, status checks); a batch of at
-        most `per_body` series emits nothing. Emitted runs and the
-        returned rest, in order, are `encode`'s parts cut every
-        `per_body`."""
+        """`encode`, handing the series over body by body while it
+        runs: `emit(parts)` receives each run of `per_body` series as
+        soon as one series more exists, so whatever is emitted has a
+        successor. -> (the last 1..`per_body` series, status checks); a
+        batch of at most `per_body` series emits nothing. A part is one
+        series or, from the native encoder, a run of them joined with
+        b"," (a view of the buffer it wrote): emitted runs and the
+        returned rest, each joined with b",", are `encode`'s parts cut
+        every `per_body` and joined."""
+        return self._encode_bodies(batch, per_body, emit, self._lib)
+
+    def _encode_bodies(self, batch: FlushBatch, per_body: int, emit,
+                       lib) -> Tuple[List[bytes], List[InterMetric]]:
         sink = self.sink
-        parts: List[bytes] = []
+        cut = _BodyCut(per_body, emit)
         checks: List[InterMetric] = []
-        ts_b = b"%d" % batch.timestamp
+        # what follows a series' prefix: `],"points":[[<ts>,<value>]]}`
+        mid = b'],"points":[[%d,' % batch.timestamp
         interval = sink.interval
+        self.native_rows = self.prefix_renders = 0
+        self.series_skipped = 0
+        self._flushes += 1
         for sec in batch.sections:
             is_counter = sec.mtype == MetricType.COUNTER
             vals = sec.values / interval if is_counter else sec.values
-            finite = np.isfinite(vals).all()
-            vals = vals.tolist()
-            names = sec.names.tolist()
-            tagrows = sec.tags.tolist()
-            frag = self._frag
-            lo = 0
-            while lo < len(names):
-                # a row adds at most one part: up to one past the cut
-                hi = lo + per_body + 1 - len(parts)
-                if finite:
-                    val_strs = [repr(v).encode() for v in vals[lo:hi]]
-                else:
-                    val_strs = [_json_num(v).encode() for v in vals[lo:hi]]
-                for nm, tags, val in zip(names[lo:hi], tagrows[lo:hi],
-                                         val_strs):
-                    _tags, prefix, _ht = frag(nm, tags, is_counter)
-                    if prefix is None:
-                        continue
-                    parts.append(prefix + b'],"points":[[' + ts_b + b","
-                                 + val + b"]]}")
-                lo = hi
-                parts = _emit_full(parts, per_body, emit)
+            if lib is None:
+                self._section_python(sec, is_counter, vals, mid, cut)
+            else:
+                self._section_native(lib, sec, is_counter, vals, mid, cut)
+        for key in [key for key, arena in self._arenas.items()
+                    if arena.used + ARENA_IDLE_FLUSHES < self._flushes]:
+            del self._arenas[key]
         if batch.bucket_sections:
             les = _dd_le_json()
             for bs in batch.bucket_sections:
                 for nm, tags, idxs, values in bs.rows(interval):
                     _tags, prefix, has_tags = self._frag(nm, tags, True)
                     if prefix is None:
+                        self.series_skipped += len(idxs)
                         continue
                     head = prefix + b"," if has_tags else prefix
                     for k, v in zip(idxs, values):
-                        parts.append(head + les[k]
-                                     + b'],"points":[[' + ts_b + b","
-                                     + _json_num(v).encode() + b"]]}")
-                    parts = _emit_full(parts, per_body, emit)
+                        cut.add(head + les[k] + mid
+                                + _json_num(v).encode() + b"]]}")
         for m in batch.extras:
             if sink.metric_name_prefix_drops and any(
                     m.name.startswith(p)
                     for p in sink.metric_name_prefix_drops):
+                self.series_skipped += 1
                 continue
             if m.type == MetricType.STATUS:
+                self.series_skipped += 1
                 checks.append(m)
             else:
-                parts.append(json.dumps(
+                cut.add(json.dumps(
                     sink._dd_metric(m), separators=(",", ":")).encode())
-                parts = _emit_full(parts, per_body, emit)
-        return parts, checks
+        self.series_written = cut.total
+        return cut.parts, checks
+
+    def _section_python(self, sec, is_counter: bool, vals: np.ndarray,
+                        mid: bytes, cut: "_BodyCut") -> None:
+        """One section's series, a part each: the loop the native
+        encoder's bytes are held to."""
+        finite = np.isfinite(vals).all()
+        vals = vals.tolist()
+        names = sec.names.tolist()
+        tagrows = sec.tags.tolist()
+        frag = self._frag
+        self.prefix_renders += len(names)
+        lo = 0
+        while lo < len(names):
+            # a row adds at most one part: up to one past the cut
+            hi = lo + cut.per_body + 1 - cut.held
+            if finite:
+                val_strs = [repr(v).encode() for v in vals[lo:hi]]
+            else:
+                val_strs = [_json_num(v).encode() for v in vals[lo:hi]]
+            parts: List[bytes] = []
+            for nm, tags, val in zip(names[lo:hi], tagrows[lo:hi],
+                                     val_strs):
+                _tags, prefix, _ht = frag(nm, tags, is_counter)
+                if prefix is None:
+                    self.series_skipped += 1
+                    continue
+                parts.append(prefix + mid + val + b"]]}")
+            lo = hi
+            cut.extend(parts)
+
+    def _section_native(self, lib, sec, is_counter: bool,
+                        vals: np.ndarray, mid: bytes,
+                        cut: "_BodyCut") -> None:
+        """One section's series through `vnt_dd_series`, a call per
+        slice that fits the body being cut: `mid` is the flush's
+        `],"points":[[<ts>,` fragment."""
+        if not sec.names.shape[0]:
+            return
+        arena, n = self._arena(lib, sec, is_counter)
+        self.series_skipped += sec.names.shape[0] - n
+        if arena.rendered is not None:
+            vals = vals[arena.rendered[:n]]
+        vals = np.ascontiguousarray(vals, np.float64)
+        offsets = arena.offsets
+        room = lib.vnt_dd_series_room(len(mid))
+        lo = 0
+        while lo < n:
+            k = min(cut.room(), n - lo)
+            cap = int(offsets[lo + k] - offsets[lo]) + k * room
+            out = np.empty(cap, np.uint8)
+            wrote = lib.vnt_dd_series(
+                arena.arena, offsets.ctypes.data + 8 * lo,
+                vals.ctypes.data + 8 * lo, k, mid, len(mid),
+                out.ctypes.data, cap)
+            if wrote < 0:
+                raise RuntimeError(
+                    f"vnt_dd_series: {cap} bytes cannot hold {k} series")
+            cut.add(memoryview(out)[:wrote], k)
+            lo += k
+        self.native_rows += n
+
+    def _arena(self, lib, sec,
+               is_counter: bool) -> Tuple["_SectionArena", int]:
+        """-> (the section's prefix arena, how many of its series the
+        section has). The arena is the kept one if every row is still
+        the same `str` and the same tags list as then (the identity
+        `_frags` keys on; the kept arrays pin the objects, so an
+        address cannot have been recycled), also where the section is
+        only the kept one's first rows (keys at its end did not
+        report); else it is rebuilt, the rows that differ looked up
+        through `_frag`."""
+        names = np.ascontiguousarray(sec.names)
+        tags = np.ascontiguousarray(sec.tags)
+        n = names.shape[0]
+        key = (id(names[0]), id(tags[0]), is_counter)
+        kept = self._arenas.get(key)
+        if kept is None:
+            miss = range(n)
+            prefixes = [None] * n
+        else:
+            same = min(n, kept.names.shape[0])
+            changed = np.empty(same, np.int64)
+            n_changed = lib.vnt_dd_changed_rows(
+                names.ctypes.data, kept.names.ctypes.data,
+                tags.ctypes.data, kept.tags.ctypes.data,
+                same, changed.ctypes.data)
+            if n_changed == 0 and n == same:
+                kept.used = self._flushes
+                return kept, kept.series_among(n)
+            miss = changed[:n_changed].tolist() + list(range(same, n))
+            prefixes = kept.prefixes[:n] + [None] * (n - same)
+        for i in miss:
+            prefixes[i] = self._frag(names[i], tags[i], is_counter)[1]
+        self.prefix_renders += len(miss)
+        arena = self._arenas[key] = _SectionArena(
+            names, tags, prefixes, self._flushes)
+        return arena, arena.offsets.shape[0] - 1
 
 
-def _emit_full(parts: List[bytes], per_body: int, emit) -> List[bytes]:
-    """Hand over every run of `per_body` parts that has a successor;
-    -> the parts still held (at most `per_body`)."""
-    while len(parts) > per_body:
-        emit(parts[:per_body])
-        parts = parts[per_body:]
-    return parts
+class _SectionArena:
+    """What `encode_bodies` keeps of one section between flushes: the
+    `names` and `tags` arrays it rendered (their elements are the
+    column store's cached objects, the same for a row's lifetime), each
+    row's prefix (None: dropped by the sink's name prefixes), and the
+    prefixes of the rows that render laid back to back in `arena`, row
+    `j` of them at `offsets[j]:offsets[j + 1]`. `rendered` indexes
+    those rows in the section, None when all render; `used` is the
+    flush that last encoded from it."""
+
+    __slots__ = ("names", "tags", "prefixes", "arena", "offsets",
+                 "rendered", "used")
+
+    def __init__(self, names: np.ndarray, tags: np.ndarray,
+                 prefixes: List[Optional[bytes]], used: int):
+        self.names = names
+        self.tags = tags
+        self.prefixes = prefixes
+        self.used = used
+        kept = [p for p in prefixes if p is not None]
+        self.rendered = None
+        if len(kept) != len(prefixes):
+            self.rendered = np.fromiter(
+                (i for i, p in enumerate(prefixes) if p is not None),
+                np.int64, len(kept))
+        self.arena = b"".join(kept)
+        self.offsets = np.zeros(len(kept) + 1, np.int64)
+        np.cumsum(np.fromiter(map(len, kept), np.int64, len(kept)),
+                  out=self.offsets[1:])
+
+    def series_among(self, rows: int) -> int:
+        """How many of the first `rows` rows render."""
+        if self.rendered is None:
+            return rows
+        return int(np.searchsorted(self.rendered, rows))
+
+
+class _BodyCut:
+    """Cuts a flush's series into bodies of `per_body`: `parts` holds
+    the body being filled (`held` series in it), and a full one is
+    handed to `emit` only when a series more is about to join, so
+    whatever is emitted has a successor."""
+
+    __slots__ = ("per_body", "emit", "parts", "held", "total")
+
+    def __init__(self, per_body: int, emit):
+        self.per_body = per_body
+        self.emit = emit
+        self.parts: List[bytes] = []
+        self.held = 0
+        self.total = 0  # series added so far, over all bodies
+
+    def room(self) -> int:
+        """How many series the body being filled still takes; a full
+        one is handed over first (the caller has a series to add)."""
+        if self.held >= self.per_body:
+            self.emit(self.parts)
+            self.parts, self.held = [], 0
+        return self.per_body - self.held
+
+    def add(self, part: bytes, series: int = 1) -> None:
+        """`part`: `series` of them joined with b",", at most `room()`."""
+        self.room()
+        self.parts.append(part)
+        self.held += series
+        self.total += series
+
+    def extend(self, parts: List[bytes]) -> None:
+        """`add` for a run of parts of one series each."""
+        while parts:
+            taken = parts[:self.room()]
+            self.parts += taken
+            self.held += len(taken)
+            self.total += len(taken)
+            parts = parts[len(taken):]
 
 
 _DD_LE_JSON: Optional[List[bytes]] = None

@@ -2062,16 +2062,24 @@ class Server:
         started_unix = rnd.start_unix + sink_span["start_s"]
         child.proto.start_timestamp = int(started_unix * 1e9)
         child.finish(end_time=started_unix + duration)
-        # what the sink posted, where it times its sends into the round
-        # (its egress_post_wall spans: this thread's, inside this call)
+        # what the sink encoded and posted, where it times them into the
+        # round (its egress_encode and egress_post_wall spans: this
+        # thread's, inside this call)
         for span in list(rnd.spans):
-            if (span["name"] == "egress_post_wall"
-                    and span["thread"] == sink_span["thread"]
-                    and span["start_s"] >= sink_span["start_s"]):
-                for field in ("bodies", "bytes", "gzip_bytes",
-                              "bodies_overlapped"):
-                    outcome[field] = (outcome.get(field, 0)
-                                      + span.get(field, 0))
+            if (span["thread"] != sink_span["thread"]
+                    or span["start_s"] < sink_span["start_s"]):
+                continue
+            if span["name"] == "egress_post_wall":
+                fields = ("bodies", "bytes", "gzip_bytes",
+                          "bodies_overlapped")
+            elif span["name"] == "egress_encode" and "encoder" in span:
+                outcome["encoder"] = span["encoder"]
+                fields = ("native_rows", "prefix_renders",
+                          "count_mismatch")
+            else:
+                continue
+            for field in fields:
+                outcome[field] = outcome.get(field, 0) + span.get(field, 0)
         if was_timed_out:
             # finished after its round was declared over — keep that
             # visible while still landing the real outcome

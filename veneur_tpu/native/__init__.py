@@ -1,10 +1,12 @@
-"""Native (C++) host kernels: the batch DogStatsD parser.
+"""Native (C++) host kernels: the batch DogStatsD parser and pump
+(dogstatsd.cc) and the Datadog series encoder (ddseries.cc).
 
-The shared library is compiled from dogstatsd.cc on first use with the
-system g++ and cached next to the source, keyed by a hash of the source, so
-a source edit triggers exactly one rebuild. Everything degrades gracefully:
-if no compiler is available the package reports unavailable and callers
-stay on the pure-Python parser.
+Each translation unit is compiled into its own shared library on first
+use with the system g++ and cached next to the source, keyed by a hash of
+the source, so a source edit triggers exactly one rebuild. Everything
+degrades gracefully: if no compiler is available the package reports
+unavailable and callers stay on the pure-Python parser and encode loop.
+Both libraries are `ctypes.CDLL`s: a call releases the GIL.
 """
 
 from __future__ import annotations
@@ -21,11 +23,6 @@ import numpy as np
 logger = logging.getLogger("veneur_tpu.native")
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-_SRC = os.path.join(_HERE, "dogstatsd.cc")
-
-_lib = None
-_lib_err: str | None = None
-_lib_lock = threading.Lock()
 
 # family codes, mirroring dogstatsd.cc
 FAM_COUNTER = 0
@@ -64,22 +61,6 @@ class ChunkDesc(ctypes.Structure):
         ("dgrams", ctypes.c_int64), ("dropped", ctypes.c_int64),
         ("reader", ctypes.c_int64), ("dwell_ms", ctypes.c_int64),
     ]
-
-
-def _build_lib_path() -> str:
-    with open(_SRC, "rb") as f:
-        digest = hashlib.sha256(f.read()).hexdigest()[:16]
-    build_dir = os.path.join(_HERE, "_build")
-    os.makedirs(build_dir, exist_ok=True)
-    return os.path.join(build_dir, f"libvntdogstatsd-{digest}.so")
-
-
-def _compile(path: str) -> None:
-    tmp = path + f".tmp{os.getpid()}"
-    cmd = ["g++", "-O3", "-std=c++20", "-shared", "-fPIC",
-           "-o", tmp, _SRC]
-    subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-    os.replace(tmp, path)  # atomic vs concurrent builders
 
 
 def _declare(lib) -> None:
@@ -198,29 +179,79 @@ def _declare(lib) -> None:
         i64, ctypes.c_int32, ctypes.c_double, i64]
 
 
+def _declare_series(lib) -> None:
+    i64 = ctypes.c_int64
+    lib.vnt_dd_series.restype = i64
+    lib.vnt_dd_series.argtypes = [
+        ctypes.c_char_p, ctypes.c_void_p, ctypes.c_void_p, i64,
+        ctypes.c_char_p, i64, ctypes.c_void_p, i64]
+    lib.vnt_dd_series_room.restype = i64
+    lib.vnt_dd_series_room.argtypes = [i64]
+    lib.vnt_dd_changed_rows.restype = i64
+    lib.vnt_dd_changed_rows.argtypes = [
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_void_p, i64, ctypes.c_void_p]
+
+
+class _Unit:
+    """One translation unit and its shared library: compiled if its
+    `_build/<stem>-<source hash>.so` is missing, loaded once, and
+    remembered as unavailable (with the reason) if either failed."""
+
+    def __init__(self, source: str, stem: str, declare):
+        self.source = os.path.join(_HERE, source)
+        self.stem = stem
+        self.declare = declare
+        self.lib = None
+        self.err: str | None = None
+        self._lock = threading.Lock()
+
+    def _lib_path(self) -> str:
+        with open(self.source, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
+        build_dir = os.path.join(_HERE, "_build")
+        os.makedirs(build_dir, exist_ok=True)
+        return os.path.join(build_dir, f"{self.stem}-{digest}.so")
+
+    def _compile(self, path: str) -> None:
+        tmp = path + f".tmp{os.getpid()}"
+        cmd = ["g++", "-O3", "-std=c++20", "-shared", "-fPIC",
+               "-o", tmp, self.source]
+        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, path)  # atomic vs concurrent builders
+
+    def load(self):
+        """Returns the loaded ctypes library, or None if unavailable."""
+        if self.lib is not None or self.err is not None:
+            return self.lib
+        with self._lock:
+            if self.lib is not None or self.err is not None:
+                return self.lib
+            if os.environ.get("VENEUR_TPU_DISABLE_NATIVE"):
+                self.err = "disabled via VENEUR_TPU_DISABLE_NATIVE"
+                return None
+            try:
+                path = self._lib_path()
+                if not os.path.exists(path):
+                    self._compile(path)
+                lib = ctypes.CDLL(path)
+                self.declare(lib)
+                self.lib = lib
+            except Exception as e:  # missing g++, compile or load error
+                self.err = str(e)
+                logger.warning("native %s unavailable, using the Python "
+                               "fallback: %s",
+                               os.path.basename(self.source), e)
+        return self.lib
+
+
+_PUMP = _Unit("dogstatsd.cc", "libvntdogstatsd", _declare)
+_SERIES = _Unit("ddseries.cc", "libvntddseries", _declare_series)
+
+
 def load():
-    """Returns the loaded ctypes library, or None if unavailable."""
-    global _lib, _lib_err
-    if _lib is not None or _lib_err is not None:
-        return _lib
-    with _lib_lock:
-        if _lib is not None or _lib_err is not None:
-            return _lib
-        if os.environ.get("VENEUR_TPU_DISABLE_NATIVE"):
-            _lib_err = "disabled via VENEUR_TPU_DISABLE_NATIVE"
-            return None
-        try:
-            path = _build_lib_path()
-            if not os.path.exists(path):
-                _compile(path)
-            lib = ctypes.CDLL(path)
-            _declare(lib)
-            _lib = lib
-        except Exception as e:  # missing g++, compile error, load error
-            _lib_err = str(e)
-            logger.warning("native parser unavailable, using Python "
-                           "fallback: %s", e)
-    return _lib
+    """Returns the parser/pump library, or None if unavailable."""
+    return _PUMP.load()
 
 
 def available() -> bool:
@@ -229,7 +260,14 @@ def available() -> bool:
 
 def unavailable_reason() -> str | None:
     load()
-    return _lib_err
+    return _PUMP.err
+
+
+def load_series():
+    """Returns the Datadog series encoder's library (`vnt_dd_series`,
+    `vnt_dd_changed_rows`; core/egress.py drives them), or None if
+    unavailable."""
+    return _SERIES.load()
 
 
 class ParseResult:
@@ -262,7 +300,7 @@ class NativeReader:
                  lib=None):
         self._lib = lib if lib is not None else load()
         if self._lib is None:
-            raise RuntimeError(f"native reader unavailable: {_lib_err}")
+            raise RuntimeError(f"native reader unavailable: {_PUMP.err}")
         self._r = self._lib.vnt_reader_new(max_msgs, max_dgram)
         self.buf_ptr = self._lib.vnt_reader_buf(self._r)
         self._n1 = ctypes.c_int32()
@@ -305,7 +343,7 @@ class Engine:
     def __init__(self, lib=None):
         self._lib = lib if lib is not None else load()
         if self._lib is None:
-            raise RuntimeError(f"native engine unavailable: {_lib_err}")
+            raise RuntimeError(f"native engine unavailable: {_PUMP.err}")
         self.ptr = self._lib.vnt_new()
 
     def __del__(self):
@@ -494,7 +532,7 @@ class NativeParser:
         self._lib = lib if lib is not None else load()
         if self._lib is None:
             raise RuntimeError(
-                f"native parser unavailable: {_lib_err}")
+                f"native parser unavailable: {_PUMP.err}")
         self.engine = engine if engine is not None else Engine(self._lib)
         self._eng = self.engine.ptr
         self._cap = 0
@@ -703,7 +741,7 @@ class Blaster:
     def __init__(self, datagrams, lib=None):
         self._lib = lib if lib is not None else load()
         if self._lib is None:
-            raise RuntimeError(f"native blaster unavailable: {_lib_err}")
+            raise RuntimeError(f"native blaster unavailable: {_PUMP.err}")
         corpus = b"".join(datagrams)
         offs = np.zeros(len(datagrams), np.int64)
         lens = np.array([len(d) for d in datagrams], np.int64)
@@ -758,7 +796,7 @@ class Pump:
                  seal_age_ms: int = 100, poll_ms: int = 50, lib=None):
         self._lib = lib if lib is not None else load()
         if self._lib is None:
-            raise RuntimeError(f"native pump unavailable: {_lib_err}")
+            raise RuntimeError(f"native pump unavailable: {_PUMP.err}")
         self.engine = engine  # keepalive: pump threads read the C table
         fd_arr = (ctypes.c_int32 * len(fds))(*fds)
         self._p = self._lib.vnt_pump_new(

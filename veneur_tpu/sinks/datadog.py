@@ -55,7 +55,10 @@ class DatadogMetricSink(MetricSink):
         self.exclude_tags_prefix_by_prefix_metric = dict(
             exclude_tags_prefix_by_prefix_metric or {})
         self.timeout = timeout
-        self._encoder = None  # DatadogColumnarEncoder, built lazily
+        # built here, with the native library it loads (and compiles on
+        # a fresh tree): before the server is ready, never in a flush
+        from veneur_tpu.core.egress import DatadogColumnarEncoder
+        self._encoder = DatadogColumnarEncoder(self)
 
     def name(self) -> str:
         return self._name
@@ -165,6 +168,13 @@ class DatadogMetricSink(MetricSink):
         falls back); after one, the workers are waited for and
         `SeriesPartlySent` is raised: no series is posted twice.
 
+        The encode is the native encoder's where its library could be
+        had (`encoder` in the round's `sinks.<key>`, beside the series
+        it wrote and the rows it had to look up in Python), and the
+        hand-off then carries a body's few chunks, not a part a series.
+        Either way the series put into bodies are counted against the
+        batch's rows (`count_mismatch`, logged as an error when not 0).
+
         Timed into the round that delivers the batch (`batch.timing`):
         `egress_encode` here; per body `egress_join`, `egress_gzip` and
         `egress_http` on whichever thread sends it; `egress_post_wall`
@@ -173,17 +183,26 @@ class DatadogMetricSink(MetricSink):
         before the encode ended); `egress_post_tail` from the end of
         the encode to the last answer, the part of the send that this
         thread still waits for."""
-        from veneur_tpu.core.egress import DatadogColumnarEncoder
-
         rnd = batch.timing
         enc = self._encoder
-        if enc is None:
-            enc = self._encoder = DatadogColumnarEncoder(self)
         posts = _BodyPosts(self, rnd)
         try:
-            with rnd.phase("egress_encode", parent="sink") as encode:
+            with rnd.phase("egress_encode", parent="sink",
+                           encoder=enc.name) as encode:
                 rest, checks = enc.encode_bodies(
                     batch, self.flush_max_per_body, posts.hand_off)
+                # the flush checks its own count: every row of the
+                # batch was put into a body or renders to none
+                mismatch = abs(len(batch) - enc.series_skipped
+                               - enc.series_written)
+                encode.update(native_rows=enc.native_rows,
+                              prefix_renders=enc.prefix_renders,
+                              count_mismatch=mismatch)
+            if mismatch:
+                logger.error(
+                    "datadog encode wrote %d series of a batch of %d "
+                    "(%d of them render to none)", enc.series_written,
+                    len(batch), enc.series_skipped)
         except Exception as e:
             if not posts.workers:
                 raise
@@ -197,7 +216,16 @@ class DatadogMetricSink(MetricSink):
                 f"{len(posts.errors)} of {len(posts.sent)} bodies failed "
                 "on a POST worker") from posts.errors[0]
         self._post_checks(checks)
-        self.note_egress(encode["wall_s"], tail)
+        statsd = getattr(self, "_statsd", None)
+        if statsd is not None:
+            tags = [f"sink:{self._name}"]
+            statsd.count("sink.datadog.encode.native_rows",
+                         enc.native_rows, tags=tags)
+            statsd.count("sink.datadog.encode.prefix_renders",
+                         enc.prefix_renders, tags=tags)
+            statsd.count("sink.datadog.encode.count_mismatch",
+                         mismatch, tags=tags)
+        self.note_egress(encode["wall_s"], tail, encoder=enc.name)
 
     def _post_parallel(self, chunks, post_one) -> None:
         """The legacy flush's send: every chunk exists before the first
@@ -352,8 +380,15 @@ class _BodyPosts:
                 self.errors.append(e)
 
     def _join(self, parts: List[bytes]) -> bytes:
+        """The body of these parts, copied once (a native part is
+        megabytes long)."""
         with self.rnd.phase("egress_join", parent="sink"):
-            return b'{"series":[' + b",".join(parts) + b"]}"
+            pieces = [b'{"series":[']
+            for part in parts:
+                pieces.append(part)
+                pieces.append(b",")
+            pieces[-1] = b"]}"
+            return b"".join(pieces)
 
     def _post(self, body: bytes) -> None:
         sent = {"bytes": len(body)}
