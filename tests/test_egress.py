@@ -197,7 +197,8 @@ def test_datadog_columnar_fallback_on_encoder_error(monkeypatch):
     sink = _dd_sink(num_workers=1, flush_max_per_body=20)
     sink._encoder = _FailingEncoder(sink, bodies_before=0)
     sink.flush_batch(batch)  # must not raise; legacy path delivers
-    assert {kind for kind, *_ in posted} == {"json"}
+    # the legacy flush dumps its bodies itself: raw, as the columnar's
+    assert {kind for kind, *_ in posted} == {"raw"}
     series = [s for _, url, body, _ in posted if "/series" in url
               for s in json.loads(body)["series"]]
     assert len(series) == len(DatadogColumnarEncoder(sink).encode(batch)[0])

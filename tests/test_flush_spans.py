@@ -64,6 +64,12 @@ PUMP_ROWS = INGEST_ROWS[:4] + ("veneur_ingest_ring_stalls_total",)
 # feeds: tests/test_sharded_flush_loop.py pins them on such a server;
 # here, on one shard, what they read must be absent (not 0)
 MESH_ONLY = ("flush.merge_ms", "ingest.shard_route_s", "mesh.merge_rounds")
+# metrics of the routed cell that only a server with
+# `features.enable_metric_sink_routing` feeds: tests/test_routed_flush.py
+# pins them on such a server; here, with routing off, absent (not 0)
+ROUTED_ONLY = ("flush.route_ms", "flush.materialize_ms",
+               "flush.egress_select_ms", "flush.routed_rows",
+               "flush.unrouted_rows")
 
 
 class _Intake(BaseHTTPRequestHandler):
@@ -425,7 +431,7 @@ def test_apply_and_readout_kernels_carry_their_scope():
 def test_layer_metric_reads_something_the_program_produces(flushed, path):
     with open(path) as f:
         reader = json.load(f)["reader"]
-    if os.path.basename(path)[:-len(".json")] in MESH_ONLY:
+    if os.path.basename(path)[:-len(".json")] in MESH_ONLY + ROUTED_ONLY:
         assert not set(reader.get("keys", ())) & set(
             flushed["round"]["phases"])
         assert reader.get("row") not in flushed["scrapes"][1]

@@ -22,7 +22,7 @@ from veneur_tpu.config import Config, SinkConfig
 from veneur_tpu.core import networking
 from veneur_tpu.core.columnstore import ColumnStore
 from veneur_tpu.core.latency import family_tree
-from veneur_tpu.core.telemetry import FlushRound
+from veneur_tpu.core.telemetry import FlushRound, current_round
 from veneur_tpu.core.flusher import (
     FlushBatch, ForwardableState, flush_columnstore_batch,
     readout_columnstore, swap_columnstore)
@@ -1748,11 +1748,31 @@ class Server:
             # routing annotates per-metric sink sets, so it needs
             # objects; materialize once here and every sink thread
             # shares the list
-            for metric in batch.materialize():
-                route = set()
-                for rule in self._routing:
-                    route.update(rule.route(metric.name, metric.tags))
-                metric.sinks = route
+            with rnd.phase("route", parent="flush"):
+                with rnd.phase("materialize", parent="route"):
+                    metrics = batch.materialize()
+                routed: Dict[str, int] = {}
+                unrouted = 0
+                with rnd.phase("route_match", parent="route"):
+                    for metric in metrics:
+                        route = set()
+                        for rule in self._routing:
+                            route.update(
+                                rule.route(metric.name, metric.tags))
+                        metric.sinks = route
+                        for to in route:
+                            routed[to] = routed.get(to, 0) + 1
+                        if not route:
+                            # delivered nowhere
+                            unrouted += 1
+            self.statsd.count("flush.route.materialized_rows", len(metrics))
+            for to, n in routed.items():
+                self.statsd.count("flush.route.routed_rows", n,
+                                  tags=[f"sink:{to}"])
+            self.statsd.count("flush.route.unrouted_rows", unrouted)
+            round_info["routing"] = {
+                "rules": len(self._routing), "materialized": len(metrics),
+                "routed": routed, "unrouted": unrouted}
 
         for sink in self.metric_sinks:
             key = f"metric:{sink.name()}"
@@ -2028,15 +2048,18 @@ class Server:
         # forward client reads it to inject (trace_id, span_id) gRPC
         # metadata, which is how the interval trace crosses the tier.
         # Gated on the delivered round being traced so unsampled
-        # intervals add no metadata downstream.
+        # intervals add no metadata downstream. The round itself is
+        # ambient too, for a sink's legacy flush(list) to time into.
         ctx_token = None
         if span_traced:
             from veneur_tpu.trace import context as trace_ctx
             ctx_token = trace_ctx._current_span.set(child)
+        round_token = current_round.set(rnd)
         try:
             with rnd.phase("sink", parent="flush", sink=key) as sink_span:
                 ok = target(*args)
         finally:
+            current_round.reset(round_token)
             if ctx_token is not None:
                 from veneur_tpu.trace import context as trace_ctx
                 trace_ctx._current_span.reset(ctx_token)
@@ -2182,10 +2205,12 @@ class Server:
                     sink.flush(batch.materialize())
                 self.ledger.note("egress.acked", len(batch), key=name)
                 return ok
-            selected = [mm for mm in batch.materialize()
-                        if mm.sinks is None or name in mm.sinks]
-            if sc is not None:
-                selected = _apply_sink_filters(selected, sc)
+            with batch.timing.phase("egress_select", parent="sink",
+                                    sink=key):
+                selected = [mm for mm in batch.materialize()
+                            if mm.sinks is None or name in mm.sinks]
+                if sc is not None:
+                    selected = _apply_sink_filters(selected, sc)
             current = selected
             if starting is not None:
                 starting.stop()

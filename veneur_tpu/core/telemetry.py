@@ -30,6 +30,7 @@ from __future__ import annotations
 
 import bisect
 import contextlib
+import contextvars
 import json
 import logging
 import sys
@@ -470,6 +471,13 @@ class _Phase:
 
     def __exit__(self, *exc) -> None:
         self.stop()
+
+
+# the round a sink thread works for, while it does: a sink's legacy
+# `flush(metrics)` is handed a list, not the `FlushBatch` that carries
+# its round, so it times itself into this one
+current_round: "contextvars.ContextVar[Optional[FlushRound]]" = \
+    contextvars.ContextVar("veneur_flush_round", default=None)
 
 
 class FlushRound:

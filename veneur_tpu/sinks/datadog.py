@@ -15,11 +15,14 @@ Behavioral parity with reference sinks/datadog/datadog.go (660 LoC):
 from __future__ import annotations
 
 import collections
+import json
 import logging
 import queue
 import threading
+import time
 from typing import Any, Dict, List, Sequence
 
+from veneur_tpu.core.telemetry import FlushRound, current_round
 from veneur_tpu.samplers.metrics import InterMetric, MetricType
 from veneur_tpu.sinks import (
     MetricSink, SpanSink, register_metric_sink, register_span_sink,
@@ -107,29 +110,46 @@ class DatadogMetricSink(MetricSink):
     # -- flush ------------------------------------------------------------
 
     def flush(self, metrics: List[InterMetric]) -> None:
-        import time as _time
-
+        """The legacy flush, of materialised `InterMetric`s: what a
+        routed, filtered or spilled interval takes, and `flush_batch`'s
+        fallback. Nothing overlaps: this thread scans the list into
+        series (`egress_encode`, `encoder=legacy`), dumps every
+        `flush_max_per_body` of them into a body (`egress_join`), and
+        only then are the bodies gzipped and posted (`egress_post_wall`
+        around `_post_parallel`; per body `egress_gzip`, `egress_http`),
+        by up to `num_workers` threads, this one among them. The spans
+        are the columnar flush's, by name, timed into the round whose
+        sink thread this is (`telemetry.current_round`)."""
+        rnd = current_round.get() or FlushRound()
+        t0 = time.perf_counter()
         # single encode pass: name-prefix drop, status split, and
         # series conversion fold into one scan of the metric list
-        t0 = _time.perf_counter()
         drops = self.metric_name_prefix_drops
         checks: List[InterMetric] = []
         series: List[dict] = []
-        for m in metrics:
-            if drops and any(m.name.startswith(p) for p in drops):
-                continue
-            if m.type == MetricType.STATUS:
-                checks.append(m)
-            else:
-                series.append(self._dd_metric(m))
-        encode_s = _time.perf_counter() - t0
-        t1 = _time.perf_counter()
-        if series:
-            chunks = [series[i:i + self.flush_max_per_body]
-                      for i in range(0, len(series), self.flush_max_per_body)]
-            self._post_parallel(chunks, self._post_series_safe)
+        with rnd.phase("egress_encode", parent="sink",
+                       encoder="legacy") as encode:
+            for m in metrics:
+                if drops and any(m.name.startswith(p) for p in drops):
+                    continue
+                if m.type == MetricType.STATUS:
+                    checks.append(m)
+                else:
+                    series.append(self._dd_metric(m))
+        bodies: List[bytes] = []
+        for i in range(0, len(series), self.flush_max_per_body):
+            with rnd.phase("egress_join", parent="sink"):
+                bodies.append(json.dumps(
+                    {"series": series[i:i + self.flush_max_per_body]},
+                    separators=(",", ":")).encode())
+        t1 = time.perf_counter()
+        if bodies:
+            posts = _BodyPosts(self, rnd)
+            posts.open_wall()
+            self._post_parallel(bodies, posts.post)
+            posts.close_wall(encode)
         self._post_checks(checks)
-        self.note_egress(encode_s, _time.perf_counter() - t1,
+        self.note_egress(t1 - t0, time.perf_counter() - t1,
                          encoder="legacy")
 
     def flush_batch(self, batch) -> None:
@@ -228,7 +248,7 @@ class DatadogMetricSink(MetricSink):
         self.note_egress(encode["wall_s"], tail, encoder=enc.name)
 
     def _post_parallel(self, chunks, post_one) -> None:
-        """The legacy flush's send: every chunk exists before the first
+        """The legacy flush's send: every body exists before the first
         leaves, and nothing here overlaps the encode. Concurrency capped
         at num_workers POSTs, this thread one of them (reference
         datadog.go:182-207 chunks a flush across num_workers)."""
@@ -268,9 +288,6 @@ class DatadogMetricSink(MetricSink):
                        phase=phase)
         except Exception as e:
             logger.error("datadog POST /api/v1/series failed: %s", e)
-
-    def _post_series_safe(self, series: List[dict]) -> None:
-        self._post_safe("/api/v1/series", {"series": series})
 
     def _post_safe(self, path: str, payload: dict) -> None:
         url = f"{self.api_url}{path}?api_key={self.api_key}"
@@ -314,7 +331,9 @@ class _BodyPosts:
     """The sending half of one `flush_columnar`: bodies handed off as
     lists of parts, each joined, gzipped and posted by one of up to
     `num_workers` POST workers beside the encoding thread, or, when the
-    flush has one body, by the encoding thread itself."""
+    flush has one body, by the encoding thread itself. The legacy
+    `flush` uses its wall and `post` alone, for bodies that all exist
+    before the first leaves."""
 
     def __init__(self, sink: DatadogMetricSink, rnd):
         self.sink = sink
@@ -329,7 +348,7 @@ class _BodyPosts:
         """A full body with more parts to come (the encoder's `emit`,
         on the encoding thread): queue it, and start one more worker
         while fewer than `num_workers` run. Never waits."""
-        self._open_wall()
+        self.open_wall()
         self.queue.put(parts)
         if len(self.workers) < max(self.sink.num_workers, 1):
             worker = threading.Thread(
@@ -345,8 +364,8 @@ class _BodyPosts:
         body = self._join(rest) if rest and not self.workers else None
         with self.rnd.phase("egress_post_tail", parent="sink") as tail:
             if body is not None:
-                self._open_wall()
-                self._post(body)
+                self.open_wall()
+                self.post(body)
             else:
                 if rest:
                     self.queue.put(rest)
@@ -354,27 +373,33 @@ class _BodyPosts:
                     self.queue.put(None)
                 for worker in self.workers:
                     worker.join()
-        if self.wall is not None:
-            encode_end_s = encode["start_s"] + encode["wall_s"]
-            gzips = [sent["gzip"] for sent in self.sent if "gzip" in sent]
-            self.wall.stop().update(
-                bodies=len(self.sent),
-                bytes=sum(sent["bytes"] for sent in self.sent),
-                gzip_bytes=sum(g.get("bytes", 0) for g in gzips),
-                bodies_overlapped=sum(
-                    1 for g in gzips if g["start_s"] < encode_end_s))
+        self.close_wall(encode)
         return tail["wall_s"]
 
-    def _open_wall(self) -> None:
+    def open_wall(self) -> None:
         if self.wall is None:
             # ends after spans of this thread that began inside it
             self.wall = self.rnd.phase(
                 "egress_post_wall", parent="sink").start(handoff=True)
 
+    def close_wall(self, encode: dict) -> None:
+        """After the last answer: the wall's span, with what was sent
+        inside it."""
+        if self.wall is None:
+            return
+        encode_end_s = encode["start_s"] + encode["wall_s"]
+        gzips = [sent["gzip"] for sent in self.sent if "gzip" in sent]
+        self.wall.stop().update(
+            bodies=len(self.sent),
+            bytes=sum(sent["bytes"] for sent in self.sent),
+            gzip_bytes=sum(g.get("bytes", 0) for g in gzips),
+            bodies_overlapped=sum(
+                1 for g in gzips if g["start_s"] < encode_end_s))
+
     def _work(self) -> None:
         for parts in iter(self.queue.get, None):
             try:
-                self._post(self._join(parts))
+                self.post(self._join(parts))
             except Exception as e:
                 logger.exception("datadog POST worker failed on a body")
                 self.errors.append(e)
@@ -390,7 +415,7 @@ class _BodyPosts:
             pieces[-1] = b"]}"
             return b"".join(pieces)
 
-    def _post(self, body: bytes) -> None:
+    def post(self, body: bytes) -> None:
         sent = {"bytes": len(body)}
         self.sent.append(sent)
 
