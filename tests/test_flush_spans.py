@@ -69,7 +69,7 @@ MESH_ONLY = ("flush.merge_ms", "ingest.shard_route_s", "mesh.merge_rounds")
 # pins them on such a server; here, with routing off, absent (not 0)
 ROUTED_ONLY = ("flush.route_ms", "flush.materialize_ms",
                "flush.egress_select_ms", "flush.routed_rows",
-               "flush.unrouted_rows")
+               "flush.unrouted_rows", "flush.route_evaluated_rows")
 
 
 class _Intake(BaseHTTPRequestHandler):

@@ -393,6 +393,17 @@ class FlushSection:
     tags: np.ndarray    # object ndarray of List[str] (shared refs)
     mtype: MetricType
 
+    def select(self, mask: np.ndarray) -> Optional["FlushSection"]:
+        """The rows a boolean mask picks: this very section where it
+        picks all (a consumer that keeps state per section finds it
+        again, and nothing is copied), None where it picks none."""
+        if mask.all():
+            return self
+        if not mask.any():
+            return None
+        return FlushSection(self.names[mask], self.values[mask],
+                            self.tags[mask], self.mtype)
+
 
 _LE_TAGS: Optional[List[str]] = None
 
@@ -428,6 +439,58 @@ class BucketSection:
 
     def line_count(self) -> int:
         return self.le_idx.shape[0] + self.names.shape[0]
+
+    def select(self, mask: np.ndarray) -> Optional["BucketSection"]:
+        """The rows a boolean mask picks, each with all its lines, as
+        CSR again; this very section where it picks all, None where it
+        picks none."""
+        if mask.all():
+            return self
+        if not mask.any():
+            return None
+        counts = np.diff(self.indptr)
+        indptr = np.zeros(int(mask.sum()) + 1, np.int64)
+        np.cumsum(counts[mask], out=indptr[1:])
+        entries = np.repeat(mask, counts)
+        return BucketSection(self.names[mask], self.tags[mask], indptr,
+                             self.le_idx[entries], self.cum[entries],
+                             self.total[mask])
+
+    def lines(self) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """Line by line, in `materialize()`'s order (a row's entries,
+        then its `le:+Inf`): the line's row, its `le_tags()` index and
+        its value."""
+        rows = self.names.shape[0]
+        row = np.repeat(np.arange(rows), np.diff(self.indptr) + 1)
+        is_inf = np.zeros(row.shape[0], bool)
+        is_inf[self.indptr[1:] + np.arange(rows)] = True
+        le = np.empty(row.shape[0], np.int64)
+        le[is_inf] = len(le_tags()) - 1
+        le[~is_inf] = self.le_idx
+        values = np.empty(row.shape[0], np.float64)
+        values[is_inf] = self.total
+        values[~is_inf] = self.cum
+        return row, le, values
+
+    def select_lines(self, mask: np.ndarray):
+        """The lines a boolean mask over `lines()` picks, for lines of
+        one row that go different ways: this very section where it picks
+        all, None where it picks none, else a COUNTER `FlushSection` of
+        the picked lines, each tagged `base + [le:<bound>]` as
+        `materialize()` tags it (the CSR cannot leave a row's `le:+Inf`
+        out)."""
+        if mask.all():
+            return self
+        if not mask.any():
+            return None
+        row, le, values = (col[mask] for col in self.lines())
+        les = le_tags()
+        tags = np.empty(row.shape[0], object)
+        for i, (base, k) in enumerate(zip(self.tags[row].tolist(),
+                                          le.tolist())):
+            tags[i] = base + [les[k]]
+        return FlushSection(self.names[row], values, tags,
+                            MetricType.COUNTER)
 
     def rows(self, scale: float = 1.0):
         """Row by row: (name, base tags, `le_tags()` indices, values),
@@ -465,6 +528,40 @@ class FlushBatch:
         return (sum(s.names.shape[0] for s in self.sections)
                 + sum(b.line_count() for b in self.bucket_sections)
                 + len(self.extras))
+
+    def select(self, sections: Sequence[np.ndarray],
+               buckets: Sequence[np.ndarray], extras: np.ndarray,
+               bucket_lines: bool = False) -> "FlushBatch":
+        """The share of this batch that boolean masks pick: one per
+        section and one per bucket section, over its rows, and one over
+        `extras`; with `bucket_lines` a bucket section's mask is over
+        its `lines()`. Shares `timestamp` and `timing`; a section picked
+        whole is the same object in the share, one picked empty is left
+        out, and a batch picked whole is this batch (so sinks that take
+        all of it share one `materialize()`). `len()` and
+        `materialize()` of the share are those of the picked series."""
+        secs = [sec.select(mask)
+                for sec, mask in zip(self.sections, sections)]
+        bsecs = [bs.select_lines(mask) if bucket_lines else bs.select(mask)
+                 for bs, mask in zip(self.bucket_sections, buckets)]
+        if extras.all() and all(
+                a is b for a, b in zip(
+                    secs + bsecs, self.sections + self.bucket_sections)):
+            return self
+        share = FlushBatch(
+            self.timestamp,
+            [sec for sec in secs + bsecs if isinstance(sec, FlushSection)],
+            [m for m, keep in zip(self.extras, extras.tolist()) if keep],
+            [bs for bs in bsecs if isinstance(bs, BucketSection)])
+        share.timing = self.timing
+        return share
+
+    @property
+    def materialized_rows(self) -> int:
+        """How many `InterMetric`s `materialize()` has built: 0 for a
+        batch that only columnar consumers have seen."""
+        built = self._materialized
+        return 0 if built is None else len(built)
 
     def materialize(self) -> List[InterMetric]:
         with self._mat_lock:

@@ -23,6 +23,7 @@ from veneur_tpu.core import networking
 from veneur_tpu.core.columnstore import ColumnStore
 from veneur_tpu.core.latency import family_tree
 from veneur_tpu.core.telemetry import FlushRound, current_round
+from veneur_tpu.core.routing import BatchRoutes, ColumnRouter
 from veneur_tpu.core.flusher import (
     FlushBatch, ForwardableState, flush_columnstore_batch,
     readout_columnstore, swap_columnstore)
@@ -220,10 +221,11 @@ class Server:
             self.sources.append(factory(src_cfg, config))
         self._source_threads: List[threading.Thread] = []
 
-        self._routing = None
+        self._routing: Optional[ColumnRouter] = None
         if config.features.enable_metric_sink_routing:
-            self._routing = [SinkRoutingMatcher(rc)
-                             for rc in config.metric_sink_routing]
+            self._routing = ColumnRouter(
+                [SinkRoutingMatcher(rc)
+                 for rc in config.metric_sink_routing])
 
         # events & service-check samples buffered between flushes
         self._other_samples: List = []
@@ -1744,35 +1746,25 @@ class Server:
                 self.forward_client.carryover.stash(fwd)
                 self.statsd.count("flush.forward_undispatched_total", 1)
 
+        routes: Optional[BatchRoutes] = None
         if self._routing is not None:
-            # routing annotates per-metric sink sets, so it needs
-            # objects; materialize once here and every sink thread
-            # shares the list
+            # a route is a function of (name, tags), which the batch
+            # holds as columns: every row gets a route id here, kept
+            # from the last flush where the row is unchanged, and each
+            # sink thread masks its own share out of the batch
             with rnd.phase("route", parent="flush"):
-                with rnd.phase("materialize", parent="route"):
-                    metrics = batch.materialize()
-                routed: Dict[str, int] = {}
-                unrouted = 0
-                with rnd.phase("route_match", parent="route"):
-                    for metric in metrics:
-                        route = set()
-                        for rule in self._routing:
-                            route.update(
-                                rule.route(metric.name, metric.tags))
-                        metric.sinks = route
-                        for to in route:
-                            routed[to] = routed.get(to, 0) + 1
-                        if not route:
-                            # delivered nowhere
-                            unrouted += 1
-            self.statsd.count("flush.route.materialized_rows", len(metrics))
+                routes = self._routing.route(batch)
+                routed, unrouted = routes.counts()
             for to, n in routed.items():
                 self.statsd.count("flush.route.routed_rows", n,
                                   tags=[f"sink:{to}"])
             self.statsd.count("flush.route.unrouted_rows", unrouted)
+            self.statsd.count("flush.route.evaluated_rows", routes.evaluated)
+            self.statsd.count("flush.route.cached_rows", routes.cached)
             round_info["routing"] = {
-                "rules": len(self._routing), "materialized": len(metrics),
-                "routed": routed, "unrouted": unrouted}
+                "rules": len(self._routing.rules), "routed": routed,
+                "unrouted": unrouted, "evaluated": routes.evaluated,
+                "cached": routes.cached}
 
         for sink in self.metric_sinks:
             key = f"metric:{sink.name()}"
@@ -1787,7 +1779,7 @@ class Server:
                                      sink=key).start(handoff=True)
                 _start_sink_thread(
                     key, self._flush_sink_safe, key, sink, batch,
-                    samples, starting)
+                    samples, starting, routes)
 
         # bounded wait: one interval from flush start, minus time already
         # spent; stragglers keep running on their daemon threads and are
@@ -1826,6 +1818,12 @@ class Server:
                 self.telemetry.record_event(
                     "sink_timeout", sink=key, flush=round_info["flush"])
 
+        if routes is not None:
+            # objects exist only where a sink could not take its share
+            # by columns (a filter, a spill, a sink without flush_batch)
+            built = routes.materialized_rows()
+            self.statsd.count("flush.route.materialized_rows", built)
+            round_info["routing"]["materialized"] = built
         if self.import_server is not None:
             # per-RPC latency/error aggregates (reference proxy/grpcstats)
             self.import_server.rpc_stats.emit(self.statsd, prefix="import.rpc")
@@ -2162,12 +2160,15 @@ class Server:
             return False
 
     def _flush_sink_safe(self, key: str, sink, batch: FlushBatch,
-                         other_samples=(), starting=None) -> Optional[bool]:
+                         other_samples=(), starting=None,
+                         routes: Optional[BatchRoutes] = None
+                         ) -> Optional[bool]:
         """Returns True/False for a delivery attempt, None when the sink
         was never exercised (nothing to flush) — None must not feed the
         sink's breaker. `starting` is the round's `egress_start` phase,
         begun where the flush loop dispatched this thread; it ends where
-        the sink's own flush is called."""
+        the sink's own flush is called. `routes` are the flush's routes
+        where routing is on: the sink gets its share of the batch."""
         ok = True
         if other_samples:
             try:
@@ -2186,37 +2187,45 @@ class Server:
             return ok if other_samples else None
         name = sink.name()
         sc = self._sink_filters.get(name)
+        # columnar fast path: no per-sink filtering and no spill to
+        # prepend, so the sink sees its share as a FlushBatch (the
+        # default flush_batch materializes; blackhole and friends never
+        # do); else the share's objects, filtered
+        columnar = sc is None and not spill
+
+        def objects(parent: str) -> List[InterMetric]:
+            share = batch if routes is None else routes.share(name)
+            with batch.timing.phase("materialize", parent=parent, sink=key):
+                built = share.materialize()
+            return built if sc is None else _apply_sink_filters(built, sc)
+
         current: Optional[List[InterMetric]] = None
         try:
             if self.chaos is not None:
                 self.chaos.inject("sink_flush")
-            if sc is None and self._routing is None and not spill:
-                # columnar fast path: no per-sink filtering, no routing
-                # annotations, no spill to prepend, so the sink sees the
-                # batch directly (the default flush_batch materializes;
-                # blackhole and friends never do). getattr: duck-typed
-                # sinks that only implement flush() still work.
-                fb = getattr(sink, "flush_batch", None)
-                if starting is not None:
-                    starting.stop()
-                if fb is not None:
-                    fb(batch)
-                else:
-                    sink.flush(batch.materialize())
-                self.ledger.note("egress.acked", len(batch), key=name)
-                return ok
-            with batch.timing.phase("egress_select", parent="sink",
-                                    sink=key):
-                selected = [mm for mm in batch.materialize()
-                            if mm.sinks is None or name in mm.sinks]
-                if sc is not None:
-                    selected = _apply_sink_filters(selected, sc)
-            current = selected
+            share = batch
+            if routes is not None or not columnar:
+                with batch.timing.phase("egress_select", parent="sink",
+                                        sink=key):
+                    if columnar:
+                        share = routes.share(name)
+                    else:
+                        current = objects("egress_select")
             if starting is not None:
                 starting.stop()
-            sink.flush(spill + selected if spill else selected)
-            self.ledger.note("egress.acked",
-                             len(selected) + len(spill or ()), key=name)
+            if not columnar:
+                sink.flush(spill + current if spill else current)
+                self.ledger.note("egress.acked",
+                                 len(current) + len(spill or ()), key=name)
+                return ok
+            # getattr: duck-typed sinks that only implement flush()
+            # still work
+            fb = getattr(sink, "flush_batch", None)
+            if fb is not None:
+                fb(share)
+            else:
+                sink.flush(share.materialize())
+            self.ledger.note("egress.acked", len(share), key=name)
             return ok
         except Exception:
             logger.exception("sink %s flush failed", sink.name())
@@ -2231,15 +2240,12 @@ class Server:
                     "sink %s: shedding %d spilled metrics after a failed "
                     "retry (one-interval spill bound)", key, len(spill))
             if current is None:
-                # failed before per-sink selection (chaos seam, filter
-                # error): spill only this sink's routed+filtered share,
-                # or the next interval would deliver it metrics that
-                # routing excluded — and double-deliver them elsewhere
+                # spill only this sink's routed+filtered share, or the
+                # next interval would deliver it metrics that routing
+                # excluded — and double-deliver them elsewhere. Only
+                # here does a columnar flush build objects
                 try:
-                    current = [mm for mm in batch.materialize()
-                               if mm.sinks is None or name in mm.sinks]
-                    if sc is not None:
-                        current = _apply_sink_filters(current, sc)
+                    current = objects("sink")
                 except Exception:
                     logger.exception(
                         "sink %s: selection failed while spilling; "
@@ -2291,6 +2297,6 @@ def _apply_sink_filters(metrics: List[InterMetric], sc: SinkConfig
                 name=metric.name, timestamp=metric.timestamp,
                 value=metric.value, tags=tags, type=metric.type,
                 message=metric.message, hostname=metric.hostname,
-                sinks=metric.sinks, backfilled=metric.backfilled)
+                backfilled=metric.backfilled)
         out.append(metric)
     return out

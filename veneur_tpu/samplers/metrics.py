@@ -150,10 +150,6 @@ def update_tags(
     return final, joined, h32, h64
 
 
-# Route information: None means "every sink"; otherwise a set of sink names.
-RouteInformation = Optional[set]
-
-
 @dataclass(slots=True)
 class InterMetric:
     """A completed metric ready for flushing by sinks
@@ -168,7 +164,6 @@ class InterMetric:
     type: MetricType
     message: str = ""
     hostname: str = ""
-    sinks: RouteInformation = None
     # True for series replayed from the durable WAL into a historical
     # interval (forward/backfill.py): `timestamp` is the ORIGINAL
     # interval start, and timestamp-aware sinks (Cortex remote-write,

@@ -73,8 +73,11 @@ class Matcher:
             [TagMatcher.from_config(t) for t in cfg.get("tags", []) or []])
 
     def match(self, name: str, tags: Sequence[str]) -> bool:
-        if not self.name.match(name):
-            return False
+        return self.name.match(name) and self.match_tags(tags)
+
+    def match_tags(self, tags: Sequence[str]) -> bool:
+        """The tag half of `match`: a function of the tags alone, so a
+        caller with many names over one tag list may ask once."""
         for tm in self.tags:
             found = any(tm.match(tag) for tag in tags)
             if found and tm.unset:
