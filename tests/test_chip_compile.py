@@ -3,7 +3,8 @@
 No chip is attached here: the TPU compiler is installed and compiles for
 a chip that is described, not attached (guide on-chip-measurement §2,
 step 3). Each case lowers one jitted program of the ingest / flush /
-import path at the widths a deployment runs (C = 128 and 2C = 256
+import path (the first two taken from the server's own warm-up list,
+`warm_programs`) at the widths a deployment runs (C = 128 and 2C = 256
 centroid columns, 16,384 HLL registers, BINS_PAD llhist bins) and 8,192
 rows — enough rows to tile, few enough that a case takes seconds — and
 asks the v5e compiler for an executable. What it refuses here it would
@@ -20,6 +21,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from veneur_tpu.core import columnstore
 from veneur_tpu.ops import batch_hll, batch_llhist, batch_tdigest, scalars
 
 K = 8192          # table rows per case
@@ -93,56 +95,37 @@ def _table(init):
 
 
 f32, i32, i8 = jnp.float32, jnp.int32, jnp.int8
-COO = (_vec(B, i32), _vec(B, f32), _vec(B, f32))    # rows, values, weights
-BINNED = (_vec(B, i32), _vec(B, i32), _vec(B, i32))  # rows, index, count
 
-# (id, jitted program, its table's init, the other arguments, statics);
-# batch_llhist.merge takes a second table, marked by its init
-CASES = [
-    ("scalars.apply_counters", scalars.apply_counters,
-     scalars.init_counters, COO, ()),
-    ("scalars.apply_gauges", scalars.apply_gauges,
-     scalars.init_gauges, COO[:2], ()),
-    ("scalars.merge_gauges", scalars.merge_gauges,
-     scalars.init_gauges, (_vec(R, i32), _vec(R, f32)), ()),
-    ("batch_tdigest._apply_batch_jit", batch_tdigest._apply_batch_jit,
-     batch_tdigest.init_state, COO + (_vec(B, i32),), ()),
-    ("batch_tdigest.compact", batch_tdigest.compact,
-     batch_tdigest.init_state, (), ()),
-    ("batch_tdigest.flush_quantiles_packed",
-     batch_tdigest.flush_quantiles_packed,
-     batch_tdigest.init_state, (), (PS, True)),
-    ("batch_tdigest.flush_export_packed", batch_tdigest.flush_export_packed,
-     batch_tdigest.init_state, (), (PS,)),
-    ("batch_tdigest.merge_centroid_rows", batch_tdigest.merge_centroid_rows,
-     batch_tdigest.init_state,
-     (_vec(R, i32), _mat(R, C, f32), _mat(R, C, f32),
-      _vec(R, f32), _vec(R, f32), _vec(R, f32)), ()),
-    ("batch_hll.apply_batch", batch_hll.apply_batch,
-     batch_hll.init_state, BINNED, ()),
-    ("batch_hll.estimate", batch_hll.estimate, batch_hll.init_state, (), ()),
-    ("batch_hll.merge_rows", batch_hll.merge_rows,
-     batch_hll.init_state, (_vec(R, i32), _mat(R, batch_hll.M, i8)), ()),
-    ("batch_llhist.apply_batch", batch_llhist.apply_batch,
-     batch_llhist.init_state, BINNED, ()),
-    ("batch_llhist.merge", batch_llhist.merge,
-     batch_llhist.init_state, (batch_llhist.init_state,), ()),
-    ("batch_llhist.flush_packed", batch_llhist.flush_packed,
-     batch_llhist.init_state, (), (PS,)),
-]
+# -- the warm-up's list -----------------------------------------------------
+#
+# What `Server._warmup` compiles at start-up for a one-device store
+# (`warm_programs`, core/columnstore.py): the cases ARE that list, so a
+# program that joins it is compiled for the chip here too, and nothing
+# the server warms goes uncompiled. Both readouts of the digest family:
+# a global's (`need_export` false) and a forwarding local's.
 
+# a small table of each family, only for its list and its shapes: K
+# rows of state from `_fresh_state_at`, a B-wide padding batch from
+# `_prewarm_cols`
+ONE_DEVICE = {family: cls(capacity=64, batch_cap=B)
+              for family, cls in (("counter", columnstore.CounterTable),
+                                  ("gauge", columnstore.GaugeTable),
+                                  ("histogram", columnstore.HistoTable),
+                                  ("llhist", columnstore.LLHistTable),
+                                  ("set", columnstore.SetTable))}
+WARM_CASES = [
+    pytest.param(family, False, wp.program, id=f"{family}.{wp.program}")
+    for family, table in ONE_DEVICE.items()
+    for wp in table.warm_programs(PS, False)
+] + [pytest.param("histogram", True, "readout",
+                  id="histogram.readout.export")]
 
 _SEGMENT_REDUCERS = (batch_tdigest.compact,
                      batch_tdigest.flush_export_packed,
                      batch_tdigest.merge_centroid_rows)
 
 
-@pytest.mark.parametrize("program,init,others,static",
-                         [pytest.param(*c[1:], id=c[0]) for c in CASES])
-def test_main_path_program_compiles_for_v5e(
-        one_chip, tpu_segment_reduce, program, init, others, static):
-    others = tuple(_table(o) if callable(o) else o for o in others)
-    args = _shapes((_table(init),) + others, one_chip)
+def _compile_for_v5e(program, args, static):
     lowered = program.lower(*args, *static)
     if program in _SEGMENT_REDUCERS:
         # the TPU side of the trace-time branch is a one-hot matmul
@@ -151,6 +134,45 @@ def test_main_path_program_compiles_for_v5e(
     # an executable for the described TPU, not for the CPU this runs on
     assert "tpu" in compiled.as_text().lower()
     assert compiled.memory_analysis().temp_size_in_bytes >= 0
+
+
+@pytest.mark.parametrize("family,need_export,program", WARM_CASES)
+def test_warm_up_program_compiles_for_v5e(
+        one_chip, tpu_segment_reduce, family, need_export, program):
+    table = ONE_DEVICE[family]
+    state = jax.eval_shape(lambda: table._fresh_state_at(K))
+    [wp] = [wp for wp in table.warm_programs(PS, need_export)
+            if wp.program == program]
+    args = _shapes(wp.args(state, table._prewarm_cols()), one_chip)
+    _compile_for_v5e(wp.fn, args, wp.static)
+
+
+# -- the import path, which no capacity implies -----------------------------
+#
+# (id, jitted program, its table's init, the other arguments);
+# batch_llhist.merge takes a second table, marked by its init
+IMPORT_CASES = [
+    ("scalars.merge_gauges", scalars.merge_gauges,
+     scalars.init_gauges, (_vec(R, i32), _vec(R, f32))),
+    ("batch_tdigest.merge_centroid_rows", batch_tdigest.merge_centroid_rows,
+     batch_tdigest.init_state,
+     (_vec(R, i32), _mat(R, C, f32), _mat(R, C, f32),
+      _vec(R, f32), _vec(R, f32), _vec(R, f32))),
+    ("batch_hll.merge_rows", batch_hll.merge_rows,
+     batch_hll.init_state, (_vec(R, i32), _mat(R, batch_hll.M, i8))),
+    ("batch_llhist.merge", batch_llhist.merge,
+     batch_llhist.init_state, (batch_llhist.init_state,)),
+]
+
+
+@pytest.mark.parametrize("program,init,others",
+                         [pytest.param(*c[1:], id=c[0])
+                          for c in IMPORT_CASES])
+def test_import_path_program_compiles_for_v5e(
+        one_chip, tpu_segment_reduce, program, init, others):
+    others = tuple(_table(o) if callable(o) else o for o in others)
+    _compile_for_v5e(program, _shapes((_table(init),) + others, one_chip),
+                     ())
 
 
 # -- the four-shard deployment's two heaviest collectives -------------------

@@ -32,7 +32,7 @@ import threading
 import time
 from dataclasses import dataclass, field
 from functools import lru_cache, partial
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -43,6 +43,7 @@ from veneur_tpu.ops import (batch_hll, batch_llhist, batch_tdigest,
                             device_scope, hll_ref, llhist_ref, scalars)
 from veneur_tpu.samplers import metrics as m
 from veneur_tpu.samplers.metrics import MetricScope, UDPMetric
+from veneur_tpu.util import compilecache
 
 logger = logging.getLogger("veneur_tpu.core.columnstore")
 
@@ -136,6 +137,48 @@ class RowMeta:
     # fields 1-3 and field 9) used by the native forward encoder —
     # identity-only, so it too lives for the row's lifetime
     pb_frame: tuple = None
+
+
+class WarmProgram(NamedTuple):
+    """One device program of a table's warm-up list (`warm_programs`):
+    `fn(*args(carry, cols), *static)` is the call the live path makes,
+    on throwaway state and an all-padding batch `cols`. On a one-device
+    table `fn` is the jitted program itself, so
+    tests/test_chip_compile.py lowers the same entries for a described
+    v5e. `carry` is what the list threads from entry to entry: the
+    throwaway generation to begin with, then `then(carry, result)` of
+    each entry (`None` = the program donates nothing and the next entry
+    gets the same carry; `_result` = it donates the state and returns
+    the next one; a sharded list keeps the merged state beside the
+    per-device ones for its readout)."""
+
+    program: str
+    fn: Callable
+    args: Callable
+    static: tuple = ()
+    then: Optional[Callable] = None
+
+
+def _state_only(state, cols):
+    return (state,)
+
+
+def _state_and_cols(state, cols):
+    return (state, *cols)
+
+
+def _result(carry, result):
+    return result
+
+
+class WarmupFailed(Exception):
+    """A program of the warm-up list raised: which one, for the
+    `warmup_failed` event."""
+
+    def __init__(self, family: str, program: str):
+        super().__init__(f"warm-up of {family}.{program} failed")
+        self.family = family
+        self.program = program
 
 
 class _BaseTable:
@@ -635,39 +678,77 @@ class _BaseTable:
     # -- shape-ladder prewarm --------------------------------------------
 
     def prewarm_rung(self, capacity: int, percentiles=(),
-                     need_export: bool = True) -> bool:
-        """Compile this family's batch-apply, readout, and zeroing
-        kernels for a FUTURE capacity rung against a throwaway state
-        (background thread; never touches live state or locks). The jit
-        caches — and the persistent compilation cache — are
-        process-global, so the first post-resize dispatch at this
-        capacity finds them warm instead of retracing on the hot path.
-        Returns True when the rung was compiled."""
-        cols = self._prewarm_cols()
-        if cols is None:
+                     need_export: bool = True, report=None) -> bool:
+        """Compile every program of this family's warm-up list
+        (`warm_programs`) for `capacity` rows against a throwaway state:
+        the server's start-up warm-up at the configured capacity
+        (`Server._warmup`) and the shape ladder's next rung
+        (core/flushexec.py) are this one call. Runs on a background
+        thread and never touches live state or locks. The jit caches —
+        and the persistent compilation cache — are process-global, so
+        the first live dispatch at this capacity finds them warm instead
+        of compiling under `apply_lock` or the flush lock. `report`,
+        when given, gets `(family, program, seconds, cache_hits,
+        cache_misses)` after each program: what the persistent cache
+        served and what it had to compile (both 0 = already in this
+        process's jit cache, or the cache is off). Returns True when the
+        rung was compiled; a program that raises ends the rung with
+        `WarmupFailed`."""
+        programs = self.warm_programs(tuple(percentiles), need_export)
+        if not programs:
             return False
+        cols = self._prewarm_cols()
         obs = self._deviceobs
-        t0 = time.perf_counter()
-        state = self._fresh_state_at(capacity)
+        t_rung = time.perf_counter()
+        state = self._warm_state(capacity)
         # the throwaway rung state is real HBM while the compile runs;
         # ledger it as a transient `prewarm` generation
         tok = obs.note_generation(self.family, "prewarm", state) \
             if obs is not None else None
         try:
-            state = self._prewarm_apply(state, cols, capacity)
-            out = self._prewarm_readout(state, capacity,
-                                        tuple(percentiles), need_export)
-            jax.block_until_ready([leaf for leaf in jax.tree.leaves(out)
-                                   if leaf is not None])
+            for wp in programs:
+                t0 = time.perf_counter()
+                hits, misses = compilecache.cache_events()
+                try:
+                    with annotate(f"warmup.{self.family}.{wp.program}"):
+                        out = wp.fn(*wp.args(state, cols), *wp.static)
+                        jax.block_until_ready(
+                            [leaf for leaf in jax.tree.leaves(out)
+                             if leaf is not None])
+                except Exception as e:
+                    raise WarmupFailed(self.family, wp.program) from e
+                if wp.then is not None:
+                    state = wp.then(state, out)
+                seconds = time.perf_counter() - t0
+                if obs is not None:
+                    obs.note_compile(self.family, seconds)
+                if report is not None:
+                    now = compilecache.cache_events()
+                    report(self.family, wp.program, seconds,
+                           now[0] - hits, now[1] - misses)
         finally:
             if obs is not None:
                 obs.drop(tok)
         self._prewarmed_caps.add(capacity)
         if obs is not None:
-            elapsed = time.perf_counter() - t0
-            obs.note_kernel("prewarm", self.family, elapsed)
-            obs.note_compile(self.family, elapsed)
+            obs.note_kernel("prewarm", self.family,
+                            time.perf_counter() - t_rung)
         return True
+
+    def warm_programs(self, ps: tuple, need_export: bool
+                      ) -> List[WarmProgram]:
+        """The device programs this family's configured capacity
+        implies, in the order the live path meets them: the batch
+        apply, the readout with the live flush's percentiles and
+        `need_export`, the zeroing of the spare generation (the digest
+        family adds `compact`). The ONE list: the warm-up runs it, and
+        tests/test_chip_compile.py compiles the one-device entries for
+        a described v5e. Empty = a host-only family."""
+        return []
+
+    def _warm_state(self, capacity: int):
+        """The throwaway generation a rung's programs run on."""
+        return self._fresh_state_at(capacity)
 
     def _prewarm_cols(self):
         """An all-padding pending batch with the live buffer dtypes
@@ -677,16 +758,6 @@ class _BaseTable:
             return None
         return (np.full(self.batch_cap, PAD_ROW, np.int32),) + tuple(
             np.zeros(self.batch_cap, c.dtype) for c in pcols[1:])
-
-    def _prewarm_apply(self, state, cols, capacity: int):
-        return self._apply_cols_state(state, cols)
-
-    def _prewarm_readout(self, state, capacity: int, ps: tuple,
-                         need_export: bool):
-        """Dispatch the family's flush-readout + zeroing kernels for the
-        rung; returns device handles to block on. Base: the zeroing
-        kernel only (scalar families read out by pure transfer)."""
-        return _zeros_like_spare(state)
 
     def _apply_cols_state(self, state, cols):
         """Pure batch apply: fold one swapped pending-column batch into
@@ -1012,6 +1083,13 @@ class CounterTable(_BaseTable):
     def _fresh_state_at(self, capacity: int):
         return scalars.init_counters(capacity)
 
+    def warm_programs(self, ps, need_export):
+        # the readout is a pure transfer of the Kahan pair: no program
+        return [WarmProgram("apply", scalars.apply_counters,
+                            _state_and_cols, then=_result),
+                WarmProgram("reset", _zeros_like_donated, _state_only,
+                            then=_result)]
+
     def apply_pending(self):
         with self.lock:
             self._dispatch_pending_locked()
@@ -1120,6 +1198,12 @@ class GaugeTable(_BaseTable):
     def _fresh_state_at(self, capacity: int):
         return scalars.init_gauges(capacity)
 
+    def warm_programs(self, ps, need_export):
+        return [WarmProgram("apply", scalars.apply_gauges,
+                            _state_and_cols, then=_result),
+                WarmProgram("reset", _zeros_like_donated, _state_only,
+                            then=_result)]
+
     def apply_pending(self):
         with self.lock:
             self._dispatch_pending_locked()
@@ -1192,6 +1276,12 @@ class HistoTable(_BaseTable):
         self._applies = 0
         # exact per-key staging-slot occupancy since the last compact
         self._staged_counts = np.zeros(self.capacity, np.int32)
+        # whole-table compacts a key past its C staging slots forced,
+        # and their host wall, by the path that ran them: `live` under
+        # apply_lock on the dispatcher's thread, `readout` on the flush
+        # thread over the captured generation's last pending batch
+        self.compacts_total = {"live": 0, "readout": 0}
+        self.compact_seconds_total = {"live": 0.0, "readout": 0.0}
 
     def _init_arrays(self):
         self._init_pending()
@@ -1229,7 +1319,17 @@ class HistoTable(_BaseTable):
                                             self._staged_counts)
         self._applies += 1
 
-    def _apply_cols_state(self, state, cols, staged_counts):
+    def _compact(self, state, path: str):
+        """One whole-table (or whole-shard) compact, counted and timed:
+        the wall is the dispatch's; the kernel runs behind it."""
+        t0 = time.perf_counter()
+        state = batch_tdigest.compact(state)
+        self.compacts_total[path] += 1
+        self.compact_seconds_total[path] += time.perf_counter() - t0
+        return state
+
+    def _apply_cols_state(self, state, cols, staged_counts,
+                          path: str = "live"):
         """Pure batch apply over an explicit (state, staging-occupancy)
         pair: the live path passes the table's own, the flush readout
         passes the captured generation's."""
@@ -1237,7 +1337,7 @@ class HistoTable(_BaseTable):
         slots, overflow = batch_tdigest.host_slots(
             rows, vals, wts, staged_counts)
         if overflow:
-            state = batch_tdigest.compact(state)
+            state = self._compact(state, path)
             staged_counts[:] = 0
             slots, _ = batch_tdigest.host_slots(
                 rows, vals, wts, staged_counts)
@@ -1246,9 +1346,25 @@ class HistoTable(_BaseTable):
     def _fresh_state_at(self, capacity: int):
         return batch_tdigest.init_state(capacity)
 
-    def _prewarm_apply(self, state, cols, capacity: int):
-        return self._apply_cols_state(state, cols,
-                                      np.zeros(capacity, np.int32))
+    def warm_programs(self, ps, need_export):
+        """`compact` is on the list by name: no padding batch overflows
+        a staging slot, so no faked apply would ever reach it, and cold
+        at 131,072 rows it compiles for longer than the flush watchdog
+        allows while a tick waits for `apply_lock`."""
+        readout = (batch_tdigest.flush_export_packed if need_export
+                   else batch_tdigest.flush_quantiles_packed)
+        return [
+            # rows, values, weights and the host-computed staging slots
+            WarmProgram("apply", batch_tdigest._apply_batch_jit,
+                        lambda state, cols: (
+                            state, *cols,
+                            np.zeros(cols[0].shape[0], np.int32)),
+                        then=_result),
+            WarmProgram("compact", batch_tdigest.compact, _state_only,
+                        then=_result),
+            WarmProgram("readout", readout, _state_only, static=(ps,)),
+            WarmProgram("reset", _reset_tdigest_donated, _state_only,
+                        then=_result)]
 
     def apply_pending(self):
         with self.lock:
@@ -1307,7 +1423,8 @@ class HistoTable(_BaseTable):
         self._applies = 0
 
     def _readout_apply(self, state, cols, snap: dict):
-        return self._apply_cols_state(state, cols, snap.pop("staged"))
+        return self._apply_cols_state(state, cols, snap.pop("staged"),
+                                      path="readout")
 
     def _readout_device(self, state, snap: dict) -> None:
         ps = snap["ps"]
@@ -1326,14 +1443,6 @@ class HistoTable(_BaseTable):
 
     def _reset_state_donated(self, captured):
         return _reset_tdigest_spare(captured)
-
-    def _prewarm_readout(self, state, capacity: int, ps: tuple,
-                         need_export: bool):
-        if need_export:
-            out = batch_tdigest.flush_export_packed(state, ps)
-        else:
-            out = batch_tdigest.flush_quantiles_packed(state, ps)
-        return (out, self._reset_state_donated(state))
 
     def snapshot_begin(self, percentiles: Tuple[float, ...],
                        need_export: bool = True) -> dict:
@@ -1562,13 +1671,31 @@ class SetTable(_BaseTable):
     def _fresh_state_at(self, capacity: int):
         return batch_hll.init_state(capacity)
 
+    def warm_programs(self, ps, need_export):
+        # a sparse table's captured bank escapes into the snapshot's
+        # register provider and is never zeroed; the sharded dense table
+        # has its own list
+        return [WarmProgram("apply", batch_hll.apply_batch,
+                            _state_and_cols, then=_result),
+                WarmProgram("readout", batch_hll.estimate, _state_only)]
+
+    def _warm_state(self, capacity: int):
+        # a sparse table's device bank rides its own 8x slot ladder
+        # (`_dev_cap`), deliberately decoupled from row capacity — see
+        # _promote_locked
+        return (self._fresh_state() if self._sparse
+                else self._fresh_state_at(capacity))
+
     def prewarm_rung(self, capacity: int, percentiles=(),
-                     need_export: bool = True) -> bool:
-        """No-op: the set table's device bank rides its own 8x slot
-        ladder (`_dev_cap`), deliberately decoupled from row-capacity
-        doublings — see _promote_locked — so a capacity resize never
-        retraces its kernels (prewarm_dense climbs the slot ladder)."""
-        return False
+                     need_export: bool = True, report=None) -> bool:
+        """The bank's current rung of the slot ladder, for the table's
+        own capacity; a no-op for any other: a capacity resize never
+        retraces the set kernels, and the ladder's upper rungs are
+        climbed by promotions (or `prewarm_dense`)."""
+        if self._sparse and capacity != self.capacity:
+            return False
+        return super().prewarm_rung(capacity, percentiles, need_export,
+                                    report)
 
     def apply_pending(self):
         with self.lock:
@@ -1983,10 +2110,15 @@ class LLHistTable(_BaseTable):
         snap["bins_dev"] = bins_dev
         snap["_recycle"] = state
 
-    def _prewarm_readout(self, state, capacity: int, ps: tuple,
-                         need_export: bool):
-        return (batch_llhist.flush_packed(state, ps),
-                _zeros_like_spare(state))
+    def warm_programs(self, ps, need_export):
+        # the gather of the touched rows' bins is shaped by how many
+        # rows an interval touched: not on a list that capacities fix
+        return [WarmProgram("apply", batch_llhist.apply_batch,
+                            _state_and_cols, then=_result),
+                WarmProgram("readout", batch_llhist.flush_packed,
+                            _state_only, static=(ps,)),
+                WarmProgram("reset", _zeros_like_donated, _state_only,
+                            then=_result)]
 
     @staticmethod
     def snapshot_finish(snap: dict):
@@ -2217,6 +2349,13 @@ class ColumnStore:
                          t.apply_seconds_total, tags))
             rows.append(("ingest.apply.lock_wait_seconds_total", "counter",
                          t.apply_lock_wait_seconds_total, tags))
+            for path, n in getattr(t, "compacts_total", {}).items():
+                # the digest table only: compacts its keys forced
+                rows.append(("ingest.tdigest.compacts_total", "counter",
+                             float(n), [f"path:{path}"]))
+                rows.append(("ingest.tdigest.compact_seconds_total",
+                             "counter", t.compact_seconds_total[path],
+                             [f"path:{path}"]))
             pending = getattr(t, "_n", None)
             if pending is not None:  # statuses have no batch buffers
                 rows.append(("columnstore.batch_cap", "gauge",

@@ -20,13 +20,12 @@ from typing import Callable, Dict, List, Optional
 from veneur_tpu import sinks as sinks_mod
 from veneur_tpu.config import Config, SinkConfig
 from veneur_tpu.core import networking
-from veneur_tpu.core.columnstore import ColumnStore
+from veneur_tpu.core.columnstore import ColumnStore, WarmupFailed
 from veneur_tpu.core.latency import family_tree
 from veneur_tpu.core.telemetry import FlushRound, current_round
 from veneur_tpu.core.routing import BatchRoutes, ColumnRouter
 from veneur_tpu.core.flusher import (
-    FlushBatch, ForwardableState, flush_columnstore_batch,
-    readout_columnstore, swap_columnstore)
+    FlushBatch, ForwardableState, readout_columnstore, swap_columnstore)
 from veneur_tpu.samplers import metrics as m
 from veneur_tpu.samplers.metrics import (
     HistogramAggregates, InterMetric, MetricScope, UDPMetric,
@@ -276,6 +275,8 @@ class Server:
         self.telemetry.registry.add_collector(self._live_telemetry_rows)
         self.telemetry.registry.add_collector(self._ring_telemetry_rows)
         self.telemetry.registry.add_collector(self._ingest_time_rows)
+        self._warmup_seconds: Dict[str, float] = {}
+        self.telemetry.registry.add_collector(self._warmup_rows)
         self.telemetry.registry.add_collector(
             telemetry_mod.device_memory_rows)
 
@@ -1502,50 +1503,67 @@ class Server:
                 os._exit(2)
 
     def _warmup(self) -> None:
-        """Compile the flush kernels against a throwaway store with the same
-        array shapes; never touches (or resets) live state."""
+        """Compile, off the ingest and flush threads and before the flush
+        watchdog's clock starts, every device program the configured
+        capacities imply: each family's own list (`warm_programs`: batch
+        apply, the digest family's `compact`, the readout with the live
+        flush's percentiles and `need_export`, the zeroing of the spare;
+        a sharded table's per-device applies and collective merges), run
+        by `prewarm_rung` against throwaway state, never the live one.
+        Cold, `compact` or a t-digest merge alone compiles for longer
+        than the watchdog allows a flush, and the first hot key (or the
+        first flush with data) would otherwise do it under `apply_lock`
+        or the flush lock. One `warmup` event lists every program with
+        its seconds and what the persistent cache served or missed; a
+        program that raises is a `warmup_failed` event, logged once, and
+        the remaining families are still warmed."""
+        t0 = time.perf_counter()
+        programs = []
+
+        def report(family, program, seconds, cache_hits, cache_misses):
+            programs.append({"family": family, "program": program,
+                             "seconds": round(seconds, 6),
+                             "cache_hits": cache_hits,
+                             "cache_misses": cache_misses})
+            self._warmup_seconds[family] = (
+                self._warmup_seconds.get(family, 0.0) + seconds)
+
+        # percentiles and need_export must match the live flush's
+        # (`_swap_columnstore`): they are static arguments of the
+        # readout programs, and warming another specialization would
+        # leave the first real flush paying the full compile
+        full_ps = tuple(self.percentiles)
+        all_ps = tuple(sorted(set(full_ps) | {0.5}))
+        need_export = self.is_local and self.forwarder is not None
         try:
-            cfg = self.config
-            scratch = ColumnStore(
-                counter_capacity=cfg.tpu.counter_capacity,
-                gauge_capacity=cfg.tpu.gauge_capacity,
-                histo_capacity=cfg.tpu.histo_capacity,
-                set_capacity=cfg.tpu.set_capacity,
-                batch_cap=cfg.tpu.batch_cap,
-                shard_devices=cfg.tpu.shards,
-                llhist_capacity=cfg.tpu.llhist_capacity,
-                histogram_encoding=cfg.histogram_encoding,
-                shard_routing=cfg.tpu.shard_routing)
-            # collect_forward must match the live flush's value: need_export
-            # selects between two distinct JIT specializations (fold_staging
-            # is a static arg), and warming the wrong one would leave the
-            # first real flush paying the full compile
-            flush_columnstore_batch(
-                scratch, self.is_local, self.percentiles, self.aggregates,
-                collect_forward=self.forwarder is not None)
-            if scratch.shard_plane is not None:
-                # a sharded store's empty flush is idle and reaches no
-                # collective merge. Compile each family's masked apply,
-                # merge + readout and zeroing kernels here, where the
-                # flush watchdog does not count: cold, the t-digest
-                # merge alone compiles for longer than a watchdog allows
-                # a flush, and the first flush with data would otherwise
-                # do it under the flush lock
-                from veneur_tpu.core.flushexec import PREWARM_FAMILIES
-                full_ps = tuple(self.percentiles)
-                all_ps = tuple(sorted(set(full_ps) | {0.5}))
-                need_export = self.is_local and self.forwarder is not None
-                for family, table in scratch.tables():
-                    if family in PREWARM_FAMILIES:
-                        table.prewarm_rung(
-                            table.capacity,
-                            all_ps if family == "histogram" else full_ps,
-                            need_export=need_export)
-        except Exception:
-            logger.exception("kernel warmup failed")
+            for family, table in self.store.tables():
+                try:
+                    table.prewarm_rung(
+                        table.capacity,
+                        all_ps if family == "histogram" else full_ps,
+                        need_export=need_export, report=report)
+                except Exception as e:
+                    program = (e.program if isinstance(e, WarmupFailed)
+                               else "list")
+                    cause = e.__cause__ or e
+                    logger.error("kernel warm-up failed at %s.%s",
+                                 family, program, exc_info=cause)
+                    self.telemetry.record_event(
+                        "warmup_failed", family=family, program=program,
+                        error=f"{type(cause).__name__}: {cause}")
         finally:
+            self.telemetry.record_event(
+                "warmup", seconds=round(time.perf_counter() - t0, 6),
+                programs=programs)
             # the flush watchdog and the readiness check count from here
             self.last_flush_unix = time.time()
+
+    def _warmup_rows(self) -> list:
+        """`warmup.seconds_total{family}`: what start-up spent compiling
+        (or loading from the persistent cache) each family's programs."""
+        return [("warmup.seconds_total", "counter", seconds,
+                 [f"family:{family}"])
+                for family, seconds in sorted(self._warmup_seconds.items())]
 
     def flush(self) -> None:
         """One flush pass (reference flusher.go:26-122)."""

@@ -51,8 +51,9 @@ import numpy as np
 
 from veneur_tpu.core.columnstore import (CounterTable, GaugeTable,
                                          HistoTable, LLHistTable, PAD_ROW,
-                                         SetTable, _BaseTable,
-                                         _SetRegisters, _zeros_like_spare)
+                                         SetTable, WarmProgram, _SetRegisters,
+                                         _result, _state_only,
+                                         _zeros_like_spare)
 from veneur_tpu.core.telemetry import FlushRound
 from veneur_tpu.ops import batch_hll, batch_llhist, batch_tdigest, scalars
 from veneur_tpu.parallel import collectives
@@ -129,10 +130,15 @@ class _DigestRouted:
     def _put_sharded(self, host_arr: np.ndarray):
         return jax.device_put(host_arr, self._shard_sharding)
 
-    def _prewarm_apply(self, state, cols, capacity: int):
-        # rung compiles must not inflate the serving plane's routed/
-        # dispatch accounting — the batch is all-PAD throwaway
-        return self._apply_cols_state(state, cols, note=False)
+    def _warm_apply(self) -> WarmProgram:
+        """The stacked families' masked apply, as a warm-up entry. Rung
+        compiles must not inflate the serving plane's routed/dispatch
+        accounting — the batch is all-PAD throwaway."""
+        return WarmProgram(
+            "apply",
+            lambda state, cols: self._apply_cols_state(
+                state, cols, note=False),
+            lambda state, cols: (state, cols), then=_result)
 
     def _stacked_batch(self, rows: np.ndarray, value_cols: Tuple,
                        note: bool = True) -> Tuple:
@@ -366,9 +372,13 @@ class ShardedCounterTable(_DigestRouted, CounterTable):
         snap["dev"] = collectives.merge_counters_stacked(state)
         self._plane.note_merge_round()
 
-    def _prewarm_readout(self, state, capacity, ps, need_export):
-        return collectives.merge_counters_stacked_reset(
-            state, self._shard_sharding)
+    def warm_programs(self, ps, need_export):
+        # the fused merge zeroes the generation it drains
+        return [self._warm_apply(),
+                WarmProgram("merge",
+                            collectives.merge_counters_stacked_reset,
+                            lambda state, cols: (state,
+                                                 self._shard_sharding))]
 
     def _reshard_capture_device(self, state, snap: dict) -> None:
         # psum selection, non-donating: (sum, comp) per row, the exact
@@ -446,9 +456,11 @@ class ShardedGaugeTable(_DigestRouted, GaugeTable):
         snap["dev"] = dev
         self._plane.note_merge_round()
 
-    def _prewarm_readout(self, state, capacity, ps, need_export):
-        return collectives.merge_gauges_stacked_reset(
-            state, self._shard_sharding)
+    def warm_programs(self, ps, need_export):
+        return [self._warm_apply(),
+                WarmProgram("merge", collectives.merge_gauges_stacked_reset,
+                            lambda state, cols: (state,
+                                                 self._shard_sharding))]
 
     def _reshard_capture_device(self, state, snap: dict) -> None:
         # home-shard LWW selection, non-donating; the set mask rides
@@ -548,10 +560,17 @@ class ShardedLLHistTable(_DigestRouted, LLHistTable):
         snap["packed"] = packed
         snap["bins_dev"] = bins_dev
 
-    def _prewarm_readout(self, state, capacity, ps, need_export):
-        merged, fresh = collectives.merge_llhist_stacked_reset(
-            state, self._shard_sharding)
-        return (batch_llhist.flush_packed(merged, ps), fresh)
+    def warm_programs(self, ps, need_export):
+        # the fused merge returns (merged, fresh): the readout reads the
+        # first and hands the second on
+        return [self._warm_apply(),
+                WarmProgram("merge", collectives.merge_llhist_stacked_reset,
+                            lambda state, cols: (state,
+                                                 self._shard_sharding),
+                            then=_result),
+                WarmProgram("readout", batch_llhist.flush_packed,
+                            lambda carry, cols: (carry[0],), static=(ps,),
+                            then=lambda carry, packed: carry[1])]
 
     def _reshard_capture_device(self, state, snap: dict) -> None:
         # register ADD, non-donating: the merged (K, BINS_PAD) bank is
@@ -636,7 +655,7 @@ class ShardedHistoTable(_PerDeviceStates, _DigestRouted, HistoTable):
                 for d in self._devices]
 
     def _apply_to_shard(self, states, shard_counts, i: int, rows, vals,
-                        wts) -> float:
+                        wts, path: str = "live") -> float:
         """One shard's masked fixed-shape batch apply over an explicit
         (states, staging-occupancy) generation — the live path passes
         the table's own, the flush readout the captured one; handles
@@ -646,7 +665,7 @@ class ShardedHistoTable(_PerDeviceStates, _DigestRouted, HistoTable):
         slots, overflow = batch_tdigest.host_slots(
             rows, vals, wts, shard_counts[i])
         if overflow:
-            states[i] = batch_tdigest.compact(states[i])
+            states[i] = self._compact(states[i], path)
             shard_counts[i][:] = 0
             slots, _ = batch_tdigest.host_slots(
                 rows, vals, wts, shard_counts[i])
@@ -656,13 +675,15 @@ class ShardedHistoTable(_PerDeviceStates, _DigestRouted, HistoTable):
         states[i] = batch_tdigest.apply_batch(states[i], *placed)
         return route_s
 
-    def _apply_cols_states(self, states, shard_counts, cols) -> None:
+    def _apply_cols_states(self, states, shard_counts, cols,
+                           path: str = "live") -> None:
         rows, vals, wts = cols
         if not self._digest_routed:
             # legacy round-robin: whole batch to the next shard
             i = self._rr_next
             self._rr_next = (i + 1) % self._n_shards
-            self._apply_to_shard(states, shard_counts, i, rows, vals, wts)
+            self._apply_to_shard(states, shard_counts, i, rows, vals, wts,
+                                 path)
             return
         t0 = time.perf_counter()
         home = self._home_of(rows)
@@ -675,7 +696,7 @@ class ShardedHistoTable(_PerDeviceStates, _DigestRouted, HistoTable):
             rows_i = np.where(home == i, rows, PAD_ROW)
             route_s += time.perf_counter() - t0
             route_s += self._apply_to_shard(states, shard_counts, i,
-                                            rows_i, vals, wts)
+                                            rows_i, vals, wts, path)
         self._plane.note_routed(self.family, counts, route_s)
 
     def _apply_cols(self, cols):
@@ -735,7 +756,8 @@ class ShardedHistoTable(_PerDeviceStates, _DigestRouted, HistoTable):
         self._applies = 0
 
     def _readout_apply(self, states, cols, snap: dict):
-        self._apply_cols_states(states, snap.pop("staged"), cols)
+        self._apply_cols_states(states, snap.pop("staged"), cols,
+                                path="readout")
         return states
 
     def _readout_device(self, states, snap: dict) -> None:
@@ -756,27 +778,43 @@ class ShardedHistoTable(_PerDeviceStates, _DigestRouted, HistoTable):
         snap["export_packed"] = export_packed
         snap["_recycle"] = states
 
-    def _prewarm_apply(self, states, cols, capacity: int):
-        counts = [np.zeros(capacity, np.int32) for _ in self._devices]
-        rows, vals, wts = cols
-        for i in range(self._n_shards):
-            self._apply_to_shard(states, counts, i, rows, vals, wts)
-            # the staging compact too: an all-padding batch overflows
-            # nothing, and the first hot key would compile it (tens of
-            # seconds cold at 32k rows) under the apply lock, or under
-            # the flush lock when the readout's last batch overflows
-            states[i] = batch_tdigest.compact(states[i])
-        return states
+    def warm_programs(self, ps, need_export):
+        """Per shard the apply and the staging compact (each device has
+        an executable of its own), then the collective merge, the
+        readout of what it returned (the carry is (states, merged)
+        between the two), then the per-device zeroing."""
 
-    def _prewarm_readout(self, states, capacity: int, ps: tuple,
-                         need_export: bool):
-        merged = self._merged_state(states, note=False)
-        if need_export:
-            out = batch_tdigest.flush_export_packed(merged, ps)
-        else:
-            out = batch_tdigest.flush_quantiles_packed(
+        def apply(states, cols):
+            rows, vals, wts = cols
+            counts = [np.zeros(st["wv"].shape[0], np.int32)
+                      for st in states]
+            for i in range(self._n_shards):
+                self._apply_to_shard(states, counts, i, rows, vals, wts)
+            return states
+
+        def compact(states):
+            return [batch_tdigest.compact(st) for st in states]
+
+        def readout(merged):
+            if need_export:
+                return batch_tdigest.flush_export_packed(merged, ps)
+            return batch_tdigest.flush_quantiles_packed(
                 merged, ps, fold_staging=False)
-        return (out, self._reset_state_donated(states))
+
+        return [
+            WarmProgram("apply", apply, lambda states, cols: (states, cols),
+                        then=_result),
+            WarmProgram("compact", compact, _state_only, then=_result),
+            WarmProgram("merge",
+                        lambda states: self._merged_state(states,
+                                                          note=False),
+                        _state_only,
+                        then=lambda states, merged: (states, merged)),
+            WarmProgram("readout", readout,
+                        lambda carry, cols: (carry[1],),
+                        then=lambda carry, out: carry[0]),
+            WarmProgram("reset", self._reset_state_donated, _state_only,
+                        then=_result)]
 
     def _retopo_device_locked(self) -> None:
         super()._retopo_device_locked()
@@ -939,25 +977,26 @@ class ShardedSetTable(_PerDeviceStates, _DigestRouted, SetTable):
         snap["registers"] = _SetRegisters.dense(merged, self.capacity)
         snap["_recycle"] = states
 
-    def prewarm_rung(self, capacity: int, percentiles=(),
-                     need_export: bool = True) -> bool:
-        """Unlike the sparse table, the dense per-device banks DO track
-        row capacity, so a resize retraces — prewarm the rung."""
-        return _BaseTable.prewarm_rung(self, capacity, percentiles,
-                                       need_export)
+    def warm_programs(self, ps, need_export):
+        """Unlike the sparse table's, the dense per-device banks DO
+        track row capacity, so a resize retraces: every rung is warmed
+        (`SetTable.prewarm_rung`)."""
 
-    def _prewarm_apply(self, states, cols, capacity: int):
-        rows, idxs, rhos = cols
-        for i, dev in enumerate(self._devices):
-            states[i] = batch_hll.apply_batch(
-                states[i], jax.device_put(rows, dev),
-                jax.device_put(idxs, dev), jax.device_put(rhos, dev))
-        return states
+        def apply(states, cols):
+            return [batch_hll.apply_batch(
+                st, *(jax.device_put(c, dev) for c in cols))
+                for dev, st in zip(self._devices, states)]
 
-    def _prewarm_readout(self, states, capacity: int, ps: tuple,
-                         need_export: bool):
-        merged = self._merged_state(states, note=False)
-        return (batch_hll.estimate(merged), _zeros_like_spare(states))
+        def readout(states):
+            return batch_hll.estimate(
+                self._merged_state(states, note=False))
+
+        return [
+            WarmProgram("apply", apply, lambda states, cols: (states, cols),
+                        then=_result),
+            WarmProgram("readout", readout, _state_only),
+            WarmProgram("reset", _zeros_like_spare, _state_only,
+                        then=_result)]
 
     def _reshard_capture_device(self, states, snap: dict) -> None:
         # elementwise register max, non-donating — bit-exact under

@@ -20,6 +20,8 @@ two, so every entry point goes through here.
 from __future__ import annotations
 
 import os
+import threading
+from typing import Tuple
 
 ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
 DEFAULT_DIR = os.path.join(
@@ -59,3 +61,38 @@ def entries() -> int:
                    if name.endswith("-cache"))
     except OSError:
         return -1
+
+
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": 0,
+                 "/jax/compilation_cache/cache_misses": 1}
+_counts = threading.local()
+_listen_lock = threading.Lock()
+_listening = False
+
+
+def _on_event(event, **_kw) -> None:
+    i = _CACHE_EVENTS.get(event)
+    if i is not None:
+        if not hasattr(_counts, "n"):
+            _counts.n = [0, 0]
+        _counts.n[i] += 1
+
+
+def cache_events() -> Tuple[int, int]:
+    """(hits, misses) of the persistent cache for the compiles THIS
+    thread has asked for, from JAX's own monitoring events (the ones the
+    benchmark's `CompileMeter` counts). JAX raises them on the compiling
+    thread, so another thread's compile (the shape ladder's) is never
+    counted here; a program already in the process's jit cache, or any
+    program while the cache is off, raises neither. Take the difference
+    of two calls around a dispatch."""
+    global _listening
+    if not _listening:
+        with _listen_lock:
+            if not _listening:
+                import jax.monitoring
+
+                jax.monitoring.register_event_listener(_on_event)
+                _listening = True
+    hits, misses = getattr(_counts, "n", (0, 0))
+    return hits, misses
