@@ -204,7 +204,8 @@ def test_datadog_columnar_fallback_on_encoder_error(monkeypatch):
     assert len(series) == len(DatadogColumnarEncoder(sink).encode(batch)[0])
 
 
-@pytest.mark.parametrize("num_workers", [1, 4])
+# None: the cap the sink derives from the host's cores
+@pytest.mark.parametrize("num_workers", [1, 4, None])
 def test_datadog_encoder_error_after_hand_off_posts_no_series_twice(
         monkeypatch, num_workers):
     """Once a body went to a POST worker the fallback would post its
@@ -244,7 +245,7 @@ def _per_body(shape: str, n_parts: int) -> int:
     return per_body
 
 
-@pytest.mark.parametrize("num_workers", [1, 4])
+@pytest.mark.parametrize("num_workers", [1, 4, None])
 @pytest.mark.parametrize("shape", [
     "one_body", "exact_multiple", "remainder", "larger_than_batch",
     "one_series_a_body"])
@@ -272,7 +273,7 @@ def test_datadog_pipeline_posts_the_bodies_of_encode(monkeypatch, shape,
     if len(want) == 1:
         assert posters == {me}
     else:
-        assert me not in posters and len(posters) <= num_workers
+        assert me not in posters and len(posters) <= sink.num_workers
 
 
 def test_datadog_pipeline_under_thread_stress(monkeypatch):
@@ -281,10 +282,20 @@ def test_datadog_pipeline_under_thread_stress(monkeypatch):
     sink's own count of what it sent agrees."""
     import sys
 
+    from veneur_tpu.sinks import datadog as ddmod
+
     posted = _capture_posts(monkeypatch)
     batch, _ = _mk_batch(_extras())
     parts, _checks = DatadogColumnarEncoder(_dd_sink()).encode(batch)
     sink = _dd_sink(num_workers=32, flush_max_per_body=1)
+    pipelines = []
+
+    class Kept(ddmod._BodyPosts):
+        def __init__(self, *args):
+            super().__init__(*args)
+            pipelines.append(self)
+
+    monkeypatch.setattr(ddmod, "_BodyPosts", Kept)
     interval = sys.getswitchinterval()
     sys.setswitchinterval(1e-5)
     try:
@@ -298,6 +309,11 @@ def test_datadog_pipeline_under_thread_stress(monkeypatch):
                       if s["name"] == "egress_post_wall"]
             assert wall["bodies"] == len(parts)
             assert wall["bytes"] == sum(map(len, got))
+            # the workers' counts came back to rest: no update was lost
+            posts = pipelines.pop()
+            assert (posts.unanswered, posts.in_flight) == (0, 0)
+            assert 1 <= wall["peak_in_flight"] <= wall["workers"] <= 32
+            assert wall["workers"] == len(posts.workers)
     finally:
         sys.setswitchinterval(interval)
     assert not any(t.name.startswith("datadog-post-")

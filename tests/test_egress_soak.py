@@ -13,8 +13,10 @@ refusal: 29 keys of one window absent or short, with every line read).
     Python loop (`encode`) gives for that flush's batch.
 
 Both encoders run it, so a failure on both names the program and not
-the encoder. (c), the arena under churn, is in test_egress.py's idiom
-at the end of this file.
+the encoder; the native one runs it once more with as many POST workers
+as this host's cores allow, where a flush's bodies arrive in any order
+and must still be the same bodies, each once. (c), the arena under
+churn, is in test_egress.py's idiom at the end of this file.
 """
 
 import gzip
@@ -128,15 +130,15 @@ def _send_pass(sock, address, lines, seconds: float) -> None:
             time.sleep(behind)
 
 
-def _soak(encoder: str) -> dict:
+def _soak(encoder: str, num_workers) -> dict:
     """The run: -> what was sent, and per flush what was posted and what
-    the Python loop makes of its batch."""
+    the Python loop makes of its batch. One POST worker: bodies arrive
+    in the order they were cut; `None`: the cap a sink derives from the
+    host's cores, as a deployment that sets no `datadog_num_workers`."""
     intake = _Intake()
-    # one POST worker, as `Config.num_workers` has it: bodies arrive in
-    # the order they were cut
     sink = DatadogMetricSink("datadog", "key", intake.url, "soak-host",
                              1.0, flush_max_per_body=PER_BODY,
-                             num_workers=1)
+                             num_workers=num_workers)
     if encoder == "python":
         sink._encoder._lib = None
     assert sink._encoder.name == encoder
@@ -150,7 +152,10 @@ def _soak(encoder: str) -> dict:
         real_flush(batch)   # returns after its last body was answered
         flushes.append({"posted": list(intake.bodies[before:]),
                         "parts": reference.encode(batch)[0],
-                        "rows": len(batch)})
+                        "rows": len(batch),
+                        "workers": max(
+                            s["workers"] for s in list(batch.timing.spans)
+                            if s["name"] == "egress_post_wall")})
 
     sink.flush_columnar = recording_flush
     cfg = generate_config(
@@ -222,7 +227,7 @@ def _soak(encoder: str) -> dict:
         intake.close()
     return {"flushes": flushes, "first": first, "passes": passes,
             "pumped": pumped, "in_one_interval": in_one_interval,
-            "ledger": ledger}
+            "ledger": ledger, "worker_cap": sink.num_workers}
 
 
 def _series_of(flush: dict) -> list:
@@ -237,11 +242,14 @@ def _name_of(series: dict) -> tuple:
     return (series["metric"], series.get("host"), tuple(series["tags"]))
 
 
-@pytest.fixture(scope="module", params=["native", "python"])
+@pytest.fixture(scope="module", params=[
+    ("native", 1), ("python", 1), ("native", None)],
+    ids=["native", "python", "native-host_workers"])
 def soaked(request):
-    if request.param == "native" and native.load_series() is None:
+    encoder, num_workers = request.param
+    if encoder == "native" and native.load_series() is None:
         pytest.skip("the native series encoder did not build")
-    return _soak(request.param)
+    return _soak(encoder, num_workers)
 
 
 def test_soak_crosses_a_dozen_ticks_with_traffic(soaked):
@@ -342,13 +350,28 @@ def test_soak_flushes_count_their_own_series(soaked):
 
 
 def test_soak_posted_bodies_are_the_python_loops_parts(soaked):
-    """(b): every flush's bodies are, in order, `encode(batch)`'s parts
-    cut every `flush_max_per_body` and joined."""
+    """(b): every flush's bodies are `encode(batch)`'s parts cut every
+    `flush_max_per_body` and joined: in order behind one POST worker,
+    each once in whatever order behind several."""
     for flush in soaked["flushes"]:
         parts = flush["parts"]
         want = [b'{"series":[' + b",".join(parts[k:k + PER_BODY]) + b"]}"
                 for k in range(0, len(parts), PER_BODY)]
-        assert flush["posted"] == want
+        if soaked["worker_cap"] == 1:
+            assert flush["posted"] == want
+        else:
+            assert sorted(flush["posted"]) == sorted(want)
+
+
+def test_soak_ran_the_post_workers_its_cap_allows(soaked):
+    """A flush of several bodies behind the native encoder keeps more
+    than one worker busy wherever the cap allows a second."""
+    started = [f["workers"] for f in soaked["flushes"]]
+    assert max(started) <= soaked["worker_cap"]
+    if soaked["worker_cap"] > 1:
+        assert max(started) >= 2
+    else:   # 0: a quiet interval's one body, sent by the sink thread
+        assert set(started) <= {0, 1}
 
 
 # -- (c) the prefix arena under churn ---------------------------------------

@@ -116,7 +116,7 @@ def flushed(tmp_path_factory):
                      name="stub-intake").start()
     sink = DatadogMetricSink(
         "datadog", "key", f"http://127.0.0.1:{httpd.server_port}", "me",
-        10.0, flush_max_per_body=400)
+        10.0, flush_max_per_body=400, num_workers=4)
     cfg = generate_config(
         statsd_listen_addresses=["udp://127.0.0.1:0"],
         http_address="127.0.0.1:0", interval=60.0, num_readers=2)
@@ -238,6 +238,10 @@ def test_gzip_and_http_run_inside_the_post_wall(flushed):
     assert rnd["phases"]["egress_http_s"] >= len(bodies) * INTAKE_DELAY_S
     sent = rnd["sinks"]["metric:datadog"]
     assert sent["bodies"] == wall["bodies"]
+    # each answer takes 0.2 s, the encode of a body far less: every
+    # hand-off but the first few finds all workers busy, up to the cap
+    assert sent["workers"] == wall["workers"] == wall["peak_in_flight"] \
+        == min(4, wall["bodies"])
     # bodies whose gzip began while the encoder ran: never the last one
     [encode] = [s for s in spans if s["name"] == "egress_encode"]
     assert sent["bodies_overlapped"] == wall["bodies_overlapped"] == sum(
@@ -314,11 +318,13 @@ def test_debug_flush_names_the_series_encoder(flushed, field):
 @pytest.mark.parametrize("row, field", [
     ("veneur_sink_datadog_encode_native_rows_total", "native_rows"),
     ("veneur_sink_datadog_encode_prefix_renders_total", "prefix_renders"),
-    ("veneur_sink_datadog_encode_count_mismatch_total", "count_mismatch")])
+    ("veneur_sink_datadog_encode_count_mismatch_total", "count_mismatch"),
+    ("veneur_sink_datadog_post_workers_total", "workers")])
 def test_metrics_count_the_series_encoders_rows(flushed, row, field):
-    """Both counters add up what each round's `sinks.<key>` says:
+    """The counters add up what each round's `sinks.<key>` says:
     `native_rows` a flush's section series, `prefix_renders` those of
-    the first flush and next to nothing since."""
+    the first flush and next to nothing since, `workers` the POST
+    workers each flush started."""
     if native.load_series() is None:
         pytest.skip("the native series encoder did not build")
     rounds = flushed["rounds"]
@@ -332,6 +338,8 @@ def test_metrics_count_the_series_encoders_rows(flushed, row, field):
         assert per_round == series
     elif field == "count_mismatch":
         assert per_round == [0, 0, 0, 0]
+    elif field == "workers":
+        assert per_round == [4, 4, 4, 4]
     else:
         assert per_round[0] == series[0] >= 2520
         assert max(per_round[1:]) <= 1

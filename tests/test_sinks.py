@@ -4,6 +4,7 @@ sinks/cortex/cortex_test.go:764)."""
 
 import gzip
 import json
+import os
 import socket
 import threading
 import time
@@ -303,6 +304,178 @@ class TestDatadogPipeline:
             assert intake.most_in_flight >= 2
         assert len({s["thread"] for s in spans["egress_http"]}) \
             <= num_workers
+        assert intake.most_in_flight <= wall["peak_in_flight"] \
+            <= wall["workers"] <= num_workers
+
+    @pytest.mark.parametrize("cores", [3, 8])
+    def test_a_sink_of_no_stated_cap_fills_the_hosts(self, monkeypatch,
+                                                     cores):
+        """No `num_workers`: the cores this process may run on, less
+        one, cap the POSTs in flight, and a slow intake fills the cap's
+        first places (here each body waits 30 ms for its answer while
+        the encode takes well under one)."""
+        monkeypatch.setattr(os, "sched_getaffinity",
+                            lambda pid: set(range(cores)), raising=False)
+        intake = CapturingHTTPServer(delay_s=0.03)
+        try:
+            spans = self._flush(intake.url, 8, None)
+        finally:
+            intake.close()
+        [wall] = spans["egress_post_wall"]
+        assert len(intake.requests) == wall["bodies"] >= 8
+        assert 1 < intake.most_in_flight <= wall["peak_in_flight"] \
+            <= wall["workers"] <= cores - 1
+        assert len({s["thread"] for s in spans["egress_http"]}) \
+            == wall["workers"]
+
+    def test_one_worker_serves_an_encoder_slower_than_the_send(
+            self, monkeypatch):
+        """An instant fake POST and an encoder that takes 20 ms more
+        after each body it hands off: every hand-off finds the one
+        worker waiting, so no second one starts under a cap of 8."""
+        from test_egress import _capture_posts
+        from veneur_tpu.core.egress import DatadogColumnarEncoder
+
+        posted = _capture_posts(monkeypatch)
+
+        class Slow(DatadogColumnarEncoder):
+            def encode_bodies(self, batch, per_body, emit):
+                handed = []
+
+                def emit_and_dawdle(parts):
+                    emit(parts)
+                    handed.append(parts)
+                    deadline = time.time() + 10.0
+                    while len(posted) < len(handed) \
+                            and time.time() < deadline:
+                        time.sleep(0.001)
+                    time.sleep(0.02)
+
+                return super().encode_bodies(batch, per_body,
+                                             emit_and_dawdle)
+
+        spans = self._flush("http://unused.invalid", 20, 8,
+                            lambda sink, rnd: Slow(sink))
+        [wall] = spans["egress_post_wall"]
+        assert wall["bodies"] == len(posted) >= 3
+        assert wall["workers"] == wall["peak_in_flight"] == 1
+        assert {thread for *_, thread in posted} == {"datadog-post-0"}
+
+    def test_two_failing_workers_give_one_error_naming_both(self, fake):
+        """Two workers each fail on a body: the flush ends once every
+        worker has, with one `SeriesPartlySent` that names both, and
+        the bodies that did leave left once."""
+        from test_egress import _mk_batch
+        from veneur_tpu.sinks.datadog import (DatadogMetricSink,
+                                              SeriesPartlySent)
+
+        batch, _ = _mk_batch()
+        sink = DatadogMetricSink("datadog", api_key="k", api_url=fake.url,
+                                 hostname="dh", interval=10.0,
+                                 flush_max_per_body=8, num_workers=2)
+        post, failed = sink._post_series_body_safe, []
+        both = threading.Barrier(2, timeout=10.0)
+
+        def post_or_fail(body, phase=None):
+            # each worker's first body: held until the other worker has
+            # one too, then lost
+            me = threading.current_thread().name
+            if me.startswith("datadog-post-") and me not in failed:
+                failed.append(me)
+                both.wait()
+                raise RuntimeError(f"boom on {me}")
+            post(body, phase)
+
+        sink._post_series_body_safe = post_or_fail
+        with pytest.raises(SeriesPartlySent) as raised:
+            sink.flush_batch(batch)
+        assert sorted(failed) == ["datadog-post-0", "datadog-post-1"]
+        assert "2 of " in str(raised.value)
+        assert all(name in str(raised.value) for name in failed)
+        [wall] = [s for s in batch.timing.spans
+                  if s["name"] == "egress_post_wall"]
+        assert wall["workers"] == 2
+        assert len(fake.requests) == wall["bodies"] - 2 >= 1
+        bodies = [body for _, _, body in fake.requests]
+        assert len(set(bodies)) == len(bodies)
+        assert not any(t.name.startswith("datadog-post-")
+                       for t in threading.enumerate())
+
+    @pytest.mark.parametrize("per_body, workers", [(25_000, 0), (8, None)])
+    def test_the_flush_counts_the_workers_it_started(self, fake, per_body,
+                                                     workers):
+        """`sink.datadog.post.workers` and the wall's `workers`: what
+        the flush started, 0 where the sink thread sent the one body."""
+        from test_egress import _mk_batch
+        from veneur_tpu.sinks.datadog import DatadogMetricSink
+
+        counts = []
+
+        class Statsd:
+            def count(self, name, value, tags=()):
+                counts.append((name, value, list(tags)))
+
+        batch, _ = _mk_batch()
+        sink = DatadogMetricSink("dd-a", api_key="k", api_url=fake.url,
+                                 hostname="dh", interval=10.0,
+                                 flush_max_per_body=per_body, num_workers=3)
+        sink._statsd = Statsd()
+        sink.flush_columnar(batch)
+        [wall] = [s for s in batch.timing.spans
+                  if s["name"] == "egress_post_wall"]
+        [(value, tags)] = [(v, t) for name, v, t in counts
+                           if name == "sink.datadog.post.workers"]
+        assert tags == ["sink:dd-a"] and value == wall["workers"]
+        if workers == 0:
+            assert value == 0 and wall["peak_in_flight"] == 1
+        else:
+            assert 1 <= value <= 3
+            assert 1 <= wall["peak_in_flight"] <= value
+
+
+class TestDatadogPostWorkerCap:
+    """What caps a flush's POST workers (`host_post_workers`, the
+    factory): the sink's own `datadog_num_workers`, else the host."""
+
+    @staticmethod
+    def _from_config(sink_keys, **server_keys):
+        from veneur_tpu.config import Config, SinkConfig
+        from veneur_tpu.sinks import MetricSinkTypes, register_builtin_sinks
+
+        register_builtin_sinks()
+        cfg = Config(**server_keys)
+        cfg.apply_defaults()
+        return MetricSinkTypes["datadog"](
+            SinkConfig(kind="datadog", name="datadog", config={
+                "datadog_api_key": "k", **sink_keys}), cfg)
+
+    @pytest.mark.parametrize("cores, cap", [(1, 1), (2, 1), (8, 7),
+                                            (112, 111)])
+    def test_the_cores_the_process_may_run_on_less_one(self, monkeypatch,
+                                                       cores, cap):
+        monkeypatch.setattr(os, "sched_getaffinity",
+                            lambda pid: set(range(cores)), raising=False)
+        monkeypatch.setattr(os, "cpu_count", lambda: 64)
+        # `Config.num_workers` counts aggregation workers, not these
+        for server_keys in ({}, {"num_workers": 96}):
+            sink = self._from_config({}, **server_keys)
+            assert sink.num_workers == cap
+
+    def test_the_hosts_cores_where_no_affinity_is_to_be_had(self,
+                                                            monkeypatch):
+        monkeypatch.delattr(os, "sched_getaffinity", raising=False)
+        monkeypatch.setattr(os, "cpu_count", lambda: 6)
+        assert self._from_config({}).num_workers == 5
+        monkeypatch.setattr(os, "cpu_count", lambda: None)
+        assert self._from_config({}).num_workers == 1
+
+    @pytest.mark.parametrize("key, cap", [(1, 1), (3, 3), ("24", 24)])
+    def test_the_sinks_own_key_wins(self, monkeypatch, key, cap):
+        monkeypatch.setattr(os, "sched_getaffinity",
+                            lambda pid: set(range(8)), raising=False)
+        sink = self._from_config({"datadog_num_workers": key},
+                                 num_workers=96)
+        assert sink.num_workers == cap
 
 
 class TestCortex:

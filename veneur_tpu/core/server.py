@@ -2110,7 +2110,7 @@ class Server:
                 continue
             if span["name"] == "egress_post_wall":
                 fields = ("bodies", "bytes", "gzip_bytes",
-                          "bodies_overlapped")
+                          "bodies_overlapped", "workers")
             elif span["name"] == "egress_encode" and "encoder" in span:
                 outcome["encoder"] = span["encoder"]
                 fields = ("native_rows", "prefix_renders",

@@ -5,7 +5,10 @@ Behavioral parity with reference sinks/datadog/datadog.go (660 LoC):
   "rate" (value/interval) (datadog.go DDMetric conversion), gauges stay
   gauges, status checks go to /api/v1/check_run.
 - A flush is chunked across `flush_max_per_body` and POSTed in parallel
-  (reference chunks across num_workers goroutines, datadog.go:182-207).
+  (reference: a goroutine a chunk, datadog.go:182-207), by as many POST
+  workers as the flush keeps busy, up to `datadog_num_workers` or, where
+  that is not set, the cores this process may run on less one
+  (`host_post_workers`). `Config.num_workers` has no say here.
 - `device:` / `host:` magic tags move into dedicated DDMetric fields.
 - Events (from flush_other_samples) post to the events intake.
 - Spans buffer in a bounded ring (2^14, reference datadog.go spanBuffer)
@@ -17,10 +20,11 @@ from __future__ import annotations
 import collections
 import json
 import logging
+import os
 import queue
 import threading
 import time
-from typing import Any, Dict, List, Sequence
+from typing import Any, Dict, List, Optional, Sequence
 
 from veneur_tpu.core.telemetry import FlushRound, current_round
 from veneur_tpu.samplers.metrics import InterMetric, MetricType
@@ -34,10 +38,23 @@ logger = logging.getLogger("veneur_tpu.sinks.datadog")
 DATADOG_SPAN_BUFFER_CAP = 1 << 14  # reference datadog.go datadogSpanBufferSize
 
 
+def host_post_workers() -> int:
+    """The most POST workers a flush runs where `datadog_num_workers`
+    does not say: the cores this process may run on, less one for the
+    sink thread that encodes beside them, at least one (a one- or
+    two-core sidecar gets one worker)."""
+    try:
+        cores = len(os.sched_getaffinity(0))
+    except (AttributeError, OSError):   # not on this platform
+        cores = os.cpu_count() or 1
+    return max(cores - 1, 1)
+
+
 class DatadogMetricSink(MetricSink):
     def __init__(self, name: str, api_key: str, api_url: str, hostname: str,
                  interval: float, flush_max_per_body: int = 25_000,
-                 num_workers: int = 4, tags: Sequence[str] = (),
+                 num_workers: Optional[int] = None,
+                 tags: Sequence[str] = (),
                  metric_name_prefix_drops: Sequence[str] = (),
                  excluded_tag_prefixes: Sequence[str] = (),
                  exclude_tags_prefix_by_prefix_metric: Dict[str, Sequence[str]] = None,
@@ -48,7 +65,10 @@ class DatadogMetricSink(MetricSink):
         self.hostname = hostname
         self.interval = max(interval, 1e-9)
         self.flush_max_per_body = flush_max_per_body
-        self.num_workers = num_workers
+        # the cap on a flush's POST workers; how many start is the
+        # flush's own matter (`_BodyPosts.hand_off`)
+        self.num_workers = (max(int(num_workers), 1) if num_workers
+                            else host_post_workers())
         self.tags = list(tags)
         # reference datadog.go:313-317: drop whole metrics by name prefix
         self.metric_name_prefix_drops = list(metric_name_prefix_drops)
@@ -117,7 +137,7 @@ class DatadogMetricSink(MetricSink):
         `flush_max_per_body` of them into a body (`egress_join`), and
         only then are the bodies gzipped and posted (`egress_post_wall`
         around `_post_parallel`; per body `egress_gzip`, `egress_http`),
-        by up to `num_workers` threads, this one among them. The spans
+        by up to `self.num_workers` threads, this one among them. The spans
         are the columnar flush's, by name, timed into the round whose
         sink thread this is (`telemetry.current_round`)."""
         rnd = current_round.get() or FlushRound()
@@ -179,10 +199,14 @@ class DatadogMetricSink(MetricSink):
         A pipeline: this thread encodes, and each time
         `flush_max_per_body` parts are ready and more are to come they
         go to a POST worker, which joins, gzips and sends that body
-        while this thread encodes the next. At most `num_workers`
-        bodies are in gzip/POST at once. A flush of one body starts no
-        thread and sends it from here. Returns after the last body was
-        answered (or failed and was logged) and the checks were posted.
+        while this thread encodes the next. A worker starts only when a
+        body is ready and every running worker has one, so the flush
+        runs as many as gzip and POST take beside the encode, and never
+        more than `self.num_workers`: `datadog_num_workers`, or else
+        the host's cores less one (`host_post_workers`). A flush of one
+        body starts no thread and sends it from here. Returns after the
+        last body was answered (or failed and was logged) and the checks
+        were posted; no worker outlives it.
 
         An error before any hand-off is raised as it is (`flush_batch`
         falls back); after one, the workers are waited for and
@@ -200,9 +224,12 @@ class DatadogMetricSink(MetricSink):
         `egress_http` on whichever thread sends it; `egress_post_wall`
         from the first hand-off to the last answer (it overlaps the
         encode; `bodies_overlapped` counts the bodies whose gzip began
-        before the encode ended); `egress_post_tail` from the end of
-        the encode to the last answer, the part of the send that this
-        thread still waits for."""
+        before the encode ended, `workers` the POST workers started and
+        `peak_in_flight` the most bodies in gzip or POST at once);
+        `egress_post_tail` from the end of the encode to the last
+        answer, the part of the send that this thread still waits for.
+        With more than one worker the per-body spans overlap: their sum
+        is what the send cost, not how long it took."""
         rnd = batch.timing
         enc = self._encoder
         posts = _BodyPosts(self, rnd)
@@ -234,7 +261,9 @@ class DatadogMetricSink(MetricSink):
         if posts.errors:
             raise SeriesPartlySent(
                 f"{len(posts.errors)} of {len(posts.sent)} bodies failed "
-                "on a POST worker") from posts.errors[0]
+                "on POST workers " + ", ".join(
+                    sorted({worker for worker, _ in posts.errors}))
+            ) from posts.errors[0][1]
         self._post_checks(checks)
         statsd = getattr(self, "_statsd", None)
         if statsd is not None:
@@ -245,13 +274,15 @@ class DatadogMetricSink(MetricSink):
                          enc.prefix_renders, tags=tags)
             statsd.count("sink.datadog.encode.count_mismatch",
                          mismatch, tags=tags)
+            statsd.count("sink.datadog.post.workers", len(posts.workers),
+                         tags=tags)
         self.note_egress(encode["wall_s"], tail, encoder=enc.name)
 
     def _post_parallel(self, chunks, post_one) -> None:
         """The legacy flush's send: every body exists before the first
         leaves, and nothing here overlaps the encode. Concurrency capped
-        at num_workers POSTs, this thread one of them (reference
-        datadog.go:182-207 chunks a flush across num_workers)."""
+        at `self.num_workers` POSTs, this thread one of them (reference
+        datadog.go:182-207: a goroutine a chunk)."""
         it = iter(chunks)
 
         def worker():
@@ -330,10 +361,10 @@ class SeriesPartlySent(Exception):
 class _BodyPosts:
     """The sending half of one `flush_columnar`: bodies handed off as
     lists of parts, each joined, gzipped and posted by one of up to
-    `num_workers` POST workers beside the encoding thread, or, when the
-    flush has one body, by the encoding thread itself. The legacy
-    `flush` uses its wall and `post` alone, for bodies that all exist
-    before the first leaves."""
+    `sink.num_workers` POST workers beside the encoding thread, or,
+    when the flush has one body, by the encoding thread itself. The
+    legacy `flush` uses its wall and `post` alone, for bodies that all
+    exist before the first leaves."""
 
     def __init__(self, sink: DatadogMetricSink, rnd):
         self.sink = sink
@@ -341,16 +372,26 @@ class _BodyPosts:
         self.queue: "queue.SimpleQueue" = queue.SimpleQueue()
         self.workers: List[threading.Thread] = []
         self.sent: List[dict] = []    # per body: bytes, gzip (its span)
-        self.errors: List[Exception] = []
+        self.errors: List[tuple] = []  # (worker's name, what it raised)
         self.wall = None              # the egress_post_wall phase
+        self._lock = threading.Lock()  # of the three counts below
+        self.unanswered = 0           # bodies handed off, not yet answered
+        self.in_flight = 0            # bodies in gzip or POST right now
+        self.peak_in_flight = 0
 
     def hand_off(self, parts: List[bytes]) -> None:
-        """A full body with more parts to come (the encoder's `emit`,
-        on the encoding thread): queue it, and start one more worker
-        while fewer than `num_workers` run. Never waits."""
+        """A body for the POST workers (the encoder's `emit`, on the
+        encoding thread): queue it, and start one more worker if every
+        running one has a body to send and fewer than
+        `sink.num_workers` run. So the workers number what gzip and
+        POST take beside the encode: several behind the native encoder,
+        one behind an encoder slower than a send. Never waits."""
         self.open_wall()
+        with self._lock:
+            self.unanswered += 1
+            all_busy = self.unanswered > len(self.workers)
         self.queue.put(parts)
-        if len(self.workers) < max(self.sink.num_workers, 1):
+        if all_busy and len(self.workers) < self.sink.num_workers:
             worker = threading.Thread(
                 target=self._work, daemon=True,
                 name=f"{self.sink.name()}-post-{len(self.workers)}")
@@ -368,7 +409,7 @@ class _BodyPosts:
                 self.post(body)
             else:
                 if rest:
-                    self.queue.put(rest)
+                    self.hand_off(rest)
                 for _ in self.workers:
                     self.queue.put(None)
                 for worker in self.workers:
@@ -394,7 +435,9 @@ class _BodyPosts:
             bytes=sum(sent["bytes"] for sent in self.sent),
             gzip_bytes=sum(g.get("bytes", 0) for g in gzips),
             bodies_overlapped=sum(
-                1 for g in gzips if g["start_s"] < encode_end_s))
+                1 for g in gzips if g["start_s"] < encode_end_s),
+            workers=len(self.workers),
+            peak_in_flight=self.peak_in_flight)
 
     def _work(self) -> None:
         for parts in iter(self.queue.get, None):
@@ -402,7 +445,10 @@ class _BodyPosts:
                 self.post(self._join(parts))
             except Exception as e:
                 logger.exception("datadog POST worker failed on a body")
-                self.errors.append(e)
+                self.errors.append((threading.current_thread().name, e))
+            finally:
+                with self._lock:
+                    self.unanswered -= 1
 
     def _join(self, parts: List[bytes]) -> bytes:
         """The body of these parts, copied once (a native part is
@@ -426,7 +472,14 @@ class _BodyPosts:
             sent[name] = phase.rec
             return phase
 
-        self.sink._post_series_body_safe(body, timed)
+        with self._lock:
+            self.in_flight += 1
+            self.peak_in_flight = max(self.peak_in_flight, self.in_flight)
+        try:
+            self.sink._post_series_body_safe(body, timed)
+        finally:
+            with self._lock:
+                self.in_flight -= 1
 
 
 # timestamp plausibility window, adapted to this pipeline's nanosecond
@@ -567,8 +620,8 @@ def _metric_factory(sink_config, server_config):
         hostname=server_config.hostname,
         interval=server_config.interval,
         flush_max_per_body=int(c.get("datadog_flush_max_per_body", 25_000)),
-        num_workers=int(c.get("datadog_num_workers",
-                              server_config.num_workers) or 4),
+        # unset (or 0): the host's cores decide, not `Config.num_workers`
+        num_workers=c.get("datadog_num_workers"),
         tags=c.get("tags", []) or [],
         metric_name_prefix_drops=c.get(
             "datadog_metric_name_prefix_drops", []) or [],
