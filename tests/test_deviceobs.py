@@ -42,6 +42,7 @@ from veneur_tpu.core.flusher import (flush_columnstore_batch,
                                      readout_columnstore,
                                      swap_columnstore)
 from veneur_tpu.core.server import Server
+from veneur_tpu.core.telemetry import FlushRound
 from veneur_tpu.samplers.metrics import HistogramAggregates
 from veneur_tpu.samplers.parser import Parser
 from veneur_tpu.sinks.channel import ChannelMetricSink
@@ -315,6 +316,103 @@ class TestKernelRegistry:
         assert bases <= set(HIST_ROWS)
         assert set(KERNEL_KINDS) == {
             b.split(".")[-1][:-2] for b in HIST_ROWS}
+
+
+    def test_readout_row_holds_the_completion_stamps(self):
+        """`device.kernel.readout_s{family}` is the chip's time: one
+        observation a `chip_busy{family,device}` span of the round, of
+        that span's wall, and nothing of the dispatch's host wall. The
+        sets here stay on the host (no promoted row, no estimate), so
+        the family has no stamp and no row."""
+        store = _mk_store()
+        obs = DeviceObservatory()
+        store.attach_deviceobs(obs)
+        rounds = []
+        for round_no in range(3):
+            _feed_store(store, corpus(round_no))
+            rounds.append(FlushRound())
+            flush_columnstore_batch(store, True, PCTS, AGGS,
+                                    timing=rounds[-1])
+        busy = [s for rnd in rounds for s in rnd.spans
+                if s["name"] == "chip_busy"]
+        families = ("counter", "gauge", "histogram", "llhist")
+        assert sorted(s["family"] for s in busy) == sorted(families * 3)
+        rows = {k["family"]: k for k in obs.kernel_report()["kernels"]
+                if k["kind"] == "readout"}
+        assert set(rows) == set(families)
+        for family, row in rows.items():
+            mine = [s["wall_s"] for s in busy if s["family"] == family]
+            assert row["dispatches"] == row["wall"]["count"] == 3
+            assert row["wall"]["sum"] == pytest.approx(sum(mine), abs=2e-6)
+        # the watcher is one thread, gone with `close`; a later flush
+        # would start another
+        watcher = obs.readout_watcher()
+        assert watcher.thread.is_alive()
+        obs.close()
+        watcher.thread.join(5.0)
+        assert not watcher.thread.is_alive()
+        assert obs.readout_watcher() is not watcher
+        obs.close()
+
+    def test_watcher_under_many_flush_threads(self):
+        """More threads than cores hand rounds to the one watcher under
+        a short switch interval: no hand-off is lost or stamped twice,
+        `join` returns only once all are stamped, and the spans of the
+        one device never overlap, whoever handed them over."""
+        import sys
+
+        import jax.numpy as jnp
+
+        obs = DeviceObservatory()
+        watcher = obs.readout_watcher()
+        threads, per_thread, errors = 24, 40, []
+        rounds = [FlushRound() for _ in range(threads)]
+        handle = jnp.zeros(4)
+        [device] = [f"{d.platform}:{d.id}" for d in handle.devices()]
+
+        def flush_thread(rnd):
+            try:
+                for i in range(per_thread):
+                    watcher.watch(rnd, f"f{i % 4}", {device: [handle + i]},
+                                  time.perf_counter())
+                    if i % 8 == 7 and not watcher.join():
+                        errors.append("join timed out")
+            except Exception as e:   # pragma: no cover - the assertion
+                errors.append(repr(e))
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            workers = [threading.Thread(target=flush_thread, args=(rnd,))
+                       for rnd in rounds]
+            for t in workers:
+                t.start()
+            for t in workers:
+                t.join(60.0)
+            assert not any(t.is_alive() for t in workers)
+        finally:
+            sys.setswitchinterval(old)
+        assert watcher.join() and not errors, errors
+        assert watcher._open == 0 and not watcher._handed
+        spans = [s for rnd in rounds for s in rnd.spans]
+        assert [len(rnd.spans) for rnd in rounds] == [per_thread] * threads
+        assert {s["device"] for s in spans} == {device}
+        ends = []   # on one clock: each round counts from its own t0
+        for rnd in rounds:
+            ends += [(rnd.t0 + s["start_s"], rnd.t0 + s["start_s"]
+                      + s["wall_s"]) for s in rnd.spans]
+        ends.sort(key=lambda se: se[1])
+        for (_s0, e0), (s1, _e1) in zip(ends, ends[1:]):
+            assert e0 <= s1 + 1e-9
+        counted = {k["family"]: k for k in obs.kernel_report()["kernels"]}
+        assert sum(k["wall"]["count"] for k in counted.values()) == len(spans)
+        assert sum(k["dispatches"] for k in counted.values()) == len(spans)
+        obs.close()
+
+    def test_disabled_observatory_has_no_watcher(self):
+        obs = DeviceObservatory(enabled=False)
+        assert obs.readout_watcher() is None
+        obs.close()
 
 
 # -------------------------------------------------------------------------

@@ -70,6 +70,14 @@ MESH_ONLY = ("flush.merge_ms", "ingest.shard_route_s", "mesh.merge_rounds")
 ROUTED_ONLY = ("flush.route_ms", "flush.materialize_ms",
                "flush.egress_select_ms", "flush.routed_rows",
                "flush.unrouted_rows", "flush.route_evaluated_rows")
+# ISSUE 39: `dispatch{set}` in its four parts, the flush thread's whole
+# wait for the chip, and the readout's device time from completion stamps
+SET_CHILDREN = ("set_fold", "set_wait", "set_transfer", "set_host_estimate")
+NEW_PHASES += tuple(name + "_s" for name in SET_CHILDREN) + (
+    "chip_wait_s", "chip_busy_s")
+WATCHED = ("histogram", "counter", "gauge", "llhist", "set")  # as dispatched
+HOT_SETS = 3           # set keys that pass the promotion threshold a round
+PROMOTE_SAMPLES = 4    # the table's own is 2,048 on the CPU backend
 
 
 class _Intake(BaseHTTPRequestHandler):
@@ -93,6 +101,12 @@ def _lines(round_no: int) -> list:
     for i in range(60):
         lines += [b"sp.g%d:2|g" % i, b"sp.s%d:m%d|s" % (i, round_no),
                   b"sp.l%d:%d|l" % (i, i + 1)]
+    # sets past the promotion threshold: their rows live on the device,
+    # so the readout waits for the estimate (`set_wait`); the 60 above
+    # stay on the host (`set_host_estimate`)
+    for i in range(HOT_SETS):
+        lines += [b"sp.hot%d:m%d.%d|s" % (i, round_no, j)
+                  for j in range(3 * PROMOTE_SAMPLES)]
     return lines
 
 
@@ -122,6 +136,7 @@ def flushed(tmp_path_factory):
         http_address="127.0.0.1:0", interval=60.0, num_readers=2)
     cfg.tpu.counter_capacity = cfg.tpu.histo_capacity = 512
     server = Server(cfg, extra_metric_sinks=[sink])
+    server.store.sets._promote_samples = PROMOTE_SAMPLES
     server.start()
     pumped = getattr(server._listeners[0], "pump", None) is not None
     sock = socket.socket(socket.AF_INET, socket.SOCK_DGRAM)
@@ -343,6 +358,150 @@ def test_metrics_count_the_series_encoders_rows(flushed, row, field):
     else:
         assert per_round[0] == series[0] >= 2520
         assert max(per_round[1:]) <= 1
+
+
+# -- (a2) the wait for the chip, where it happens (ISSUE 39) ---------------
+
+def _spans(rnd: dict, name: str, **tags) -> list:
+    return [s for s in rnd["spans"] if s["name"] == name
+            and all(s.get(k) == v for k, v in tags.items())]
+
+
+def _end(span: dict) -> float:
+    return span["start_s"] + span["wall_s"]
+
+
+@pytest.mark.parametrize("name", SET_CHILDREN)
+def test_dispatch_set_has_the_child(flushed, name):
+    """The round's set table held promoted rows and sparse ones, so all
+    four parts of `dispatch{set}` are there, inside it (the fold a
+    second time where a last pending batch was applied)."""
+    rnd = flushed["round"]
+    [outer] = _spans(rnd, "dispatch", family="set")
+    children = _spans(rnd, name)
+    assert 1 <= len(children) <= 1 + (name == "set_fold")
+    for child in children:
+        assert child["parent"] == "dispatch" and child["family"] == "set"
+        assert child["thread"] == outer["thread"]
+        assert outer["start_s"] - 1e-6 <= child["start_s"]
+        assert _end(child) <= _end(outer) + 1e-6
+
+
+def test_set_children_follow_each_other_and_make_up_dispatch_set(flushed):
+    rnd = flushed["round"]
+    [outer] = _spans(rnd, "dispatch", family="set")
+    parts = sorted((s for name in SET_CHILDREN for s in _spans(rnd, name)),
+                   key=lambda s: s["start_s"])
+    assert [s["name"] for s in parts][-4:] == list(SET_CHILDREN)
+    for before, after in zip(parts, parts[1:]):
+        assert _end(before) <= after["start_s"] + 1e-6
+    covered = sum(s["wall_s"] for s in parts)
+    assert covered == pytest.approx(
+        sum(rnd["phases"][name + "_s"] for name in SET_CHILDREN), abs=1e-5)
+    assert covered <= outer["wall_s"] + 1e-6
+    assert outer["wall_s"] - covered <= SWITCH_S, (outer, parts)
+
+
+def test_chip_wait_is_every_sync_and_the_sets_wait(flushed):
+    p = flushed["round"]["phases"]
+    assert p["set_wait_s"] > 0
+    assert p["chip_wait_s"] == pytest.approx(p["sync_s"] + p["set_wait_s"],
+                                             abs=2e-6)
+
+
+@pytest.mark.parametrize("family", WATCHED)
+def test_one_chip_busy_span_per_family_and_device(flushed, family):
+    """One device here, so one completion stamp a family: inside
+    `readout`, closed before the assembly starts, not before the
+    family was dispatched."""
+    rnd = flushed["round"]
+    [busy] = _spans(rnd, "chip_busy", family=family)
+    [readout] = _spans(rnd, "readout")
+    [assembly] = _spans(rnd, "assembly")
+    [dispatch] = _spans(rnd, "dispatch", family=family)
+    assert busy["parent"] == "readout" and busy["device"].startswith("cpu:")
+    assert busy["wall_s"] >= 0.0 and busy["cpu_s"] == 0.0
+    assert readout["start_s"] <= dispatch["start_s"] - 1e-6 <= busy["start_s"]
+    assert _end(busy) <= assembly["start_s"] + 1e-6
+    if family == "set":
+        # its stamp is the end of its own wait, not a second one
+        [wait] = _spans(rnd, "set_wait")
+        assert _end(busy) == pytest.approx(_end(wait), abs=1e-4)
+
+
+def test_chip_busy_spans_of_a_device_follow_each_other(flushed):
+    rnd = flushed["round"]
+    busy = _spans(rnd, "chip_busy")
+    assert [s["family"] for s in busy] == list(WATCHED)   # as dispatched
+    assert len({s["device"] for s in busy}) == 1
+    for before, after in zip(busy, busy[1:]):
+        assert _end(before) <= after["start_s"] + 1e-9
+    assert rnd["phases"]["chip_busy_s"] == pytest.approx(
+        sum(s["wall_s"] for s in busy), abs=1e-5)
+    # device seconds of one device cannot pass the wall they lie in
+    [readout] = _spans(rnd, "readout")
+    assert rnd["phases"]["chip_busy_s"] <= readout["wall_s"]
+
+
+def test_readout_kernel_row_is_fed_by_the_stamps(flushed):
+    """`device.kernel.readout_s` counts one time a family, device and
+    round (four rounds by the second scrape), `dispatches` one a family
+    and round, as it did."""
+    _first, second = flushed["scrapes"]
+    assert second["veneur_device_kernel_readout_s_count_total"] == \
+        4 * len(WATCHED)
+
+
+def _quiet_round(observatory: bool) -> dict:
+    """The last of three flushes of a server that nobody sends a set,
+    through `handle_metric_packet` (no listener, no sink)."""
+    cfg = generate_config(interval=60.0, device_observatory=observatory)
+    cfg.tpu.counter_capacity = cfg.tpu.histo_capacity = 512
+    server = Server(cfg)
+    try:
+        for round_no in range(3):
+            for i in range(40):
+                for line in (b"q.c%d:1|c", b"q.g%d:2|g", b"q.t%d:3|ms",
+                             b"q.l%d:4|l"):
+                    server.handle_metric_packet(line % i)
+            server.store.apply_all_pending()
+            server.flush()
+        return server.telemetry.flushes.snapshot()[-1]
+    finally:
+        server.shutdown()
+
+
+@pytest.fixture(scope="module")
+def quiet():
+    return {on: _quiet_round(on) for on in (True, False)}
+
+
+def test_idle_set_table_waits_in_sync_alone(quiet):
+    """No set key: the table closes none of its spans, and the flush
+    thread's wait for the chip is the `sync` spans'."""
+    rnd = quiet[True]
+    assert not [s for s in rnd["spans"] if s["name"] in SET_CHILDREN]
+    assert not {name + "_s" for name in SET_CHILDREN} & set(rnd["phases"])
+    assert rnd["phases"]["chip_wait_s"] == pytest.approx(
+        rnd["phases"]["sync_s"], abs=2e-6)
+    assert [s["family"] for s in _spans(rnd, "chip_busy")] == list(
+        WATCHED[:-1])
+
+
+def test_no_observatory_no_chip_busy_and_the_same_flush(quiet):
+    on, off = quiet[True], quiet[False]
+    assert not _spans(off, "chip_busy")
+    assert "chip_busy_s" not in off["phases"]
+    assert "chip_wait_s" in off["phases"]
+
+    def shape(rnd):
+        return sorted((s["name"], s["parent"], s.get("family"),
+                       s.get("device")) for s in rnd["spans"]
+                      if s["name"] != "chip_busy")
+
+    assert shape(on) == shape(off)
+    assert on["metrics_flushed"] == off["metrics_flushed"] > 0
+    assert set(on["phases"]) - set(off["phases"]) == {"chip_busy_s"}
 
 
 # -- (b) the spans form a tree on one clock --------------------------------

@@ -44,6 +44,8 @@ from veneur_tpu.parallel import collectives  # noqa: E402
 from veneur_tpu.sinks.channel import ChannelMetricSink  # noqa: E402
 from veneur_tpu.util import http as vhttp  # noqa: E402
 
+from test_flush_spans import (SET_CHILDREN, SWITCH_S, WATCHED,  # noqa: E402
+                              _end)
 from test_server import generate_config  # noqa: E402
 
 SHARDS = 4
@@ -506,6 +508,79 @@ def test_each_family_has_one_merge_span_under_its_dispatch(mesh):
             "counter", "gauge", "histogram", "llhist"}
 
 
+# ISSUE 39: the readout's completion stamps and `dispatch{set}` in parts
+# (the one-device server's are tests/test_flush_spans.py's)
+
+@pytest.mark.parametrize("shard", range(SHARDS))
+def test_each_device_has_a_stamp_a_family_in_dispatch_order(mesh, shard):
+    """20 completion stamps a round: the four merged families' handles
+    are replicated, and so is the estimate of the merged register bank
+    (every device computes it), so the sets' one wait stamps all four.
+    On each device the spans follow each other in the order the
+    families were dispatched, inside `readout`, closed before the
+    assembly starts."""
+    for r in mesh["rounds"]:
+        busy = [s for s in r["spans"] if s["name"] == "chip_busy"]
+        assert len(busy) == len(WATCHED) * SHARDS
+        devices = sorted({s["device"] for s in busy})
+        assert len(devices) == SHARDS
+        mine = [s for s in busy if s["device"] == devices[shard]]
+        assert [s["family"] for s in mine] == list(WATCHED)
+        [readout] = [s for s in r["spans"] if s["name"] == "readout"]
+        [assembly] = [s for s in r["spans"] if s["name"] == "assembly"]
+        assert all(s["parent"] == "readout" for s in mine)
+        assert readout["start_s"] <= mine[0]["start_s"]
+        for before, after in zip(mine, mine[1:]):
+            assert _end(before) <= after["start_s"] + 1e-9
+        assert _end(mine[-1]) <= assembly["start_s"] + 1e-6
+        assert r["phases"]["chip_busy_s"] == pytest.approx(
+            sum(s["wall_s"] for s in busy), abs=1e-5)
+        # device seconds, summed over the devices
+        assert r["phases"]["chip_busy_s"] <= SHARDS * readout["wall_s"]
+
+
+def test_dispatch_set_is_its_four_parts_and_the_merge(mesh):
+    for r in mesh["rounds"]:
+        [outer] = [s for s in r["spans"] if s["name"] == "dispatch"
+                   and s["family"] == "set"]
+        parts = sorted((s for s in r["spans"]
+                        if s["name"] in SET_CHILDREN + ("merge",)
+                        and s["family"] == "set"),
+                       key=lambda s: s["start_s"])
+        # the last pending batch routed and applied, the merge's own
+        # span, then the wait for the merged bank's estimate
+        assert [s["name"] for s in parts] == [
+            "set_fold", "merge", "set_wait", "set_transfer",
+            "set_host_estimate"]
+        assert all(s["parent"] == "dispatch" for s in parts)
+        assert outer["start_s"] <= parts[0]["start_s"] + 1e-6
+        assert _end(parts[-1]) <= _end(outer) + 1e-6
+        for before, after in zip(parts, parts[1:]):
+            assert _end(before) <= after["start_s"] + 1e-6
+        covered = sum(s["wall_s"] for s in parts)
+        assert outer["wall_s"] - covered <= SWITCH_S, (outer, parts)
+        p = r["phases"]
+        assert p["chip_wait_s"] == pytest.approx(
+            p["sync_s"] + p["set_wait_s"], abs=2e-6)
+
+
+def test_readout_kernel_row_counts_a_stamp_a_device(mesh):
+    before, after = mesh["scrapes"]
+    stamps0 = _rows(before, "veneur_device_kernel_readout_s_count_total")
+    stamps1 = _rows(after, "veneur_device_kernel_readout_s_count_total")
+    assert {k.split('family="')[1].split('"')[0] for k in stamps1} == set(
+        WATCHED)
+    per_family = {}
+    for r in mesh["all_rounds"]:
+        for s in r["spans"]:
+            if s["name"] == "chip_busy":
+                per_family[s["family"]] = per_family.get(s["family"], 0) + 1
+    for key, value in stamps1.items():
+        family = key.split('family="')[1].split('"')[0]
+        assert value - stamps0.get(key, 0.0) == per_family[family] \
+            >= SHARDS * FLUSHES, key
+
+
 def test_mesh_rows_are_on_metrics_and_rise(mesh):
     before, after = mesh["scrapes"]
     route0 = _rows(before, "veneur_ingest_shard_route_seconds_total")
@@ -547,7 +622,8 @@ def test_one_shard_server_has_no_merge_span_and_no_route_row(work):
 
 @pytest.mark.parametrize("metric", [
     "flush.merge_ms", "flush.shard_sync_ms", "ingest.shard_route_s",
-    "mesh.merge_rounds"])
+    "mesh.merge_rounds", "flush.chip_wait_ms", "flush.set_wait_ms",
+    "flush.set_host_ms", "flush.set_transfer_ms", "flush.readout_chip_ms"])
 def test_mesh_layer_metric_reads_something_the_program_produces(
         mesh, metric):
     """The benchmark's vocabulary for the four-shard cell, as

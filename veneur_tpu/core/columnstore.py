@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from veneur_tpu.core.telemetry import annotate
+from veneur_tpu.core.telemetry import FlushRound, annotate
 from veneur_tpu.ops import (batch_hll, batch_llhist, batch_tdigest,
                             device_scope, hll_ref, llhist_ref, scalars)
 from veneur_tpu.samplers import metrics as m
@@ -506,15 +506,19 @@ class _BaseTable:
         Touches no live table state beyond monotonic telemetry counters,
         so it needs no locks and may run concurrently with ingest.
         `timing` is the flush round whose `dispatch{family}` span the
-        call runs under: a sharded table hangs its `merge` span there
-        (core/sharded_tables.py); a one-device table has no use for it."""
+        call runs under, in the snap as `_timing` while the call lasts:
+        a sharded table hangs its `merge` span there
+        (core/sharded_tables.py), the set tables their `set_*` spans."""
         if "state" not in snap:
             return snap  # idle fast path: nothing was swapped
+        if timing is not None:
+            snap["_timing"] = timing
         state = snap.pop("state")
         cols = snap.pop("cols")
         if cols is not None:
             state = self._readout_apply(state, cols, snap)
         self._readout_device(state, snap)
+        snap.pop("_timing", None)
         return snap
 
     def _readout_apply(self, state, cols, snap: dict):
@@ -1873,65 +1877,121 @@ class SetTable(_BaseTable):
             state = jnp.copy(state)
         self._readout_device(state, snap)
 
+    # -- the readout's spans ---------------------------------------------
+    #
+    # `dispatch{set}` is the one dispatch span of the flush that waits
+    # for the chip, so it is split where it happens. Children of
+    # `dispatch`, `family="set"`, closed only where the table did the
+    # work (an idle table closes none):
+    #   set_fold           host: the last pending batch's apply, the COO
+    #                      concatenate, the slot lookup, the hot rows'
+    #                      apply_batch dispatches and the estimate's
+    #   set_wait           THE FLUSH THREAD BLOCKED ON THE CHIP, until
+    #                      the estimate is ready; a chip runs its stream
+    #                      in order, so also until every readout program
+    #                      dispatched before it has run
+    #   set_transfer       the ready estimate's copy to the host
+    #   set_host_estimate  host: the device rows' scatter, the sparse
+    #                      rows' LogLog-Beta and argsort, the provider
+
+    @staticmethod
+    def _set_phase(snap: dict, name: str):
+        # a readout nobody times (a hand-called snapshot_and_reset)
+        # gets a round of its own, as a sharded table's merge does
+        timing = snap.get("_timing") or FlushRound()
+        return timing.phase(name, parent="dispatch", family="set")
+
+    def _estimate_on_host(self, dev, snap: dict) -> np.ndarray:
+        """`np.asarray` of a dispatched estimate in its two halves, the
+        wait and the copy. The handle and the time it was ready go into
+        the snap (`_waited`): the family's completion stamp, for
+        `deviceobs`'s watcher."""
+        with self._set_phase(snap, "set_wait"):
+            jax.block_until_ready(dev)
+            snap["_waited"] = (dev, time.perf_counter())
+        with self._set_phase(snap, "set_transfer"):
+            return np.asarray(dev)
+
+    def _readout_apply(self, state, cols, snap: dict):
+        with self._set_phase(snap, "set_fold"):
+            return self._apply_cols_state(state, cols)
+
     def _readout_device(self, state, snap: dict) -> None:
         """Estimate + register-provider assembly over the captured
         generation. The register provider keeps a live device reference
         (lazy transfer), so the captured generation escapes into the
         snapshot and is NOT recycled."""
         if not self._sparse:
-            snap["estimates"] = np.asarray(batch_hll.estimate(state))
-            snap["registers"] = _SetRegisters.dense(state, self.capacity)
+            snap["estimates"] = self._estimate_on_host(
+                batch_hll.estimate(state), snap)
+            with self._set_phase(snap, "set_host_estimate"):
+                snap["registers"] = _SetRegisters.dense(state,
+                                                        self.capacity)
             return
         sparse = snap.pop("sparse")
         coo = sparse["coo"]
         slot_of = sparse["slot_of"]
         slot_row = sparse["slot_row"]
         nslots = sparse["nslots"]
+        if not coo and not nslots:
+            # idle: no sparse sample, no promoted row, nothing to wait
+            # for and no span
+            snap["estimates"] = np.zeros(self.capacity, np.float32)
+            snap["registers"] = _SetRegisters(
+                None, slot_of, *(np.zeros(0, np.int32),) * 3)
+            return
         # fold promoted rows' pre-promotion backlog into the device
         # table, then split the remaining COO per sparse row
-        if coo:
-            rows_all = np.concatenate([c[0] for c in coo])
-            idx_all = np.concatenate([c[1] for c in coo])
-            rho_all = np.concatenate([c[2] for c in coo])
-        else:
-            rows_all = np.zeros(0, np.int32)
-            idx_all = rho_all = rows_all
-        pslots = slot_of[rows_all] if rows_all.size else rows_all
-        hot = pslots >= 0
-        hot_slots = pslots[hot]
-        hot_idx, hot_rho = idx_all[hot], rho_all[hot]
-        for i in range(0, hot_slots.shape[0], self.batch_cap):
-            sl = slice(i, i + self.batch_cap)
-            chunk_rows = hot_slots[sl]
-            pad = self.batch_cap - chunk_rows.shape[0]
-            state = batch_hll.apply_batch(
-                state,
-                np.concatenate([chunk_rows,
-                                np.full(pad, PAD_ROW, np.int32)]),
-                np.concatenate([hot_idx[sl], np.zeros(pad, np.int32)]),
-                np.concatenate([hot_rho[sl], np.zeros(pad, np.int32)]))
+        with self._set_phase(snap, "set_fold"):
+            if coo:
+                rows_all = np.concatenate([c[0] for c in coo])
+                idx_all = np.concatenate([c[1] for c in coo])
+                rho_all = np.concatenate([c[2] for c in coo])
+            else:
+                rows_all = np.zeros(0, np.int32)
+                idx_all = rho_all = rows_all
+            pslots = slot_of[rows_all] if rows_all.size else rows_all
+            hot = pslots >= 0
+            hot_slots = pslots[hot]
+            hot_idx, hot_rho = idx_all[hot], rho_all[hot]
+            for i in range(0, hot_slots.shape[0], self.batch_cap):
+                sl = slice(i, i + self.batch_cap)
+                chunk_rows = hot_slots[sl]
+                pad = self.batch_cap - chunk_rows.shape[0]
+                state = batch_hll.apply_batch(
+                    state,
+                    np.concatenate([chunk_rows,
+                                    np.full(pad, PAD_ROW, np.int32)]),
+                    np.concatenate([hot_idx[sl], np.zeros(pad, np.int32)]),
+                    np.concatenate([hot_rho[sl], np.zeros(pad, np.int32)]))
 
-        estimates = np.zeros(self.capacity, np.float32)
-        dev_regs = None
+            estimates = np.zeros(self.capacity, np.float32)
+            dev_regs = None
+            dev = batch_hll.estimate(state) if nslots else None
         if nslots:
-            dev_est = np.asarray(batch_hll.estimate(state))
+            dev_est = self._estimate_on_host(dev, snap)
             dev_regs = state  # device ref; _SetRegisters is lazy
-            estimates[np.asarray(slot_row, np.int64)] = dev_est[:nslots]
-        s_rows = rows_all[~hot]
-        s_idx, s_rho = idx_all[~hot], rho_all[~hot]
-        if s_rows.size:
-            urows, est = self._host_estimates(s_rows, s_idx, s_rho)
-            estimates[urows] = est
-            order = np.argsort(s_rows, kind="stable")
-            s_rows, s_idx, s_rho = (s_rows[order], s_idx[order],
-                                    s_rho[order])
-        snap["estimates"] = estimates
-        snap["registers"] = _SetRegisters(dev_regs, slot_of, s_rows,
-                                          s_idx, s_rho)
+        with self._set_phase(snap, "set_host_estimate"):
+            if nslots:
+                estimates[np.asarray(slot_row, np.int64)] = dev_est[:nslots]
+            s_rows = rows_all[~hot]
+            s_idx, s_rho = idx_all[~hot], rho_all[~hot]
+            if s_rows.size:
+                urows, est = self._host_estimates(s_rows, s_idx, s_rho)
+                estimates[urows] = est
+                order = np.argsort(s_rows, kind="stable")
+                s_rows, s_idx, s_rho = (s_rows[order], s_idx[order],
+                                        s_rho[order])
+            snap["estimates"] = estimates
+            snap["registers"] = _SetRegisters(dev_regs, slot_of, s_rows,
+                                              s_idx, s_rho)
 
     def snapshot_begin(self) -> dict:
-        """Dispatch half: swap + estimate readout (the estimate is
-        realized eagerly — the set families are host-dominant)."""
+        """Dispatch half: swap + estimate readout. Unlike every other
+        family's, this one WAITS FOR THE CHIP: the estimate of the
+        promoted rows is realized on the host here, under the `set_wait`
+        and `set_transfer` spans, because the sparse rows' estimates are
+        scattered into the same array."""
         return self.readout(self.swap_out())
 
     @staticmethod

@@ -22,11 +22,17 @@ for it, three planes wired through the existing registries:
   device watermark rung (`overload_device_soft_bytes` /
   `_hard_bytes`) beside the RSS rung.
 - **Kernel registry** — the jitted apply / readout / merge / reset /
-  prewarm kernels register dispatch counts and wall time into
+  prewarm kernels register dispatch counts and a time into
   per-(kind, family) LatencyHist rows (`device.kernel.*`), plus
   compile/retrace counts generalizing the PR-10/15 compile-cache probe
   beyond the resize hook: prewarm-rung compiles and post-resize
-  retraces land in the same `device.compile.*` counters.
+  retraces land in the same `device.compile.*` counters. Which time:
+  `device.kernel.readout_s` holds the CHIP's, from the completion
+  stamps of `_ReadoutWatcher` (one `chip_busy{family,device}` span a
+  family and device in the flush round); `apply_s`, `merge_s`,
+  `reset_s` and `prewarm_s` hold the HOST wall of an asynchronous
+  dispatch (`merge_s`: of the `merge{family}` span, stacking
+  included), which says nothing of how long the chip took.
 - **Shard-balance observatory** — computed at scrape time from the
   attached store's digest-routed tables: per-shard live rows and
   samples-routed, a digest-space occupancy histogram, the skew ratio
@@ -44,13 +50,18 @@ kernel table + balance report is served at ``GET /debug/device``.
 
 from __future__ import annotations
 
+import logging
 import threading
 import time
+import weakref
+from collections import deque
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
 from veneur_tpu.core.latency import LatencyHist
+
+logger = logging.getLogger("veneur_tpu.deviceobs")
 
 # lifecycle states a generation token may carry
 STATE_LIVE = "live"
@@ -65,7 +76,9 @@ _STATES = (STATE_LIVE, STATE_SPARE, STATE_INFLIGHT, STATE_PREWARM,
 # kernel kinds the registry tracks; each timed kind renders a
 # `device.kernel.<kind>_s` llhist series (p50/p99/max gauges + count
 # counter). Listed literally so scripts/check_metric_names.py can lint
-# the expanded names against the README inventory.
+# the expanded names against the README inventory. `readout_s` is the
+# chip's time per family and device, from completion stamps
+# (`_ReadoutWatcher`); the others are host walls of a dispatch.
 KERNEL_KINDS = ("apply", "readout", "merge", "reset", "prewarm")
 HIST_ROWS = ("device.kernel.apply_s", "device.kernel.readout_s",
              "device.kernel.merge_s", "device.kernel.reset_s",
@@ -136,6 +149,104 @@ class _Token:
         self.shard = shard
 
 
+class _ReadoutWatcher:
+    """Completion stamps for a flush's readout programs: the kernel
+    registry's device half. The flush thread hands over each family's
+    OUTPUT handles, grouped by device, as soon as it has dispatched the
+    family (`flusher.readout_columnstore`); this one daemon thread takes
+    them in that order, blocks until a device's handles are ready (the
+    GIL is released meanwhile), reads `perf_counter()` and closes a
+    `chip_busy{family,device}` span (parent `readout`) in the round:
+    from the later of that device's previous stamp and the family's
+    dispatch start, to the stamp. A family that waited for its own
+    output on the flush thread (the sets' `set_wait`) brings its stamp
+    along as `done_at` and is not waited for again. The span's wall also
+    feeds `device.kernel.readout_s{family}`.
+
+    What a stamp can and cannot say. A chip runs its stream in order, so
+    a span holds everything the device ran between two watched
+    completions: programs nobody watches (the dispatcher's live applies
+    and compacts during the flush, the merges, resets) are booked to the
+    next watched program of that device. One watcher waits for the
+    devices of a family in turn, so a device that finished before the
+    one ahead of it reads late by the difference; and a stamp read while
+    the flush thread holds the GIL is late by up to the interpreter's
+    switch interval. Only outputs are held, never a state that a later
+    program donates before `join` has returned."""
+
+    JOIN_S = 5.0   # a wedged device: the flush goes on without stamps
+
+    def __init__(self, obs: "DeviceObservatory"):
+        self._obs = weakref.ref(obs)
+        self._cv = threading.Condition()
+        self._handed: deque = deque()
+        self._open = 0        # handed over and not yet stamped
+        self._closed = False
+        self._last: Dict[str, float] = {}   # device -> its last stamp
+        self.thread = threading.Thread(
+            target=self._run, name="readout-watcher", daemon=True)
+        self.thread.start()
+
+    def watch(self, rnd, family: str, by_device: Dict[str, list],
+              dispatched_at: float,
+              done_at: Optional[float] = None) -> None:
+        with self._cv:
+            self._handed.append((rnd, family, by_device, dispatched_at,
+                                 done_at))
+            self._open += 1
+            self._cv.notify_all()
+
+    def join(self) -> bool:
+        """Until everything handed over is stamped: a wake-up once the
+        flush thread has synced the same handles itself."""
+        with self._cv:
+            return self._cv.wait_for(lambda: not self._open, self.JOIN_S)
+
+    def close(self) -> None:
+        with self._cv:
+            self._closed = True
+            self._cv.notify_all()
+
+    def _run(self) -> None:
+        while True:
+            with self._cv:
+                self._cv.wait_for(lambda: self._handed or self._closed)
+                if not self._handed:
+                    return
+                item = self._handed.popleft()
+            try:
+                self._stamp(*item)
+            except Exception:
+                logger.exception("readout completion stamp failed")
+            finally:
+                del item   # the handles: nothing outlives its stamp
+                with self._cv:
+                    self._open -= 1
+                    self._cv.notify_all()
+
+    def _stamp(self, rnd, family, by_device, dispatched_at, done_at):
+        import jax
+
+        obs = self._obs()
+        first = True
+        for device, handles in by_device.items():
+            if device == "host":
+                continue
+            done = done_at
+            if done is None:
+                jax.block_until_ready(handles)
+                done = time.perf_counter()
+            start = max(self._last.get(device, 0.0), dispatched_at)
+            done = self._last[device] = max(done, start)
+            rnd.stamped("chip_busy", "readout", start, done,
+                        family=family, device=device)
+            if obs is not None:
+                # one dispatch a family and round, one time a device
+                obs.note_kernel("readout", family, done - start,
+                                n=int(first))
+            first = False
+
+
 class DeviceObservatory:
     """One server's (or standalone store's) device observatory.
 
@@ -160,6 +271,7 @@ class DeviceObservatory:
         # shard-balance plane reads the attached store at scrape time
         self._store = None
         self._resize_events = 0
+        self._watcher: Optional[_ReadoutWatcher] = None
 
     # ------------------------------------------------------------------
     # HBM ledger
@@ -259,9 +371,10 @@ class DeviceObservatory:
 
     def note_kernel(self, kind: str, family: str,
                     seconds: Optional[float] = None, n: int = 1) -> None:
-        """Record `n` dispatches of a jitted kernel; `seconds` (when the
-        caller timed the dispatch) feeds the `device.kernel.<kind>_s`
-        llhist for that family."""
+        """Record `n` dispatches of a jitted kernel; `seconds` feeds the
+        `device.kernel.<kind>_s` llhist for that family: the chip's
+        time from a completion stamp for `readout`, the caller's host
+        wall of the dispatch for every other kind."""
         if not self.enabled:
             return
         key = (kind, family)
@@ -274,6 +387,25 @@ class DeviceObservatory:
                         f"device.kernel.{kind}_s")
         if seconds is not None:
             hist.observe(seconds)
+
+    def readout_watcher(self) -> Optional[_ReadoutWatcher]:
+        """The completion watcher of the flush's readout programs,
+        started with the first flush that asks; None when disabled."""
+        if not self.enabled:
+            return None
+        with self._lock:
+            if self._watcher is None:
+                self._watcher = _ReadoutWatcher(self)
+                weakref.finalize(self, self._watcher.close)
+            return self._watcher
+
+    def close(self) -> None:
+        """Stop the watcher's thread (`Server.shutdown`); the next
+        flush, if one comes, starts another."""
+        with self._lock:
+            watcher, self._watcher = self._watcher, None
+        if watcher is not None:
+            watcher.close()
 
     def note_compile(self, family: str,
                      seconds: Optional[float] = None) -> None:

@@ -18,7 +18,6 @@ Semantic parity with reference flusher.go:26-122 and samplers.go:359-514:
 
 from __future__ import annotations
 
-import contextlib
 import math
 import threading
 import time
@@ -689,7 +688,22 @@ def readout_columnstore(
     a server (`latency_observatory: true`, `Server._run_readout`), and
     `latency.family_tree` builds the round's `families` tree from its
     spans. Without it everything still on the device is drained in one
-    `sync`: the path no benchmark cell has measured."""
+    `sync`: the path no benchmark cell has measured.
+
+    Where the chip's time shows. Every family's dispatch is asynchronous
+    but the sets': `SetTable._readout_device` needs the estimate on the
+    host to fill in the rows the device does not hold, so it blocks for
+    it inside `dispatch{set}`, under a `set_wait` span of its own; a
+    chip runs its stream in order, so that wait covers every readout
+    program dispatched before it, and the `sync` spans that follow find
+    an empty queue. In a deployment that sends no sets they are where
+    the flush thread waits. `chip_wait_s` (`Server._flush_locked`) is
+    the two together. With a device observatory each family's output
+    handles go to its completion watcher at the end of the family's
+    dispatch; it closes one `chip_busy{family,device}` span a family and
+    device (what the device ran up to that family's completion:
+    `deviceobs._ReadoutWatcher` says what such a span books to whom) and
+    is joined once `device_sync` has ended, before the assembly starts."""
     import jax
 
     timing = timing or FlushRound()
@@ -704,49 +718,64 @@ def readout_columnstore(
     local_code = int(MetricScope.LOCAL_ONLY)
     global_code = int(MetricScope.GLOBAL_ONLY)
     deviceobs = getattr(store, "deviceobs", None)
+    watcher = deviceobs.readout_watcher() if deviceobs is not None else None
+    # family -> its output handles by device, grouped once: what the
+    # watcher waits for and what the attributed path syncs
+    by_device: Dict[str, Dict[str, list]] = {}
 
-    @contextlib.contextmanager
     def dispatching(family: str):
         """One family's dispatch span."""
-        with timing.phase("dispatch", parent="readout",
-                          family=family) as span:
-            yield
-        if deviceobs is not None and family != "status":
-            # kernel-registry row: the waterfall's per-family dispatch_s
-            # decomposed as a device.kernel.readout_s distribution
-            deviceobs.note_kernel("readout", family, span["wall_s"])
+        return timing.phase("dispatch", parent="readout", family=family)
+
+    def dispatched(family: str, span: dict, handles: list,
+                   done_at: Optional[float] = None) -> list:
+        """The outputs of a family whose dispatch span has closed, on
+        their way to the completion watcher; `done_at` where the family
+        waited for them itself."""
+        if watcher is not None or attribute:
+            by_device[family] = _handles_by_device(handles)
+        if watcher is not None and by_device[family]:
+            watcher.watch(timing, family, by_device[family],
+                          timing.t0 + span["start_s"], done_at)
+        return handles
 
     # ---- phase 1: dispatch every device flush, sync nothing ------------
     # (the per-family dispatch spans are back-to-back, so their sum IS
     # the dispatch_s total)
-    with dispatching("histogram"):
+    with dispatching("histogram") as span:
         h_snap = store.histos.readout(swap["histogram"], timing)
-    with dispatching("counter"):
+    h_handles = dispatched("histogram", span, [
+        h for h in (h_snap["packed"], h_snap["export_packed"])
+        if h is not None])
+    with dispatching("counter") as span:
         c_snap = store.counters.readout(swap["counter"], timing)
-    with dispatching("gauge"):
+    c_handles = dispatched("counter", span, list(c_snap["dev"]))
+    with dispatching("gauge") as span:
         g_snap = store.gauges.readout(swap["gauge"], timing)
-    with dispatching("llhist"):
+    g_handles = dispatched("gauge", span, [g_snap["dev"]])
+    with dispatching("llhist") as span:
         ll_snap = store.llhists.readout(swap["llhist"], timing)
-    # sets are host-dominant (the sparse set path only touches the
-    # device when rows promoted this interval): the estimate realizes
-    # eagerly inside readout
-    with dispatching("set"):
+    ll_handles = dispatched("llhist", span, [
+        h for h in (ll_snap["packed"], ll_snap["bins_dev"])
+        if h is not None])
+    # the one family that waits for the chip inside its dispatch: the
+    # estimate is realized under `set_wait` (see above), and the stamp
+    # of that wait is the family's completion
+    with dispatching("set") as span:
         set_snap = store.sets.readout(swap["set"], timing)
         estimates, registers, s_touched, s_meta = \
             store.sets.snapshot_finish(set_snap)
+    waited = set_snap.pop("_waited", None)
+    if watcher is not None and waited is not None:
+        dispatched("set", span, [waited[0]], waited[1])
     with dispatching("status"):
         st_vals, st_touched, st_meta = swap["status"]
 
     # ---- phase 2: drain the device queue, then transfer ----------------
-    h_handles = [h_snap["packed"]]
-    if h_snap["export_packed"] is not None:
-        h_handles.append(h_snap["export_packed"])
-    ll_handles = [x for x in (ll_snap["packed"], ll_snap["bins_dev"])
-                  if x is not None]
     family_finishes = (
-        ("counter", [c_snap["dev"][0], c_snap["dev"][1]],
+        ("counter", c_handles,
          lambda: store.counters.snapshot_finish(c_snap)),
-        ("gauge", [g_snap["dev"]],
+        ("gauge", g_handles,
          lambda: store.gauges.snapshot_finish(g_snap)),
         ("histogram", h_handles,
          lambda: store.histos.snapshot_finish(h_snap)),
@@ -754,11 +783,6 @@ def readout_columnstore(
          lambda: store.llhists.snapshot_finish(ll_snap)),
     )
     finished = {}
-    # the attributed path syncs each family's handles device by device;
-    # grouping them is host work, done before the clock of device_sync
-    by_device = ({family: _handles_by_device(handles)
-                  for family, handles, _fn in family_finishes}
-                 if attribute else {})
     with timing.phase("device_sync", parent="readout"):
         if not attribute:
             # one queue drain for everything still on device
@@ -774,6 +798,11 @@ def readout_columnstore(
             with timing.phase("transfer", parent="device_sync",
                               family=family):
                 finished[family] = finish()
+    if watcher is not None:
+        # every handle is ready: a wake-up, after which the round holds
+        # its `chip_busy` spans (outside `device_sync`, which stays the
+        # sum of its `sync` and `transfer` spans)
+        watcher.join()
     c_vals, c_touched, c_meta = finished["counter"]
     g_vals, g_touched, g_meta = finished["gauge"]
     out, export, h_touched, h_meta = finished["histogram"]

@@ -450,8 +450,10 @@ class LatencyObservatory:
 def family_tree(readout) -> dict:
     """One readout's per-family segment tree, from its spans
     (`telemetry.FlushRound`, as `flusher.readout_columnstore` fills it
-    with `attribute`): per family the `dispatch` span, the `sync` span
-    of each device and the `transfer` span, and the wall-clock window
+    with `attribute`): per family the `dispatch` span, the `sync` and
+    `chip_busy` spans of each device, the `transfer` span (and what
+    else carries the family: `merge`, the sets' `set_*`), and the
+    wall-clock window
     from the start of its dispatch to the end of its transfer
     (`start_unix`, `end_unix`: what the `flush.family` SSF span is
     stamped with)."""
@@ -466,8 +468,9 @@ def family_tree(readout) -> dict:
             "start_unix": start, "end_unix": start})
         rec["start_unix"] = min(rec["start_unix"], start)
         rec["end_unix"] = max(rec["end_unix"], start + span["wall_s"])
-        if span["name"] == "sync":
-            rec["devices"][span["device"]] = {"sync_s": span["wall_s"]}
+        if "device" in span:
+            rec["devices"].setdefault(span["device"], {})[
+                span["name"] + "_s"] = span["wall_s"]
         else:
             rec[span["name"] + "_s"] = span["wall_s"]
     return tree

@@ -1419,6 +1419,8 @@ class Server:
             self.flush()
         if self._flush_executor is not None:
             self._flush_executor.stop()
+        # the last flush is done: the readout's completion watcher goes
+        self.deviceobs.close()
         if self.prewarmer is not None:
             self.prewarmer.stop()
         if self.import_server is not None:
@@ -1873,6 +1875,10 @@ class Server:
         flush_span.finish()
         duration = flush_phase.stop()["wall_s"]
         phases["flush_cpu_s"] = rnd.cpu_s()
+        # every span in which the flush thread stood still for the chip:
+        # the `sync` spans and, where the sets' estimate ran, `set_wait`
+        phases["chip_wait_s"] = (phases.get("sync_s", 0.0)
+                                 + phases.get("set_wait_s", 0.0))
         self.statsd.gauge("flush.total_duration_ns", int(duration * 1e9))
         self.statsd.timing("flush.total_duration", duration)
         for phase, secs in phases.items():

@@ -166,9 +166,11 @@ class _DigestRouted:
         """One collective merge of a flush readout: a `merge{family}`
         span of the round the readout runs for, child of its
         `dispatch{family}`; the `device.kernel.merge_s` row is fed from
-        the span's own clock. A readout nobody times (a hand-called
+        the span's own clock, so it holds a HOST wall (stacking the
+        per-device states and dispatching the merge), not the chip's
+        time. A readout nobody times (a hand-called
         `snapshot_and_reset`) gets a round of its own."""
-        timing = snap.pop("_timing", None) or FlushRound()
+        timing = snap.get("_timing") or FlushRound()
         with timing.phase("merge", parent="dispatch",
                           family=self.family) as span:
             yield
@@ -176,11 +178,6 @@ class _DigestRouted:
         if obs is not None:
             obs.note_kernel("merge", self.family, span["wall_s"])
         self._plane.note_merge_round()
-
-    def readout(self, snap: dict, timing=None) -> dict:
-        if timing is not None and "state" in snap:
-            snap["_timing"] = timing
-        return super().readout(snap)
 
     # -- elastic resharding (parallel/reshard.py) ------------------------
 
@@ -930,7 +927,8 @@ class ShardedSetTable(_PerDeviceStates, _DigestRouted, SetTable):
         self._apply_cols_states(self.states, cols)
 
     def _readout_apply(self, states, cols, snap: dict):
-        self._apply_cols_states(states, cols)
+        with self._set_phase(snap, "set_fold"):
+            self._apply_cols_states(states, cols)
         return states
 
     def merge_batch(self, stubs, in_regs) -> None:
@@ -966,15 +964,21 @@ class ShardedSetTable(_PerDeviceStates, _DigestRouted, SetTable):
         return collectives.merge_hll_stacked(stacked)
 
     def _readout_device(self, states, snap: dict) -> None:
+        """The spans of `SetTable`'s readout, the merge's own between
+        them: `set_fold` (the last pending batch, routed and applied
+        per shard), `merge`, then `set_wait` for the estimate of the
+        merged bank, which every device computes."""
         with self._merging(snap):
             merged = self._merged_state(states, note=False)
-        snap["estimates"] = np.asarray(batch_hll.estimate(merged))
+        snap["estimates"] = self._estimate_on_host(
+            batch_hll.estimate(merged), snap)
         # lazy per-row provider (columnstore._SetRegisters): the
         # merged (K, M) bank only crosses the device link if a
         # consumer (the forward exporter) actually reads registers.
         # The provider references the MERGED bank, so the drained
         # per-device generations are recyclable.
-        snap["registers"] = _SetRegisters.dense(merged, self.capacity)
+        with self._set_phase(snap, "set_host_estimate"):
+            snap["registers"] = _SetRegisters.dense(merged, self.capacity)
         snap["_recycle"] = states
 
     def warm_programs(self, ps, need_export):

@@ -23,7 +23,9 @@ Three pieces, all thread-safe and all O(1)-bounded:
   times an interval once and feeds three outputs: the round's `phases`
   totals, its `spans` list, and a `veneur/<name>` annotation on the
   profiler's host plane (`annotate` is the same annotation alone, for
-  the ingest path, which belongs to no round).
+  the ingest path, which belongs to no round). `round.stamped(...)`
+  files an interval somebody else timed (the chip's, between two
+  completion stamps) into the first two.
 """
 
 from __future__ import annotations
@@ -498,6 +500,16 @@ class FlushRound:
     def phase(self, name: str, parent: Optional[str] = None,
               **tags) -> _Phase:
         return _Phase(self, {"name": name, "parent": parent, **tags})
+
+    def stamped(self, name: str, parent: Optional[str], t0: float,
+                t1: float, **tags) -> dict:
+        """A span whose two ends somebody else read on `perf_counter`
+        (`deviceobs`'s completion watcher: an interval of the chip's,
+        not of a thread's). Wall time only, like a hand-off phase."""
+        rec = {"name": name, "parent": parent,
+               "thread": threading.current_thread().name, **tags}
+        self._close(rec, t0, t1 - t0, 0.0)
+        return rec
 
     def _close(self, rec: dict, t0: float, wall_s: float,
                cpu_s: float) -> None:
