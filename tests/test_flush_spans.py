@@ -374,20 +374,27 @@ def _end(span: dict) -> float:
 @pytest.mark.parametrize("name", SET_CHILDREN)
 def test_dispatch_set_has_the_child(flushed, name):
     """The round's set table held promoted rows and sparse ones, so all
-    four parts of `dispatch{set}` are there, inside it (the fold a
-    second time where a last pending batch was applied)."""
+    four parts of the sets' readout are there, on the flush thread: the
+    fold inside `dispatch{set}` (a second time where a last pending
+    batch was applied), the wait, the copy and the host estimate inside
+    `assembly_set`, where the estimate is collected (ISSUE 40)."""
     rnd = flushed["round"]
-    [outer] = _spans(rnd, "dispatch", family="set")
+    [dispatch] = _spans(rnd, "dispatch", family="set")
+    parent = "dispatch" if name == "set_fold" else "assembly_set"
+    [outer] = _spans(rnd, parent, **({"family": "set"}
+                                     if parent == "dispatch" else {}))
     children = _spans(rnd, name)
     assert 1 <= len(children) <= 1 + (name == "set_fold")
     for child in children:
-        assert child["parent"] == "dispatch" and child["family"] == "set"
-        assert child["thread"] == outer["thread"]
+        assert child["parent"] == parent and child["family"] == "set"
+        assert child["thread"] == outer["thread"] == dispatch["thread"]
         assert outer["start_s"] - 1e-6 <= child["start_s"]
         assert _end(child) <= _end(outer) + 1e-6
 
 
 def test_set_children_follow_each_other_and_make_up_dispatch_set(flushed):
+    """The four parts follow each other; the fold makes up
+    `dispatch{set}` (the estimate's dispatch is its last step)."""
     rnd = flushed["round"]
     [outer] = _spans(rnd, "dispatch", family="set")
     parts = sorted((s for name in SET_CHILDREN for s in _spans(rnd, name)),
@@ -395,11 +402,34 @@ def test_set_children_follow_each_other_and_make_up_dispatch_set(flushed):
     assert [s["name"] for s in parts][-4:] == list(SET_CHILDREN)
     for before, after in zip(parts, parts[1:]):
         assert _end(before) <= after["start_s"] + 1e-6
-    covered = sum(s["wall_s"] for s in parts)
-    assert covered == pytest.approx(
+    assert sum(s["wall_s"] for s in parts) == pytest.approx(
         sum(rnd["phases"][name + "_s"] for name in SET_CHILDREN), abs=1e-5)
-    assert covered <= outer["wall_s"] + 1e-6
-    assert outer["wall_s"] - covered <= SWITCH_S, (outer, parts)
+    folds = sum(s["wall_s"] for s in _spans(rnd, "set_fold"))
+    assert folds <= outer["wall_s"] + 1e-6
+    assert outer["wall_s"] - folds <= SWITCH_S, (outer, parts)
+
+
+def test_dispatch_set_waits_for_nothing_and_the_join_follows_the_sets(
+        flushed):
+    """No `set_wait` lies inside `dispatch{set}`: the estimate is
+    collected in `assembly_set`, after the scalars' and histograms'
+    assembly; the watcher is joined after that, before the drained
+    generations are recycled (the last span of the assembly)."""
+    rnd = flushed["round"]
+    [dispatch] = _spans(rnd, "dispatch", family="set")
+    [wait] = _spans(rnd, "set_wait")
+    [assembly] = _spans(rnd, "assembly")
+    [collected] = _spans(rnd, "assembly_set")
+    [recycle] = _spans(rnd, "recycle")
+    assert wait["start_s"] >= _end(dispatch)
+    for before in ("assembly_scalar", "assembly_histogram"):
+        [block] = _spans(rnd, before)
+        assert _end(block) <= collected["start_s"] + 1e-6
+    blocks = [s for s in rnd["spans"] if s["parent"] == "assembly"]
+    assert max(blocks, key=lambda s: s["start_s"]) is recycle
+    assert _end(recycle) <= _end(assembly) + 1e-6
+    for busy in _spans(rnd, "chip_busy"):
+        assert _end(busy) <= recycle["start_s"] + 1e-6, busy
 
 
 def test_chip_wait_is_every_sync_and_the_sets_wait(flushed):
@@ -411,22 +441,17 @@ def test_chip_wait_is_every_sync_and_the_sets_wait(flushed):
 
 @pytest.mark.parametrize("family", WATCHED)
 def test_one_chip_busy_span_per_family_and_device(flushed, family):
-    """One device here, so one completion stamp a family: inside
-    `readout`, closed before the assembly starts, not before the
-    family was dispatched."""
+    """One device here, so one completion stamp a family, the sets'
+    too, from the watcher: inside `readout`, not before the family was
+    dispatched, closed before the readout ends."""
     rnd = flushed["round"]
     [busy] = _spans(rnd, "chip_busy", family=family)
     [readout] = _spans(rnd, "readout")
-    [assembly] = _spans(rnd, "assembly")
     [dispatch] = _spans(rnd, "dispatch", family=family)
     assert busy["parent"] == "readout" and busy["device"].startswith("cpu:")
     assert busy["wall_s"] >= 0.0 and busy["cpu_s"] == 0.0
     assert readout["start_s"] <= dispatch["start_s"] - 1e-6 <= busy["start_s"]
-    assert _end(busy) <= assembly["start_s"] + 1e-6
-    if family == "set":
-        # its stamp is the end of its own wait, not a second one
-        [wait] = _spans(rnd, "set_wait")
-        assert _end(busy) == pytest.approx(_end(wait), abs=1e-4)
+    assert _end(busy) <= _end(readout) + 1e-6
 
 
 def test_chip_busy_spans_of_a_device_follow_each_other(flushed):
@@ -454,7 +479,8 @@ def test_readout_kernel_row_is_fed_by_the_stamps(flushed):
 
 def _quiet_round(observatory: bool) -> dict:
     """The last of three flushes of a server that nobody sends a set,
-    through `handle_metric_packet` (no listener, no sink)."""
+    through `handle_metric_packet` (no listener, no sink), with the set
+    table's count of deferred estimates beside it."""
     cfg = generate_config(interval=60.0, device_observatory=observatory)
     cfg.tpu.counter_capacity = cfg.tpu.histo_capacity = 512
     server = Server(cfg)
@@ -466,7 +492,9 @@ def _quiet_round(observatory: bool) -> dict:
                     server.handle_metric_packet(line % i)
             server.store.apply_all_pending()
             server.flush()
-        return server.telemetry.flushes.snapshot()[-1]
+        return dict(server.telemetry.flushes.snapshot()[-1],
+                    deferred_estimates=(
+                        server.store.sets.deferred_estimates_total))
     finally:
         server.shutdown()
 
@@ -488,6 +516,18 @@ def test_idle_set_table_waits_in_sync_alone(quiet):
         WATCHED[:-1])
 
 
+def test_deferred_estimates_count_the_flushes_with_device_set_rows(
+        flushed, quiet):
+    """`flush.set.deferred_estimates_total`: one a flush whose set table
+    held promoted rows (every one of the four here), none where nobody
+    sends a set."""
+    row = "veneur_flush_set_deferred_estimates_total"
+    first, second = flushed["scrapes"]
+    assert (first[row], second[row]) == (3, 4)
+    assert quiet[True]["deferred_estimates"] == 0
+    assert quiet[False]["deferred_estimates"] == 0
+
+
 def test_no_observatory_no_chip_busy_and_the_same_flush(quiet):
     on, off = quiet[True], quiet[False]
     assert not _spans(off, "chip_busy")
@@ -502,6 +542,104 @@ def test_no_observatory_no_chip_busy_and_the_same_flush(quiet):
     assert shape(on) == shape(off)
     assert on["metrics_flushed"] == off["metrics_flushed"] > 0
     assert set(on["phases"]) - set(off["phases"]) == {"chip_busy_s"}
+
+
+def _set_round_store():
+    """A store whose one round holds promoted set rows (past the
+    promotion threshold, with a pre-promotion backlog to fold) and
+    sparse ones, of both scopes, beside the other families: a local
+    server emits the local-only sets' estimates and forwards the
+    others' registers."""
+    from veneur_tpu.core.columnstore import ColumnStore
+    from veneur_tpu.samplers.parser import Parser
+
+    store = ColumnStore(counter_capacity=64, gauge_capacity=64,
+                        histo_capacity=64, set_capacity=64, batch_cap=128)
+    store.sets._promote_samples = PROMOTE_SAMPLES
+    lines = []
+    for i in range(6):
+        scope = b"" if i % 2 else b"|#veneurlocalonly"
+        lines += [b"hot.%d:m%d|s%s" % (i, j, scope)
+                  for j in range(3 * PROMOTE_SAMPLES + i)]
+        lines += [b"cold.%d:m%d|s%s" % (i, j, scope) for j in range(2)]
+        lines += [b"c.%d:1|c" % i, b"t.%d:%d|ms" % (i, i), b"l.%d:2|l" % i]
+    parser = Parser()
+    for line in lines:
+        parser.parse_metric_fast(line, store.process)
+    store.apply_all_pending()
+    return store
+
+
+def test_deferred_set_readout_flushes_what_the_eager_one_does(monkeypatch):
+    """The columnar flush collects the sets' estimate in `assembly_set`;
+    with `SetTable.readout` forced to collect at once (as every snapshot
+    and live read does) the same round gives the same FlushBatch:
+    sections in the same order, the same names, values, tags and types,
+    and the same forwarded registers."""
+    from veneur_tpu.core.flusher import flush_columnstore_batch
+    from veneur_tpu.samplers.metrics import HistogramAggregates
+
+    aggs = HistogramAggregates.from_names(["min", "max", "count"])
+    deferred, eager = _set_round_store(), _set_round_store()
+    plain = eager.sets.readout
+    monkeypatch.setattr(eager.sets, "readout",
+                        lambda snap, timing=None, collect=True:
+                        plain(snap, timing))
+    rounds = (FlushRound(), FlushRound())
+    (got, got_fwd), (want, want_fwd) = (
+        flush_columnstore_batch(store, True, (0.5, 0.99), aggs,
+                                collect_forward=True, timing=rnd)
+        for store, rnd in zip((deferred, eager), rounds))
+    # both paths ran, and waited for promoted rows' estimate
+    assert [s["parent"] for s in rounds[0].spans
+            if s["name"] == "set_wait"] == ["assembly_set"]
+    assert [s["parent"] for s in rounds[1].spans
+            if s["name"] == "set_wait"] == ["dispatch"]
+    assert (deferred.sets.deferred_estimates_total,
+            eager.sets.deferred_estimates_total) == (1, 0)
+    assert len(got.sections) == len(want.sections) > 0
+    for a, b in zip(got.sections, want.sections):
+        assert a.mtype == b.mtype
+        np.testing.assert_array_equal(a.names, b.names)
+        np.testing.assert_array_equal(a.values, b.values)
+        assert list(a.tags) == list(b.tags)
+    estimates = [v for a in got.sections for n, v in zip(a.names, a.values)
+                 if str(n).startswith(("hot.", "cold."))]
+    assert len(estimates) == 6 and min(estimates) >= 2
+    assert [m.name for m, _ in got_fwd.sets] == [
+        m.name for m, _ in want_fwd.sets]
+    assert len(got_fwd.sets) == 6
+    for (_, a), (_, b) in zip(got_fwd.sets, want_fwd.sets):
+        np.testing.assert_array_equal(a, b)
+        assert a.any()
+
+
+def test_an_assembly_that_raises_still_recycles_every_family(monkeypatch):
+    """The drained generations go back as spares even where the
+    assembly fails, after the sets' collect would have run: the
+    recycle span closes and every family's snap is recycled once."""
+    from veneur_tpu.core.flusher import flush_columnstore_batch
+    from veneur_tpu.samplers.metrics import HistogramAggregates
+
+    store = _set_round_store()
+    recycled = []
+    for family in ("counters", "gauges", "histos", "llhists", "sets"):
+        table = getattr(store, family)
+        monkeypatch.setattr(table, "recycle",
+                            lambda snap, f=family: recycled.append(f))
+
+    def broken(snap, *args, **kwargs):
+        raise RuntimeError("assembly failed")
+
+    monkeypatch.setattr(store.sets, "collect", broken)
+    rnd = FlushRound()
+    with pytest.raises(RuntimeError, match="assembly failed"):
+        flush_columnstore_batch(
+            store, True, (0.5,), HistogramAggregates.from_names(["max"]),
+            timing=rnd)
+    assert recycled == ["counters", "gauges", "histos", "llhists", "sets"]
+    assert [s["parent"] for s in rnd.spans
+            if s["name"] == "recycle"] == ["assembly"]
 
 
 # -- (b) the spans form a tree on one clock --------------------------------

@@ -518,7 +518,8 @@ def test_each_device_has_a_stamp_a_family_in_dispatch_order(mesh, shard):
     (every device computes it), so the sets' one wait stamps all four.
     On each device the spans follow each other in the order the
     families were dispatched, inside `readout`, closed before the
-    assembly starts."""
+    drained generations are recycled (ISSUE 40: the watcher is joined
+    once the sets' estimate has been collected in `assembly_set`)."""
     for r in mesh["rounds"]:
         busy = [s for s in r["spans"] if s["name"] == "chip_busy"]
         assert len(busy) == len(WATCHED) * SHARDS
@@ -527,12 +528,12 @@ def test_each_device_has_a_stamp_a_family_in_dispatch_order(mesh, shard):
         mine = [s for s in busy if s["device"] == devices[shard]]
         assert [s["family"] for s in mine] == list(WATCHED)
         [readout] = [s for s in r["spans"] if s["name"] == "readout"]
-        [assembly] = [s for s in r["spans"] if s["name"] == "assembly"]
+        [recycle] = [s for s in r["spans"] if s["name"] == "recycle"]
         assert all(s["parent"] == "readout" for s in mine)
         assert readout["start_s"] <= mine[0]["start_s"]
         for before, after in zip(mine, mine[1:]):
             assert _end(before) <= after["start_s"] + 1e-9
-        assert _end(mine[-1]) <= assembly["start_s"] + 1e-6
+        assert _end(mine[-1]) <= recycle["start_s"] + 1e-6
         assert r["phases"]["chip_busy_s"] == pytest.approx(
             sum(s["wall_s"] for s in busy), abs=1e-5)
         # device seconds, summed over the devices
@@ -540,24 +541,36 @@ def test_each_device_has_a_stamp_a_family_in_dispatch_order(mesh, shard):
 
 
 def test_dispatch_set_is_its_four_parts_and_the_merge(mesh):
+    """`dispatch{set}` is the routed fold, the merge and the fold's last
+    step, the merged bank's estimate dispatched; the wait for that
+    estimate, its copy and the provider follow in `assembly_set`, and
+    only then are the per-device states, the merge's inputs, recycled
+    (ISSUE 40)."""
     for r in mesh["rounds"]:
         [outer] = [s for s in r["spans"] if s["name"] == "dispatch"
                    and s["family"] == "set"]
+        [collected] = [s for s in r["spans"] if s["name"] == "assembly_set"]
+        [recycle] = [s for s in r["spans"] if s["name"] == "recycle"]
         parts = sorted((s for s in r["spans"]
                         if s["name"] in SET_CHILDREN + ("merge",)
                         and s["family"] == "set"),
                        key=lambda s: s["start_s"])
         # the last pending batch routed and applied, the merge's own
-        # span, then the wait for the merged bank's estimate
+        # span, the estimate's dispatch, then the wait for it
         assert [s["name"] for s in parts] == [
-            "set_fold", "merge", "set_wait", "set_transfer",
+            "set_fold", "merge", "set_fold", "set_wait", "set_transfer",
             "set_host_estimate"]
-        assert all(s["parent"] == "dispatch" for s in parts)
+        assert [s["parent"] for s in parts] == [
+            "dispatch", "dispatch", "dispatch", "assembly_set",
+            "assembly_set", "assembly_set"]
         assert outer["start_s"] <= parts[0]["start_s"] + 1e-6
-        assert _end(parts[-1]) <= _end(outer) + 1e-6
+        assert _end(parts[2]) <= _end(outer) + 1e-6
+        assert collected["start_s"] <= parts[3]["start_s"] + 1e-6
+        assert _end(parts[-1]) <= _end(collected) + 1e-6
+        assert _end(parts[-1]) <= recycle["start_s"] + 1e-6
         for before, after in zip(parts, parts[1:]):
             assert _end(before) <= after["start_s"] + 1e-6
-        covered = sum(s["wall_s"] for s in parts)
+        covered = sum(s["wall_s"] for s in parts[:3])
         assert outer["wall_s"] - covered <= SWITCH_S, (outer, parts)
         p = r["phases"]
         assert p["chip_wait_s"] == pytest.approx(

@@ -1536,6 +1536,9 @@ class SetTable(_BaseTable):
         self._promote_samples = promote_samples
         if max_dev_slots > 0:
             self.MAX_DEV_SLOTS = max_dev_slots
+        # flush rounds whose estimate was left on the device for the
+        # assembly to collect (`readout(collect=False)`)
+        self.deferred_estimates_total = 0
         super().__init__(capacity, batch_cap, max_rows=max_rows)
 
     @property
@@ -1876,72 +1879,79 @@ class SetTable(_BaseTable):
         if self._sparse:
             state = jnp.copy(state)
         self._readout_device(state, snap)
+        self.collect(snap)
 
-    # -- the readout's spans ---------------------------------------------
+    # -- the readout in two halves ----------------------------------------
     #
-    # `dispatch{set}` is the one dispatch span of the flush that waits
-    # for the chip, so it is split where it happens. Children of
-    # `dispatch`, `family="set"`, closed only where the table did the
-    # work (an idle table closes none):
-    #   set_fold           host: the last pending batch's apply, the COO
-    #                      concatenate, the slot lookup, the hot rows'
-    #                      apply_batch dispatches and the estimate's
+    # The sets are the one family whose readout needs its device output
+    # on the host before the FlushBatch can be built (the sparse rows'
+    # estimates are scattered into the same array), so the readout is
+    # split where it would wait for the chip:
+    #   dispatch half  `_readout_device`, under `dispatch{set}`: the fold
+    #                  and the estimate's dispatch; what the other half
+    #                  needs stays in the snap (`_estimate`)
+    #   collect half   `collect`: the wait, the copy and the host work
+    # `readout()` runs both back to back, and with it every snapshot and
+    # live read; only the columnar flush runs the collect half apart, in
+    # `assembly_set`, so the chip's work runs beside the other families'
+    # transfers and assembly (`flusher.readout_columnstore`). Spans,
+    # `family="set"`, closed only where the table did the work (an idle
+    # table closes none):
+    #   set_fold           host, child of `dispatch`: the last pending
+    #                      batch's apply, the COO concatenate, the slot
+    #                      lookup, the hot rows' apply_batch dispatches
+    #                      and the estimate's
     #   set_wait           THE FLUSH THREAD BLOCKED ON THE CHIP, until
     #                      the estimate is ready; a chip runs its stream
-    #                      in order, so also until every readout program
+    #                      in order, so also until every program
     #                      dispatched before it has run
     #   set_transfer       the ready estimate's copy to the host
     #   set_host_estimate  host: the device rows' scatter, the sparse
     #                      rows' LogLog-Beta and argsort, the provider
+    # the last three children of the collect half's `parent`
 
     @staticmethod
-    def _set_phase(snap: dict, name: str):
+    def _set_phase(snap: dict, name: str, parent: str = "dispatch",
+                   timing=None):
         # a readout nobody times (a hand-called snapshot_and_reset)
         # gets a round of its own, as a sharded table's merge does
-        timing = snap.get("_timing") or FlushRound()
-        return timing.phase(name, parent="dispatch", family="set")
+        timing = timing or snap.get("_timing") or FlushRound()
+        return timing.phase(name, parent=parent, family="set")
 
-    def _estimate_on_host(self, dev, snap: dict) -> np.ndarray:
-        """`np.asarray` of a dispatched estimate in its two halves, the
-        wait and the copy. The handle and the time it was ready go into
-        the snap (`_waited`): the family's completion stamp, for
-        `deviceobs`'s watcher."""
-        with self._set_phase(snap, "set_wait"):
-            jax.block_until_ready(dev)
-            snap["_waited"] = (dev, time.perf_counter())
-        with self._set_phase(snap, "set_transfer"):
-            return np.asarray(dev)
+    def _dispatch_estimate(self, bank, snap: dict) -> None:
+        """A dense bank's estimate dispatched, the last step of the
+        fold (`set_fold`), left for `collect` with the bank the
+        register provider reads."""
+        with self._set_phase(snap, "set_fold"):
+            snap["_estimate"] = {"dev": batch_hll.estimate(bank),
+                                 "bank": bank}
 
     def _readout_apply(self, state, cols, snap: dict):
         with self._set_phase(snap, "set_fold"):
             return self._apply_cols_state(state, cols)
 
     def _readout_device(self, state, snap: dict) -> None:
-        """Estimate + register-provider assembly over the captured
-        generation. The register provider keeps a live device reference
-        (lazy transfer), so the captured generation escapes into the
-        snapshot and is NOT recycled."""
+        """The dispatch half over the captured generation: the fold and
+        the estimate's dispatch, leaving for `collect` the estimate's
+        handle (`dev`, None where no row is on the device), the bank the
+        register provider reads (lazy transfer, so the captured
+        generation escapes into the snapshot and is NOT recycled) and
+        the sparse tier's rows."""
         if not self._sparse:
-            snap["estimates"] = self._estimate_on_host(
-                batch_hll.estimate(state), snap)
-            with self._set_phase(snap, "set_host_estimate"):
-                snap["registers"] = _SetRegisters.dense(state,
-                                                        self.capacity)
+            self._dispatch_estimate(state, snap)
             return
         sparse = snap.pop("sparse")
         coo = sparse["coo"]
-        slot_of = sparse["slot_of"]
-        slot_row = sparse["slot_row"]
         nslots = sparse["nslots"]
         if not coo and not nslots:
             # idle: no sparse sample, no promoted row, nothing to wait
             # for and no span
             snap["estimates"] = np.zeros(self.capacity, np.float32)
             snap["registers"] = _SetRegisters(
-                None, slot_of, *(np.zeros(0, np.int32),) * 3)
+                None, sparse["slot_of"], *(np.zeros(0, np.int32),) * 3)
             return
         # fold promoted rows' pre-promotion backlog into the device
-        # table, then split the remaining COO per sparse row
+        # table; the rest of the COO is the sparse rows'
         with self._set_phase(snap, "set_fold"):
             if coo:
                 rows_all = np.concatenate([c[0] for c in coo])
@@ -1950,7 +1960,7 @@ class SetTable(_BaseTable):
             else:
                 rows_all = np.zeros(0, np.int32)
                 idx_all = rho_all = rows_all
-            pslots = slot_of[rows_all] if rows_all.size else rows_all
+            pslots = sparse["slot_of"][rows_all] if rows_all.size else rows_all
             hot = pslots >= 0
             hot_slots = pslots[hot]
             hot_idx, hot_rho = idx_all[hot], rho_all[hot]
@@ -1964,18 +1974,53 @@ class SetTable(_BaseTable):
                                     np.full(pad, PAD_ROW, np.int32)]),
                     np.concatenate([hot_idx[sl], np.zeros(pad, np.int32)]),
                     np.concatenate([hot_rho[sl], np.zeros(pad, np.int32)]))
+            snap["_estimate"] = {
+                "dev": batch_hll.estimate(state) if nslots else None,
+                "bank": state if nslots else None, "sparse": sparse,
+                "coo": (rows_all, idx_all, rho_all), "hot": hot}
 
+    def readout(self, snap: dict, timing=None, collect: bool = True) -> dict:
+        """Both halves back to back; with `collect=False` (the columnar
+        flush alone) the dispatch half, the estimate left in the snap
+        for `collect`. Such a round is counted where its estimate is
+        on the device (`flush.set.deferred_estimates_total`)."""
+        super().readout(snap, timing)
+        if collect:
+            return self.collect(snap, timing)
+        if snap.get("_estimate", {}).get("dev") is not None:
+            self.deferred_estimates_total += 1
+        return snap
+
+    def collect(self, snap: dict, timing=None,
+                parent: str = "dispatch") -> dict:
+        """The collect half: wait for the estimate the dispatch half
+        left in the snap, copy it and fill `estimates` and `registers`
+        (the spans `set_wait`, `set_transfer`, `set_host_estimate`,
+        children of `parent` in `timing`). A snap with nothing pending
+        (an idle table) is returned as it is."""
+        pending = snap.pop("_estimate", None)
+        if pending is None:
+            return snap
+        dev = pending["dev"]
+        if dev is not None:
+            with self._set_phase(snap, "set_wait", parent, timing):
+                jax.block_until_ready(dev)
+            with self._set_phase(snap, "set_transfer", parent, timing):
+                dev_est = np.asarray(dev)
+        with self._set_phase(snap, "set_host_estimate", parent, timing):
+            sparse = pending.get("sparse")
+            if sparse is None:
+                snap["estimates"] = dev_est
+                snap["registers"] = _SetRegisters.dense(pending["bank"],
+                                                        self.capacity)
+                return snap
             estimates = np.zeros(self.capacity, np.float32)
-            dev_regs = None
-            dev = batch_hll.estimate(state) if nslots else None
-        if nslots:
-            dev_est = self._estimate_on_host(dev, snap)
-            dev_regs = state  # device ref; _SetRegisters is lazy
-        with self._set_phase(snap, "set_host_estimate"):
-            if nslots:
-                estimates[np.asarray(slot_row, np.int64)] = dev_est[:nslots]
-            s_rows = rows_all[~hot]
-            s_idx, s_rho = idx_all[~hot], rho_all[~hot]
+            if dev is not None:
+                estimates[np.asarray(sparse["slot_row"], np.int64)] = \
+                    dev_est[:sparse["nslots"]]
+            rows_all, idx_all, rho_all = pending["coo"]
+            cold = ~pending["hot"]
+            s_rows, s_idx, s_rho = rows_all[cold], idx_all[cold], rho_all[cold]
             if s_rows.size:
                 urows, est = self._host_estimates(s_rows, s_idx, s_rho)
                 estimates[urows] = est
@@ -1983,15 +2028,15 @@ class SetTable(_BaseTable):
                 s_rows, s_idx, s_rho = (s_rows[order], s_idx[order],
                                         s_rho[order])
             snap["estimates"] = estimates
-            snap["registers"] = _SetRegisters(dev_regs, slot_of, s_rows,
+            snap["registers"] = _SetRegisters(pending["bank"],
+                                              sparse["slot_of"], s_rows,
                                               s_idx, s_rho)
+        return snap
 
     def snapshot_begin(self) -> dict:
-        """Dispatch half: swap + estimate readout. Unlike every other
-        family's, this one WAITS FOR THE CHIP: the estimate of the
-        promoted rows is realized on the host here, under the `set_wait`
-        and `set_transfer` spans, because the sparse rows' estimates are
-        scattered into the same array."""
+        """Swap + both halves of the readout: unlike every other
+        family's, this one WAITS FOR THE CHIP (the `set_wait` span), so
+        the snap holds the estimates on the host."""
         return self.readout(self.swap_out())
 
     @staticmethod
@@ -2426,6 +2471,10 @@ class ColumnStore:
             if nslots is not None:  # sparse set table: promoted HBM rows
                 rows.append(("columnstore.set_dev_slots", "gauge",
                              float(nslots), tags))
+            deferred = getattr(t, "deferred_estimates_total", None)
+            if deferred is not None:  # the set tables
+                rows.append(("flush.set.deferred_estimates_total",
+                             "counter", float(deferred), ()))
         # llhist accuracy accounting: samples binned, and how many fell
         # outside the representable magnitude window (collapsed to the
         # zero bin / clamped into a top bin)

@@ -158,10 +158,8 @@ class _ReadoutWatcher:
     GIL is released meanwhile), reads `perf_counter()` and closes a
     `chip_busy{family,device}` span (parent `readout`) in the round:
     from the later of that device's previous stamp and the family's
-    dispatch start, to the stamp. A family that waited for its own
-    output on the flush thread (the sets' `set_wait`) brings its stamp
-    along as `done_at` and is not waited for again. The span's wall also
-    feeds `device.kernel.readout_s{family}`.
+    dispatch start, to the stamp. The span's wall also feeds
+    `device.kernel.readout_s{family}`.
 
     What a stamp can and cannot say. A chip runs its stream in order, so
     a span holds everything the device ran between two watched
@@ -188,11 +186,9 @@ class _ReadoutWatcher:
         self.thread.start()
 
     def watch(self, rnd, family: str, by_device: Dict[str, list],
-              dispatched_at: float,
-              done_at: Optional[float] = None) -> None:
+              dispatched_at: float) -> None:
         with self._cv:
-            self._handed.append((rnd, family, by_device, dispatched_at,
-                                 done_at))
+            self._handed.append((rnd, family, by_device, dispatched_at))
             self._open += 1
             self._cv.notify_all()
 
@@ -224,7 +220,7 @@ class _ReadoutWatcher:
                     self._open -= 1
                     self._cv.notify_all()
 
-    def _stamp(self, rnd, family, by_device, dispatched_at, done_at):
+    def _stamp(self, rnd, family, by_device, dispatched_at):
         import jax
 
         obs = self._obs()
@@ -232,10 +228,8 @@ class _ReadoutWatcher:
         for device, handles in by_device.items():
             if device == "host":
                 continue
-            done = done_at
-            if done is None:
-                jax.block_until_ready(handles)
-                done = time.perf_counter()
+            jax.block_until_ready(handles)
+            done = time.perf_counter()
             start = max(self._last.get(device, 0.0), dispatched_at)
             done = self._last[device] = max(done, start)
             rnd.stamped("chip_busy", "readout", start, done,

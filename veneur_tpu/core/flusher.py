@@ -681,8 +681,8 @@ def readout_columnstore(
     interval's accumulation. `timing`, when given, receives the spans
     (all under a `readout` parent): one `dispatch` per family, back to
     back, so their sum is `dispatch_s`; `device_sync` around the `sync`
-    and `transfer` spans; `assembly` around `recycle` and one
-    `assembly_<family>` per family block. With `attribute` every family
+    and `transfer` spans; `assembly` around one `assembly_<family>` per
+    family block and `recycle`. With `attribute` every family
     is synced on its own, device by device, so a sync stall is booked
     to the family and device that caused it: that is the DEFAULT path of
     a server (`latency_observatory: true`, `Server._run_readout`), and
@@ -690,20 +690,23 @@ def readout_columnstore(
     spans. Without it everything still on the device is drained in one
     `sync`: the path no benchmark cell has measured.
 
-    Where the chip's time shows. Every family's dispatch is asynchronous
-    but the sets': `SetTable._readout_device` needs the estimate on the
-    host to fill in the rows the device does not hold, so it blocks for
-    it inside `dispatch{set}`, under a `set_wait` span of its own; a
-    chip runs its stream in order, so that wait covers every readout
-    program dispatched before it, and the `sync` spans that follow find
-    an empty queue. In a deployment that sends no sets they are where
-    the flush thread waits. `chip_wait_s` (`Server._flush_locked`) is
-    the two together. With a device observatory each family's output
-    handles go to its completion watcher at the end of the family's
-    dispatch; it closes one `chip_busy{family,device}` span a family and
-    device (what the device ran up to that family's completion:
-    `deviceobs._ReadoutWatcher` says what such a span books to whom) and
-    is joined once `device_sync` has ended, before the assembly starts."""
+    Where the chip's time shows. Every family's dispatch is
+    asynchronous. The sets need their estimate on the host to fill in
+    the rows the device does not hold, so `dispatch{set}` dispatches it
+    (`SetTable.readout(collect=False)`) and `assembly_set` collects it
+    (`SetTable.collect`: `set_wait`, `set_transfer`, `set_host_estimate`),
+    after the other families' syncs, transfers and scalar and histogram
+    assembly: the chip runs the sets' programs meanwhile, and a copy of
+    a ready output does not queue behind them. The flush thread waits
+    for the chip in the `sync` spans and in `set_wait`; `chip_wait_s`
+    (`Server._flush_locked`) is the two together. With a device
+    observatory each family's output handles go to its completion
+    watcher at the end of the family's dispatch; it closes one
+    `chip_busy{family,device}` span a family and device (what the device
+    ran up to that family's completion: `deviceobs._ReadoutWatcher` says
+    what such a span books to whom) and is joined once the sets are
+    collected, before the drained generations are recycled (the
+    counters' and gauges' outputs are their captured states)."""
     import jax
 
     timing = timing or FlushRound()
@@ -727,16 +730,14 @@ def readout_columnstore(
         """One family's dispatch span."""
         return timing.phase("dispatch", parent="readout", family=family)
 
-    def dispatched(family: str, span: dict, handles: list,
-                   done_at: Optional[float] = None) -> list:
+    def dispatched(family: str, span: dict, handles: list) -> list:
         """The outputs of a family whose dispatch span has closed, on
-        their way to the completion watcher; `done_at` where the family
-        waited for them itself."""
+        their way to the completion watcher."""
         if watcher is not None or attribute:
             by_device[family] = _handles_by_device(handles)
         if watcher is not None and by_device[family]:
             watcher.watch(timing, family, by_device[family],
-                          timing.t0 + span["start_s"], done_at)
+                          timing.t0 + span["start_s"])
         return handles
 
     # ---- phase 1: dispatch every device flush, sync nothing ------------
@@ -758,16 +759,11 @@ def readout_columnstore(
     ll_handles = dispatched("llhist", span, [
         h for h in (ll_snap["packed"], ll_snap["bins_dev"])
         if h is not None])
-    # the one family that waits for the chip inside its dispatch: the
-    # estimate is realized under `set_wait` (see above), and the stamp
-    # of that wait is the family's completion
+    # the estimate is dispatched here and collected in `assembly_set`
     with dispatching("set") as span:
-        set_snap = store.sets.readout(swap["set"], timing)
-        estimates, registers, s_touched, s_meta = \
-            store.sets.snapshot_finish(set_snap)
-    waited = set_snap.pop("_waited", None)
-    if watcher is not None and waited is not None:
-        dispatched("set", span, [waited[0]], waited[1])
+        set_snap = store.sets.readout(swap["set"], timing, collect=False)
+    estimate = set_snap.get("_estimate", {}).get("dev")
+    dispatched("set", span, [] if estimate is None else [estimate])
     with dispatching("status"):
         st_vals, st_touched, st_meta = swap["status"]
 
@@ -798,245 +794,259 @@ def readout_columnstore(
             with timing.phase("transfer", parent="device_sync",
                               family=family):
                 finished[family] = finish()
-    if watcher is not None:
-        # every handle is ready: a wake-up, after which the round holds
-        # its `chip_busy` spans (outside `device_sync`, which stays the
-        # sum of its `sync` and `transfer` spans)
-        watcher.join()
     c_vals, c_touched, c_meta = finished["counter"]
     g_vals, g_touched, g_meta = finished["gauge"]
     out, export, h_touched, h_meta = finished["histogram"]
     assembly = timing.phase("assembly", parent="readout").start()
-    # transfers done: donate the drained generations back as the next
-    # interval's spares (the second buffer of each family's
-    # double-buffer; no-op for snaps whose state escaped — sparse
-    # sets). Booked in the assembly phase: the zeroing dispatches are
-    # async and off the segment-attribution pin.
-    with timing.phase("recycle", parent="assembly"):
-        store.counters.recycle(c_snap)
-        store.gauges.recycle(g_snap)
-        store.histos.recycle(h_snap)
-        store.llhists.recycle(ll_snap)
-        store.sets.recycle(set_snap)
-
-    # ---- counters & gauges ---------------------------------------------
-    def scalar_family(table, vals, touched, meta_list, mtype, fwd_list):
-        rows = _valid_rows(touched, meta_list)
-        if rows.size == 0:
-            return
-        vals_sel = np.asarray(vals, np.float64)[rows]
-        if is_local:
-            fwd_mask = table.scope_code[rows] == global_code
-            if fwd_mask.any():
-                if collect_forward:
-                    fwd_list.extend(
-                        (meta_list[r], v)
-                        for r, v in zip(rows[fwd_mask].tolist(),
-                                        vals_sel[fwd_mask].tolist()))
-                keep = ~fwd_mask
-                rows, vals_sel = rows[keep], vals_sel[keep]
-        if rows.size:
-            sections.append(FlushSection(
-                table.flush_names("", rows, meta_list, lambda m: m.name),
-                vals_sel, table.flush_tags(rows, meta_list), mtype))
-
-    with timing.phase("assembly_scalar", parent="assembly"):
-        scalar_family(store.counters, c_vals, c_touched, c_meta,
-                      MetricType.COUNTER, fwd.counters)
-        scalar_family(store.gauges, g_vals, g_touched, g_meta,
-                      MetricType.GAUGE, fwd.gauges)
-
-    # ---- histograms & timers -------------------------------------------
-    with timing.phase("assembly_histogram", parent="assembly"):
-        hr = _valid_rows(h_touched, h_meta)
-        if hr.size:
-            htab = store.histos
-            scope = htab.scope_code[hr]
-            local_only = scope == local_code
-            global_only = scope == global_code
-            # server_aggs == aggregates (flusher.go:360-371 passes the
-            # configured set unconditionally), so the only per-scope bits
-            # variation is global-only rows emitting nothing on a local server
-            a_on = np.where(global_only & is_local, 0, full_bits)
-            use_global = global_only & (not is_local)
-            emit_ps = local_only | (not is_local)
-
-            cols = {k: np.asarray(out[k], np.float64)[hr]
-                    for k in ("lmin", "lmax", "lsum", "lweight", "lrecip",
-                              "min", "max", "sum", "count", "hmean")}
-            quants = np.asarray(out["quantiles"], np.float64)[hr]
-            # one tag-cache pass for every histo section; sections slice it
-            tags_hr = htab.flush_tags(hr, h_meta)
-
-            def agg_section(suffix, mask, values, mtype=MetricType.GAUGE):
-                if not mask.any():
-                    return
+    try:
+        # ---- counters & gauges -----------------------------------------
+        def scalar_family(table, vals, touched, meta_list, mtype, fwd_list):
+            rows = _valid_rows(touched, meta_list)
+            if rows.size == 0:
+                return
+            vals_sel = np.asarray(vals, np.float64)[rows]
+            if is_local:
+                fwd_mask = table.scope_code[rows] == global_code
+                if fwd_mask.any():
+                    if collect_forward:
+                        fwd_list.extend(
+                            (meta_list[r], v)
+                            for r, v in zip(rows[fwd_mask].tolist(),
+                                            vals_sel[fwd_mask].tolist()))
+                    keep = ~fwd_mask
+                    rows, vals_sel = rows[keep], vals_sel[keep]
+            if rows.size:
                 sections.append(FlushSection(
-                    htab.flush_names(
-                        suffix, hr[mask], h_meta,
-                        lambda m, s=suffix: f"{m.name}.{s}"),
-                    values[mask], tags_hr[mask], mtype))
+                    table.flush_names("", rows, meta_list, lambda m: m.name),
+                    vals_sel, table.flush_tags(rows, meta_list), mtype))
 
-            lmin, lmax = cols["lmin"], cols["lmax"]
-            lsum, lweight = cols["lsum"], cols["lweight"]
-            lrecip = cols["lrecip"]
-            dmin, dmax = cols["min"], cols["max"]
-            dsum, dcount = cols["sum"], cols["count"]
-            with np.errstate(divide="ignore", invalid="ignore"):
-                avg = np.where(use_global,
-                               dsum / np.where(dcount, dcount, 1.0),
-                               lsum / np.where(lweight, lweight, 1.0))
-                hmean = np.where(use_global, cols["hmean"],
-                                 lweight / np.where(lrecip, lrecip, 1.0))
-            agg_section("max", ((a_on & _A_MAX) != 0)
-                        & (~np.isinf(lmax) | use_global),
-                        np.where(use_global, dmax, lmax))
-            agg_section("min", ((a_on & _A_MIN) != 0)
-                        & (~np.isinf(lmin) | use_global),
-                        np.where(use_global, dmin, lmin))
-            agg_section("sum", ((a_on & _A_SUM) != 0)
-                        & ((lsum != 0) | use_global),
-                        np.where(use_global, dsum, lsum))
-            agg_section("avg", ((a_on & _A_AVERAGE) != 0)
-                        & (use_global | ((lsum != 0) & (lweight != 0))), avg)
-            agg_section("count", ((a_on & _A_COUNT) != 0)
-                        & ((lweight != 0) | use_global),
-                        np.where(use_global, dcount, lweight),
-                        MetricType.COUNTER)
-            agg_section("median", (a_on & _A_MEDIAN) != 0,
-                        quants[:, ps_index[0.5]])
-            agg_section("hmean", ((a_on & _A_HMEAN) != 0)
-                        & (use_global | ((lrecip != 0) & (lweight != 0))),
-                        hmean)
+        with timing.phase("assembly_scalar", parent="assembly"):
+            scalar_family(store.counters, c_vals, c_touched, c_meta,
+                          MetricType.COUNTER, fwd.counters)
+            scalar_family(store.gauges, g_vals, g_touched, g_meta,
+                          MetricType.GAUGE, fwd.gauges)
 
-            if full_ps and emit_ps.any():
-                pr = hr[emit_ps]
-                pq = quants[emit_ps]
-                ptags = tags_hr[emit_ps]
-                for p in full_ps:
+        # ---- histograms & timers -------------------------------------------
+        with timing.phase("assembly_histogram", parent="assembly"):
+            hr = _valid_rows(h_touched, h_meta)
+            if hr.size:
+                htab = store.histos
+                scope = htab.scope_code[hr]
+                local_only = scope == local_code
+                global_only = scope == global_code
+                # server_aggs == aggregates (flusher.go:360-371 passes the
+                # configured set unconditionally), so the only per-scope
+                # bits variation is global-only rows emitting nothing on a
+                # local server
+                a_on = np.where(global_only & is_local, 0, full_bits)
+                use_global = global_only & (not is_local)
+                emit_ps = local_only | (not is_local)
+
+                cols = {k: np.asarray(out[k], np.float64)[hr]
+                        for k in ("lmin", "lmax", "lsum", "lweight", "lrecip",
+                                  "min", "max", "sum", "count", "hmean")}
+                quants = np.asarray(out["quantiles"], np.float64)[hr]
+                # one tag-cache pass for every histo section; sections slice it
+                tags_hr = htab.flush_tags(hr, h_meta)
+
+                def agg_section(suffix, mask, values, mtype=MetricType.GAUGE):
+                    if not mask.any():
+                        return
                     sections.append(FlushSection(
                         htab.flush_names(
-                            p, pr, h_meta,
-                            lambda m, p=p: _percentile_name(m.name, p)),
-                        pq[:, ps_index[p]], ptags, MetricType.GAUGE))
+                            suffix, hr[mask], h_meta,
+                            lambda m, s=suffix: f"{m.name}.{s}"),
+                        values[mask], tags_hr[mask], mtype))
 
-            if need_export:
-                exp_means, exp_weights, exp_min, exp_max, exp_recip = export
-                fr = hr[~local_only]
-                if fr.size:
-                    # one bulk fancy-index copy into a COMPACT matrix, then
-                    # row views into it: per-row .copy() was pure overhead on
-                    # the forward config's flush path, but views into the
-                    # full (K, 2C+3) export would pin ~capacity-sized memory
-                    # for the lifetime of the async forward send
-                    cm, cw = exp_means[fr], exp_weights[fr]
-                    cmin, cmax = exp_min[fr], exp_max[fr]
-                    crecip = exp_recip[fr]
-                    for j, row in enumerate(fr.tolist()):
-                        fwd.histograms.append((
-                            h_meta[row], cm[j], cw[j], float(cmin[j]),
-                            float(cmax[j]), float(crecip[j])))
+                lmin, lmax = cols["lmin"], cols["lmax"]
+                lsum, lweight = cols["lsum"], cols["lweight"]
+                lrecip = cols["lrecip"]
+                dmin, dmax = cols["min"], cols["max"]
+                dsum, dcount = cols["sum"], cols["count"]
+                with np.errstate(divide="ignore", invalid="ignore"):
+                    avg = np.where(use_global,
+                                   dsum / np.where(dcount, dcount, 1.0),
+                                   lsum / np.where(lweight, lweight, 1.0))
+                    hmean = np.where(use_global, cols["hmean"],
+                                     lweight / np.where(lrecip, lrecip, 1.0))
+                agg_section("max", ((a_on & _A_MAX) != 0)
+                            & (~np.isinf(lmax) | use_global),
+                            np.where(use_global, dmax, lmax))
+                agg_section("min", ((a_on & _A_MIN) != 0)
+                            & (~np.isinf(lmin) | use_global),
+                            np.where(use_global, dmin, lmin))
+                agg_section("sum", ((a_on & _A_SUM) != 0)
+                            & ((lsum != 0) | use_global),
+                            np.where(use_global, dsum, lsum))
+                agg_section("avg", ((a_on & _A_AVERAGE) != 0)
+                            & (use_global
+                               | ((lsum != 0) & (lweight != 0))), avg)
+                agg_section("count", ((a_on & _A_COUNT) != 0)
+                            & ((lweight != 0) | use_global),
+                            np.where(use_global, dcount, lweight),
+                            MetricType.COUNTER)
+                agg_section("median", (a_on & _A_MEDIAN) != 0,
+                            quants[:, ps_index[0.5]])
+                agg_section("hmean", ((a_on & _A_HMEAN) != 0)
+                            & (use_global | ((lrecip != 0) & (lweight != 0))),
+                            hmean)
 
-    # ---- sets -----------------------------------------------------------
-    with timing.phase("assembly_set", parent="assembly"):
-        sr = _valid_rows(s_touched, s_meta)
-        if sr.size:
-            stab = store.sets
-            s_local = stab.scope_code[sr] == local_code
-            if is_local:
-                if collect_forward:
-                    for row in sr[~s_local].tolist():
-                        fwd.sets.append((s_meta[row], registers[row].copy()))
-                er = sr[s_local]
-            else:
-                er = sr
-            if er.size:
-                sections.append(FlushSection(
-                    stab.flush_names("", er, s_meta, lambda m: m.name),
-                    np.asarray(estimates, np.float64)[er],
-                    stab.flush_tags(er, s_meta), MetricType.GAUGE))
+                if full_ps and emit_ps.any():
+                    pr = hr[emit_ps]
+                    pq = quants[emit_ps]
+                    ptags = tags_hr[emit_ps]
+                    for p in full_ps:
+                        sections.append(FlushSection(
+                            htab.flush_names(
+                                p, pr, h_meta,
+                                lambda m, p=p: _percentile_name(m.name, p)),
+                            pq[:, ps_index[p]], ptags, MetricType.GAUGE))
 
-    # ---- log-linear histograms ------------------------------------------
-    # percentiles/sum/count columnarize like every other family; the
-    # variable-length cumulative buckets become a BucketSection. The
-    # transferred register table is mostly zeros (six samples a key
-    # leave 6 of 4,501 registers live), so it is scanned ONCE for its
-    # nonzero (row, bin, count) entries and everything is derived from
-    # those: nothing here allocates a (rows, BINS) array. Exploded per
-    # row only by materialize() and the legacy `_flush_llhist_family`
-    # oracle (parity pinned by tests)
-    with timing.phase("assembly_llhist", parent="assembly"):
-        extras: List[InterMetric] = []
-        bucket_sections: List[BucketSection] = []
-        ll_out, ll_bins, ll_touched, ll_meta = finished["llhist"]
-        llr = np.flatnonzero(ll_touched)
-        if llr.size:
-            from veneur_tpu.ops import llhist_ref
+                if need_export:
+                    (exp_means, exp_weights, exp_min, exp_max,
+                     exp_recip) = export
+                    fr = hr[~local_only]
+                    if fr.size:
+                        # one bulk fancy-index copy into a COMPACT matrix,
+                        # then row views into it: per-row .copy() was pure
+                        # overhead on the forward config's flush path, but
+                        # views into the full (K, 2C+3) export would pin
+                        # ~capacity-sized memory for the lifetime of the
+                        # async forward send
+                        cm, cw = exp_means[fr], exp_weights[fr]
+                        cmin, cmax = exp_min[fr], exp_max[fr]
+                        crecip = exp_recip[fr]
+                        for j, row in enumerate(fr.tolist()):
+                            fwd.histograms.append((
+                                h_meta[row], cm[j], cw[j], float(cmin[j]),
+                                float(cmax[j]), float(crecip[j])))
 
-            lltab = store.llhists
-            # ll_bins is compact over the touched rows in `llr` order;
-            # `emit` marks the compact rows this server emits: no
-            # reclaim stragglers and, on a local, no row it forwards
-            emit = np.fromiter((ll_meta[r] is not None for r in llr.tolist()),
-                               bool, llr.size)
-            if is_local:
-                fwd_mask = emit & (lltab.scope_code[llr] != local_code)
-                if fwd_mask.any():
-                    if need_export:
-                        # the global merges whole rows: widen the ones
-                        # that leave (a compact copy, so the forward
-                        # send does not pin the transferred table)
-                        fwd_bins = ll_bins[fwd_mask].astype(np.int64)
-                        fwd.llhists.extend(
-                            (ll_meta[row], fwd_bins[j]) for j, row
-                            in enumerate(llr[fwd_mask].tolist()))
-                    emit &= ~fwd_mask
-            er = llr[emit]
-            if er.size:
-                quants = np.asarray(ll_out["quantiles"], np.float64)[er]
-                tags_er = lltab.flush_tags(er, ll_meta)
-                for j, p in enumerate(full_ps):
+        # ---- sets -----------------------------------------------------------
+        with timing.phase("assembly_set", parent="assembly"):
+            # the estimate dispatched in `dispatch{set}`, collected where it
+            # is first needed (see above)
+            store.sets.collect(set_snap, timing, parent="assembly_set")
+            estimates, registers, s_touched, s_meta = \
+                store.sets.snapshot_finish(set_snap)
+            sr = _valid_rows(s_touched, s_meta)
+            if sr.size:
+                stab = store.sets
+                s_local = stab.scope_code[sr] == local_code
+                if is_local:
+                    if collect_forward:
+                        for row in sr[~s_local].tolist():
+                            fwd.sets.append((s_meta[row],
+                                             registers[row].copy()))
+                    er = sr[s_local]
+                else:
+                    er = sr
+                if er.size:
                     sections.append(FlushSection(
-                        lltab.flush_names(
-                            p, er, ll_meta,
-                            lambda m, p=p: _percentile_name(m.name, p)),
-                        quants[:, j], tags_er, MetricType.GAUGE))
-                e_rows, e_bins, e_counts = \
-                    llhist_ref.nonzero_entries(ll_bins)
-                kept = emit[e_rows]
-                e_bins, e_counts = e_bins[kept], e_counts[kept]
-                # compact row -> emitted row
-                e_rows = (np.cumsum(emit) - 1)[e_rows[kept]]
-                # count and sum from the HOST-side registers (see the
-                # legacy helper: count must equal the le:+Inf bucket)
-                sections.append(FlushSection(
-                    lltab.flush_names("sum", er, ll_meta,
-                                      lambda m: f"{m.name}.sum"),
-                    llhist_ref.entry_sums(e_rows, e_bins, e_counts, er.size),
-                    tags_er, MetricType.GAUGE))
-                indptr, le_idx, cum, total = llhist_ref.cumulative_entries(
-                    e_rows, e_bins, e_counts, er.size)
-                total = total.astype(np.float64)
-                sections.append(FlushSection(
-                    lltab.flush_names("count", er, ll_meta,
-                                      lambda m: f"{m.name}.count"),
-                    total, tags_er, MetricType.COUNTER))
-                bucket_sections.append(BucketSection(
-                    lltab.flush_names("bucket", er, ll_meta,
-                                      lambda m: f"{m.name}.bucket"),
-                    tags_er, indptr, le_idx, cum.astype(np.float64), total))
+                        stab.flush_names("", er, s_meta, lambda m: m.name),
+                        np.asarray(estimates, np.float64)[er],
+                        stab.flush_tags(er, s_meta), MetricType.GAUGE))
 
-    # ---- status checks --------------------------------------------------
-    for row in np.flatnonzero(st_touched).tolist():
-        meta = st_meta[row]
-        if meta is None:  # recycled mid-interval (reclaim straggler)
-            continue
-        entry = st_vals[row]
-        extras.append(InterMetric(
-            name=meta.name, timestamp=now, value=entry.value,
-            tags=list(meta.tags), type=MetricType.STATUS,
-            message=entry.message, hostname=entry.hostname))
+        # ---- log-linear histograms ------------------------------------------
+        # percentiles/sum/count columnarize like every other family; the
+        # variable-length cumulative buckets become a BucketSection. The
+        # transferred register table is mostly zeros (six samples a key
+        # leave 6 of 4,501 registers live), so it is scanned ONCE for its
+        # nonzero (row, bin, count) entries and everything is derived from
+        # those: nothing here allocates a (rows, BINS) array. Exploded per
+        # row only by materialize() and the legacy `_flush_llhist_family`
+        # oracle (parity pinned by tests)
+        with timing.phase("assembly_llhist", parent="assembly"):
+            extras: List[InterMetric] = []
+            bucket_sections: List[BucketSection] = []
+            ll_out, ll_bins, ll_touched, ll_meta = finished["llhist"]
+            llr = np.flatnonzero(ll_touched)
+            if llr.size:
+                from veneur_tpu.ops import llhist_ref
 
+                lltab = store.llhists
+                # ll_bins is compact over the touched rows in `llr` order;
+                # `emit` marks the compact rows this server emits: no
+                # reclaim stragglers and, on a local, no row it forwards
+                emit = np.fromiter(
+                    (ll_meta[r] is not None for r in llr.tolist()),
+                    bool, llr.size)
+                if is_local:
+                    fwd_mask = emit & (lltab.scope_code[llr] != local_code)
+                    if fwd_mask.any():
+                        if need_export:
+                            # the global merges whole rows: widen the ones
+                            # that leave (a compact copy, so the forward
+                            # send does not pin the transferred table)
+                            fwd_bins = ll_bins[fwd_mask].astype(np.int64)
+                            fwd.llhists.extend(
+                                (ll_meta[row], fwd_bins[j]) for j, row
+                                in enumerate(llr[fwd_mask].tolist()))
+                        emit &= ~fwd_mask
+                er = llr[emit]
+                if er.size:
+                    quants = np.asarray(ll_out["quantiles"], np.float64)[er]
+                    tags_er = lltab.flush_tags(er, ll_meta)
+                    for j, p in enumerate(full_ps):
+                        sections.append(FlushSection(
+                            lltab.flush_names(
+                                p, er, ll_meta,
+                                lambda m, p=p: _percentile_name(m.name, p)),
+                            quants[:, j], tags_er, MetricType.GAUGE))
+                    e_rows, e_bins, e_counts = \
+                        llhist_ref.nonzero_entries(ll_bins)
+                    kept = emit[e_rows]
+                    e_bins, e_counts = e_bins[kept], e_counts[kept]
+                    # compact row -> emitted row
+                    e_rows = (np.cumsum(emit) - 1)[e_rows[kept]]
+                    # count and sum from the HOST-side registers (see the
+                    # legacy helper: count must equal the le:+Inf bucket)
+                    sections.append(FlushSection(
+                        lltab.flush_names("sum", er, ll_meta,
+                                          lambda m: f"{m.name}.sum"),
+                        llhist_ref.entry_sums(e_rows, e_bins, e_counts,
+                                              er.size),
+                        tags_er, MetricType.GAUGE))
+                    indptr, le_idx, cum, total = llhist_ref.cumulative_entries(
+                        e_rows, e_bins, e_counts, er.size)
+                    total = total.astype(np.float64)
+                    sections.append(FlushSection(
+                        lltab.flush_names("count", er, ll_meta,
+                                          lambda m: f"{m.name}.count"),
+                        total, tags_er, MetricType.COUNTER))
+                    bucket_sections.append(BucketSection(
+                        lltab.flush_names("bucket", er, ll_meta,
+                                          lambda m: f"{m.name}.bucket"),
+                        tags_er, indptr, le_idx, cum.astype(np.float64),
+                        total))
+
+        # ---- status checks --------------------------------------------------
+        for row in np.flatnonzero(st_touched).tolist():
+            meta = st_meta[row]
+            if meta is None:  # recycled mid-interval (reclaim straggler)
+                continue
+            entry = st_vals[row]
+            extras.append(InterMetric(
+                name=meta.name, timestamp=now, value=entry.value,
+                tags=list(meta.tags), type=MetricType.STATUS,
+                message=entry.message, hostname=entry.hostname))
+    finally:
+        if watcher is not None:
+            # every handle is ready: a wake-up, after which the round holds
+            # its `chip_busy` spans
+            watcher.join()
+        # donate the drained generations back as the next interval's spares
+        # (the second buffer of each family's double-buffer; no-op for snaps
+        # whose state escaped — sparse sets): after the transfers, the sets'
+        # collect (a sharded table's per-device states are the inputs of the
+        # merge its estimate reads) and the join (the watcher may hold the
+        # counters' and gauges' states). Booked in the assembly phase: the
+        # zeroing dispatches are async and off the segment-attribution pin.
+        with timing.phase("recycle", parent="assembly"):
+            store.counters.recycle(c_snap)
+            store.gauges.recycle(g_snap)
+            store.histos.recycle(h_snap)
+            store.llhists.recycle(ll_snap)
+            store.sets.recycle(set_snap)
     batch = FlushBatch(now, sections, extras, bucket_sections)
     assembly.stop()
     return batch, fwd

@@ -51,9 +51,8 @@ import numpy as np
 
 from veneur_tpu.core.columnstore import (CounterTable, GaugeTable,
                                          HistoTable, LLHistTable, PAD_ROW,
-                                         SetTable, WarmProgram, _SetRegisters,
-                                         _result, _state_only,
-                                         _zeros_like_spare)
+                                         SetTable, WarmProgram, _result,
+                                         _state_only, _zeros_like_spare)
 from veneur_tpu.core.telemetry import FlushRound
 from veneur_tpu.ops import batch_hll, batch_llhist, batch_tdigest, scalars
 from veneur_tpu.parallel import collectives
@@ -964,21 +963,17 @@ class ShardedSetTable(_PerDeviceStates, _DigestRouted, SetTable):
         return collectives.merge_hll_stacked(stacked)
 
     def _readout_device(self, states, snap: dict) -> None:
-        """The spans of `SetTable`'s readout, the merge's own between
-        them: `set_fold` (the last pending batch, routed and applied
-        per shard), `merge`, then `set_wait` for the estimate of the
-        merged bank, which every device computes."""
+        """The dispatch half of `SetTable`'s readout, the merge's own
+        span between two `set_fold`s: the last pending batch, routed and
+        applied per shard, before it; the estimate of the merged bank,
+        which every device computes, dispatched after it and left for
+        `collect`. The lazy per-row provider (columnstore._SetRegisters)
+        references the MERGED bank, so the drained per-device
+        generations are recyclable, once the estimate has been
+        collected: they are the merge's inputs."""
         with self._merging(snap):
             merged = self._merged_state(states, note=False)
-        snap["estimates"] = self._estimate_on_host(
-            batch_hll.estimate(merged), snap)
-        # lazy per-row provider (columnstore._SetRegisters): the
-        # merged (K, M) bank only crosses the device link if a
-        # consumer (the forward exporter) actually reads registers.
-        # The provider references the MERGED bank, so the drained
-        # per-device generations are recyclable.
-        with self._set_phase(snap, "set_host_estimate"):
-            snap["registers"] = _SetRegisters.dense(merged, self.capacity)
+        self._dispatch_estimate(merged, snap)
         snap["_recycle"] = states
 
     def warm_programs(self, ps, need_export):
