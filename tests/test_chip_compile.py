@@ -175,6 +175,23 @@ def test_import_path_program_compiles_for_v5e(
                      ())
 
 
+# -- the set bank at its largest rung ----------------------------------------
+#
+# `sets100k` (benchmark/configs) holds 100,000 keys in a 131,072 x 16,384
+# int8 bank (2 GiB); a flush holds two such generations. Its fold's
+# scatter and its estimate must neither stop compiling nor keep a whole
+# bank as a temporary (at 16,384 rows the scatter keeps one, D17).
+
+@pytest.mark.parametrize("program,others", [
+    (batch_hll.apply_batch, (_vec(B, i32),) * 3),
+    (batch_hll.estimate, ())], ids=["apply_batch", "estimate"])
+def test_set_bank_programs_fit_at_131072_rows(one_chip, program, others):
+    bank = _mat(131072, batch_hll.M, i8)
+    compiled = program.lower(*_shapes((bank,) + others, one_chip)).compile()
+    assert "tpu" in compiled.as_text().lower()
+    assert compiled.memory_analysis().temp_size_in_bytes < 131072 * 16384 // 8
+
+
 # -- the four-shard deployment's two heaviest collectives -------------------
 #
 # `global100k-shards4` (benchmark/configs) merges, every flush, four

@@ -528,6 +528,76 @@ def test_deferred_estimates_count_the_flushes_with_device_set_rows(
     assert quiet[False]["deferred_estimates"] == 0
 
 
+def test_set_chip_share_and_set_counters_read_what_the_rounds_did(
+        flushed, quiet):
+    """`set_chip_busy_s` is the sets' `chip_busy` spans, a
+    share of `chip_busy_s`; absent where nobody sends a set. At
+    /metrics, the three promoted keys of every round are its device
+    rows, the 60 one-member keys (and at most the server's own
+    `ssf.names_unique`) its host rows, the bank never climbs, and the
+    fold carries no more than the promoted keys' backlog."""
+    rnd = flushed["round"]
+    busy = _spans(rnd, "chip_busy", family="set")
+    assert rnd["phases"]["set_chip_busy_s"] == pytest.approx(
+        sum(s["wall_s"] for s in busy), abs=1e-5)
+    assert 0 <= rnd["phases"]["set_chip_busy_s"] <= \
+        rnd["phases"]["chip_busy_s"]
+    assert "set_chip_busy_s" not in quiet[True]["phases"]
+    first, second = flushed["scrapes"]
+
+    def grew(row):
+        return first["veneur_" + row], second["veneur_" + row]
+
+    assert grew("flush_set_device_rows_total") == (3 * HOT_SETS,
+                                                    4 * HOT_SETS)
+    host = grew("flush_set_host_rows_total")
+    assert 3 * 60 <= host[0] <= 3 * 61 and 60 <= host[1] - host[0] <= 61
+    dispatches = grew("flush_set_fold_dispatches_total")
+    entries = grew("flush_set_fold_entries_total")
+    assert 0 <= dispatches[1] - dispatches[0] <= 1
+    assert 0 <= entries[1] - entries[0] <= HOT_SETS * (PROMOTE_SAMPLES - 1)
+    assert grew("set_slot_ladder_climbs_total") == (0, 0)
+    assert second["veneur_set_device_slots"] == 64   # the table's rows
+
+
+def test_set_fold_counters_count_the_rounds_dispatches_and_entries():
+    """Through the columnar flush, six keys promoted at their fourth
+    member leave 3 backlog entries each; at a batch_cap of 8 they fold
+    in three dispatches, after the last pending batch's; their estimates
+    and six two-member keys' are computed on the device and the host."""
+    from veneur_tpu.core.columnstore import ColumnStore
+    from veneur_tpu.core.flusher import flush_columnstore_batch
+    from veneur_tpu.ops import hll_ref
+    from veneur_tpu.samplers.metrics import HistogramAggregates
+    from veneur_tpu.samplers.parser import Parser
+
+    store = ColumnStore(counter_capacity=64, gauge_capacity=64,
+                        histo_capacity=64, set_capacity=64, batch_cap=8)
+    sets = store.sets
+    sets._promote_samples = PROMOTE_SAMPLES
+    parser = Parser()
+    for i in range(6):
+        for j in range(3 * PROMOTE_SAMPLES + i):
+            parser.parse_metric_fast(b"hot.%d:m%d|s" % (i, j), store.process)
+        for j in range(2):
+            parser.parse_metric_fast(b"cold.%d:m%d|s" % (i, j), store.process)
+    pending = sets._n
+    batch, _fwd = flush_columnstore_batch(
+        store, False, (0.5,), HistogramAggregates.from_names(["count"]),
+        timing=FlushRound())
+    backlog = 6 * (PROMOTE_SAMPLES - 1)
+    assert sets.fold_entries_total == backlog + pending
+    assert sets.fold_dispatches_total == -(-backlog // 8) + (pending > 0)
+    assert (sets.device_rows_total, sets.host_rows_total) == (6, 6)
+    got = {str(n): v for s in batch.sections for n, v in zip(s.names,
+                                                              s.values)}
+    for name, n in (("hot.5", 3 * PROMOTE_SAMPLES + 5), ("cold.0", 2)):
+        ref = hll_ref.HLL()
+        for j in range(n):
+            ref.insert(b"m%d" % j)
+        assert got[name] == ref.estimate(), name
+
+
 def test_no_observatory_no_chip_busy_and_the_same_flush(quiet):
     on, off = quiet[True], quiet[False]
     assert not _spans(off, "chip_busy")

@@ -168,6 +168,16 @@ class TestSparseSetTable:
         Parser().parse_metric_fast(b"%s:x|s" % name, out.append)
         return out[0]
 
+    @staticmethod
+    def _promote_interned(table) -> int:
+        """Promote every interned row the slot limit admits; returns
+        the promoted-slot count."""
+        with table.lock:
+            for row in range(len(table.meta)):
+                if table._slot_of[row] < 0:
+                    table._promote_locked(row)
+            return table._nslots
+
     def test_small_sets_stay_off_device(self):
         # explicit high threshold: the point here is the sparse tier's
         # estimate/register parity, independent of the promote policy
@@ -219,6 +229,53 @@ class TestSparseSetTable:
         np.testing.assert_array_equal(regs[row], oracle.regs)
         assert float(est[row]) == oracle.estimate()
 
+    def test_ladder_climb_inside_one_interval_matches_reference(self):
+        """300 keys of 16 members promote at their 16th and climb the
+        bank from 256 to 2,048 slots within one interval; their first 15
+        members wait in the host backlog and fold into the bank at the
+        flush in batch_cap chunks. Ten keys of 5 members stay on the
+        host. After the fold and the collect, every estimate and every
+        register row is the reference's, and the flush counters say what
+        the round did."""
+        import numpy as np
+        from veneur_tpu.ops import hll_ref
+        table = self._mk(capacity=512, batch_cap=256, promote_samples=16)
+        assert table._ladder() == [256, 2048]
+        rng = np.random.default_rng(2_147_484_101)
+        sizes = [16] * 300 + [5] * 10
+        rows, oracle, lines = [], [], []
+        for k, n in enumerate(sizes):
+            with table.lock:
+                rows.append(table.row_for(self._stub(b"lad.%d" % k)))
+            oracle.append(hll_ref.HLL())
+            for m in rng.integers(0, 1 << 40, n).tolist():
+                member = b"u%d" % m
+                oracle[k].insert(member)
+                lines.append((rows[k], *hll_ref.pos_val(
+                    hll_ref.hash_member(member))))
+        order = rng.permutation(len(lines))
+        cols = np.asarray(lines, np.int32)[order].T
+        for at in range(0, cols.shape[1], 100):   # the pump's chunks
+            table.add_batch(*(c[at:at + 100].copy() for c in cols))
+        assert table._dev_cap == 2048 and table.slot_ladder_climbs_total == 1
+        # a promoted key's lines before its promotion's chunk wait in
+        # the backlog; the rest of the bank's entries are pending or
+        # applied already
+        coo_rows = np.concatenate([c[0] for c in table._coo])
+        backlog = int(np.count_nonzero(table._slot_of[coo_rows] >= 0))
+        assert 300 * 12 < backlog <= 300 * 15
+        pending = table._n
+        snap = table.collect(table.readout(table.swap_out(), collect=False))
+        est, regs = snap["estimates"], snap["registers"]
+        for k, row in enumerate(rows):
+            assert float(est[row]) == oracle[k].estimate(), k
+            np.testing.assert_array_equal(regs[row], oracle[k].regs)
+        assert table.fold_entries_total == backlog + pending
+        assert table.fold_dispatches_total == -(-backlog // 256) + (
+            1 if pending else 0)
+        assert table.fold_dispatches_total > 2
+        assert (table.device_rows_total, table.host_rows_total) == (300, 10)
+
     def test_dev_slot_cap_keeps_overflow_keys_sparse(self):
         """Past MAX_DEV_SLOTS (the HBM guard) hot keys stay on the host
         tier and still estimate correctly."""
@@ -248,10 +305,9 @@ class TestSparseSetTable:
             assert float(est[row]) == oracle[n].estimate(), n
             np.testing.assert_array_equal(regs[row], oracle[n].regs)
 
-    def test_prewarm_dense_promotes_interned_rows(self):
-        """prewarm_dense (bench warmup: climb the dev-cap ladder before
-        the measured window) promotes every interned row below the slot
-        cap; estimates after a real interval stay correct."""
+    def test_promotion_of_interned_rows_stops_at_the_slot_cap(self):
+        """Promoting every interned row stops at the slot cap; estimates
+        after a real interval stay correct."""
         import numpy as np
         from veneur_tpu.ops import hll_ref
         table = self._mk(batch_cap=256, promote_samples=2048,
@@ -262,7 +318,7 @@ class TestSparseSetTable:
             with table.lock:
                 rows.append(table.row_for(stub))
         assert table._nslots == 0  # nothing promoted yet (big threshold)
-        assert table.prewarm_dense() == 3  # capped at max_dev_slots
+        assert self._promote_interned(table) == 3  # capped at max_dev_slots
         assert sorted(int(table._slot_of[r]) >= 0 for r in rows) == \
             [False, True, True, True]
         # a normal interval after prewarm: samples route per tier and
@@ -338,7 +394,7 @@ class TestSparseSetTable:
         with table.lock:
             for s in stubs:
                 table.row_for(s)
-        assert table.prewarm_dense() == 8
+        assert self._promote_interned(table) == 8
         assert table._nslots == 8
         # at the clamp: a promotion attempt is a no-op, not state growth
         table._promote_locked(0)
@@ -348,7 +404,7 @@ class TestSparseSetTable:
         with table.lock:
             row9 = table.row_for(extra)
         assert table.capacity == 16
-        assert table.prewarm_dense() == 9
+        assert self._promote_interned(table) == 9
         assert table._slot_of[row9] >= 0
         assert table._dev_cap >= 9  # device cap regrew past the old clamp
         # and the dense tier still aggregates for the new slot

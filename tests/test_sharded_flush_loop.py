@@ -577,6 +577,28 @@ def test_dispatch_set_is_its_four_parts_and_the_merge(mesh):
             p["sync_s"] + p["set_wait_s"], abs=2e-6)
 
 
+def test_sharded_set_table_counts_its_fold_and_rows_as_one_device_does(
+        mesh):
+    """The set counters on the dense sharded bank: the fold is the
+    last pending batch's apply, one dispatch a shard it reaches; every
+    touched row is estimated on the device, none on the host; the bank
+    never climbs; `set_chip_busy_s` sums the sets' stamps of every
+    device."""
+    sets = mesh["store"].sets
+    assert sets.host_rows_total == 0 and sets.slot_ladder_climbs_total == 0
+    assert sets.device_rows_total >= SIZES["set"] * FLUSHES
+    # one readout a flush of an interval with set keys
+    assert 0 < sets.fold_dispatches_total <= (
+        SHARDS * sets.deferred_estimates_total)
+    assert sets.fold_entries_total >= sets.fold_dispatches_total
+    for r in mesh["rounds"]:
+        busy = [s["wall_s"] for s in r["spans"]
+                if s["name"] == "chip_busy" and s["family"] == "set"]
+        assert len(busy) == SHARDS
+        assert r["phases"]["set_chip_busy_s"] == pytest.approx(sum(busy),
+                                                               abs=1e-5)
+
+
 def test_readout_kernel_row_counts_a_stamp_a_device(mesh):
     before, after = mesh["scrapes"]
     stamps0 = _rows(before, "veneur_device_kernel_readout_s_count_total")

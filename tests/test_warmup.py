@@ -158,6 +158,39 @@ def test_nothing_compiles_after_the_warm_up(shards, caps):
         stop(server)
 
 
+def test_the_set_banks_slot_ladder_is_warmed_up_to_its_cap():
+    """(a) for the set bank's slot ladder: with `set_max_dev_slots` past
+    the first rung, the warm-up compiles every rung promotions can
+    reach (the climb, the apply, the next swap's fresh generation, the
+    estimate), and 2,100 keys promoted in one interval, twice, compile
+    nothing: the first interval climbs 256 -> 2,048 -> 2,100 on the
+    live path, the second starts at the top rung."""
+    cfg = config(1, (136, 104, 200, 2100, 28, 392))
+    cfg.tpu.set_max_dev_slots = 2100
+    cfg.tpu.set_promote_samples = 2
+    server, sink = started(cfg)
+    try:
+        [warmup] = events(server, "warmup")
+        assert [p["program"] for p in warmup["programs"]
+                if p["family"] == "set"] == ["apply", "readout"] + [
+            f"{program}@{rung}" for rung in (2048, 2100)
+            for program in ("climb", "apply", "fresh", "readout")]
+        sets = server.store.sets
+        with Compiles() as after:
+            for k in range(2):
+                feed(server, [b"w.ladder.%d:m%d|s" % (i, 2 * k + j)
+                              for i in range(2100) for j in range(2)])
+                server.flush()
+                got = {m.name: m.value for m in sink.wait_flush(30.0)}
+                assert got["w.ladder.0"] == got["w.ladder.2099"] == 2.0
+                assert sets._dev_cap == 2100
+        assert after.count == 0
+        assert sets.slot_ladder_climbs_total == 2
+        assert sets.device_rows_total == 2 * 2100
+    finally:
+        stop(server)
+
+
 # -- (b) ---------------------------------------------------------------------
 
 def test_timers_only_server_over_udp_against_the_reference():

@@ -122,8 +122,15 @@ class TpuConfig:
     # backend where the "device" is the same host core and promoting
     # buys nothing.
     set_promote_samples: int = 0
-    # hard cap on promoted device rows (HBM guard: slots are 16 KB
-    # each; 65536 = 1 GB). Keys past the cap stay on the host tier.
+    # hard cap on promoted device rows (HBM guard: slots are 16 KiB
+    # each; 65536 = 1 GiB a generation). Keys past the cap stay on the
+    # host tier. Raising it, budget for the rung the bank climbs to
+    # (256, 2,048, 16,384, 131,072: 8x a rung, capped here) times the
+    # generations held at once: the live bank and the one a flush
+    # captured, 2 x 2 GiB at 131,072 slots (a climb briefly holds the
+    # old rung beside the new). At 131,072 the v5e compiler keeps no
+    # whole-bank temporary in the scatter or the estimate; at 16,384
+    # the scatter keeps one (256 MiB).
     set_max_dev_slots: int = 65536
 
 

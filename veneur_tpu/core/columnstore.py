@@ -145,7 +145,9 @@ class WarmProgram(NamedTuple):
     on throwaway state and an all-padding batch `cols`. On a one-device
     table `fn` is the jitted program itself, so
     tests/test_chip_compile.py lowers the same entries for a described
-    v5e. `carry` is what the list threads from entry to entry: the
+    v5e (bar the set bank's climbs and fresh generations above its
+    first rung: eager calls, as the live path makes them). `carry` is
+    what the list threads from entry to entry: the
     throwaway generation to begin with, then `then(carry, result)` of
     each entry (`None` = the program donates nothing and the next entry
     gets the same carry; `_result` = it donates the state and returns
@@ -165,6 +167,10 @@ def _state_only(state, cols):
 
 def _state_and_cols(state, cols):
     return (state, *cols)
+
+
+def _no_args(state, cols):
+    return ()
 
 
 def _result(carry, result):
@@ -1524,7 +1530,12 @@ class SetTable(_BaseTable):
     `sparse=False` (the sharded table) keeps the original all-dense
     device path: every row maps 1:1 to a device slot."""
 
-    MAX_DEV_SLOTS = 65536  # HBM guard: 16 KB/slot -> 1 GB at the cap
+    # HBM guard (`tpu.set_max_dev_slots`): 16 KiB a slot, 1 GiB a
+    # generation at this default. The bank climbs 8x a rung up to it,
+    # and a flush holds two generations at once: the live one and the
+    # one it captured (its estimate and register provider read it).
+    # What a raised cap costs is written at the option (config.py).
+    MAX_DEV_SLOTS = 65536
 
     def __init__(self, capacity: int = 256, batch_cap: int = 8192,
                  sparse: bool = True, max_rows: int = 0,
@@ -1539,6 +1550,15 @@ class SetTable(_BaseTable):
         # flush rounds whose estimate was left on the device for the
         # assembly to collect (`readout(collect=False)`)
         self.deferred_estimates_total = 0
+        # what the flushes' readouts did (`readout`): the fold's
+        # apply_batch dispatches and the entries they carried, the rows
+        # estimated on the device and on the host; and the bank's climbs
+        # up its slot ladder (`_promote_locked`)
+        self.fold_dispatches_total = 0
+        self.fold_entries_total = 0
+        self.device_rows_total = 0
+        self.host_rows_total = 0
+        self.slot_ladder_climbs_total = 0
         super().__init__(capacity, batch_cap, max_rows=max_rows)
 
     @property
@@ -1588,22 +1608,6 @@ class SetTable(_BaseTable):
             self._dev_cap = new_cap
             self.state = _pad_cap(self.state, new_cap)
 
-    def prewarm_dense(self) -> int:
-        """Promote every currently-interned row (up to MAX_DEV_SLOTS) so
-        the device slot ladder — and each dev-cap shape's scatter and
-        estimate compiles — is climbed NOW rather than inside a live
-        interval. Benchmark/warmup helper; the next snapshot resets slot
-        assignments but _dev_cap persists, so steady state never
-        recompiles. Returns the promoted-slot count. No-op for dense
-        tables."""
-        if not self._sparse:
-            return 0
-        with self.lock:
-            for row in range(min(len(self.meta), self.MAX_DEV_SLOTS)):
-                if self._slot_of[row] < 0:
-                    self._promote_locked(row)
-            return self._nslots
-
     @property
     def _slot_limit(self) -> int:
         """How many device slots may be ASSIGNED: the HBM guard clamped
@@ -1612,6 +1616,17 @@ class SetTable(_BaseTable):
         promotion-scan gate — they must agree or the scan skip would
         drop count accumulation while promotion is still possible."""
         return min(self.MAX_DEV_SLOTS, self.capacity)
+
+    def _next_rung(self, slots: int) -> int:
+        return min(slots * 8, self.MAX_DEV_SLOTS)
+
+    def _ladder(self) -> List[int]:
+        """The bank's slot ladder from its current rung up: every
+        `_dev_cap` that promotions can climb to at this row capacity."""
+        rungs = [self._dev_cap]
+        while rungs[-1] < self._slot_limit:
+            rungs.append(self._next_rung(rungs[-1]))
+        return rungs
 
     def _promote_locked(self, row: int) -> None:
         """Assign a device slot (caller holds the buffer lock). A no-op
@@ -1628,9 +1643,12 @@ class SetTable(_BaseTable):
                 # scatter/estimate shape compile per doubling on the
                 # live ingest path (blocking under apply_lock). Ladder
                 # shapes are <= 4 total; slots past the row capacity
-                # simply idle (<= 8x overshoot, <= the guard).
-                self._dev_cap = min(self._dev_cap * 8, self.MAX_DEV_SLOTS)
+                # simply idle (<= 8x overshoot, <= the guard). Every
+                # rung's programs compile in the start-up warm-up
+                # (`warm_programs`).
+                self._dev_cap = self._next_rung(self._dev_cap)
                 self.state = _pad_cap(self.state, self._dev_cap)
+                self.slot_ladder_climbs_total += 1
         self._slot_of[row] = self._nslots
         self._slot_row.append(row)
         self._nslots += 1
@@ -1679,12 +1697,29 @@ class SetTable(_BaseTable):
         return batch_hll.init_state(capacity)
 
     def warm_programs(self, ps, need_export):
-        # a sparse table's captured bank escapes into the snapshot's
-        # register provider and is never zeroed; the sharded dense table
-        # has its own list
-        return [WarmProgram("apply", batch_hll.apply_batch,
+        """The bank's apply and estimate at its current rung, then, for
+        every rung above it that promotions can reach (`_ladder`), what
+        the live path meets there: the climb (`_pad_cap`, on the
+        dispatcher's thread under `apply_lock`), the apply, the next
+        swap's fresh generation and the estimate. A sparse table's
+        captured bank escapes into the snapshot's register provider and
+        is never zeroed; the sharded dense table has its own list."""
+        programs = [WarmProgram("apply", batch_hll.apply_batch,
+                                _state_and_cols, then=_result),
+                    WarmProgram("readout", batch_hll.estimate, _state_only)]
+        if not self._sparse:
+            return programs
+        for rung in self._ladder()[1:]:
+            programs += [
+                WarmProgram(f"climb@{rung}", _pad_cap, _state_only,
+                            static=(rung,), then=_result),
+                WarmProgram(f"apply@{rung}", batch_hll.apply_batch,
                             _state_and_cols, then=_result),
-                WarmProgram("readout", batch_hll.estimate, _state_only)]
+                WarmProgram(f"fresh@{rung}", batch_hll.init_state, _no_args,
+                            static=(rung,)),
+                WarmProgram(f"readout@{rung}", batch_hll.estimate,
+                            _state_only)]
+        return programs
 
     def _warm_state(self, capacity: int):
         # a sparse table's device bank rides its own 8x slot ladder
@@ -1695,10 +1730,9 @@ class SetTable(_BaseTable):
 
     def prewarm_rung(self, capacity: int, percentiles=(),
                      need_export: bool = True, report=None) -> bool:
-        """The bank's current rung of the slot ladder, for the table's
-        own capacity; a no-op for any other: a capacity resize never
-        retraces the set kernels, and the ladder's upper rungs are
-        climbed by promotions (or `prewarm_dense`)."""
+        """The bank's slot ladder from its current rung up, for the
+        table's own capacity; a no-op for any other: a capacity resize
+        never retraces the set kernels."""
         if self._sparse and capacity != self.capacity:
             return False
         return super().prewarm_rung(capacity, percentiles, need_export,
@@ -1880,6 +1914,7 @@ class SetTable(_BaseTable):
             state = jnp.copy(state)
         self._readout_device(state, snap)
         self.collect(snap)
+        snap.pop("_fold", None)   # a query is no flush: nothing counted
 
     # -- the readout in two halves ----------------------------------------
     #
@@ -1921,13 +1956,24 @@ class SetTable(_BaseTable):
     def _dispatch_estimate(self, bank, snap: dict) -> None:
         """A dense bank's estimate dispatched, the last step of the
         fold (`set_fold`), left for `collect` with the bank the
-        register provider reads."""
+        register provider reads. Every touched row is a device row."""
         with self._set_phase(snap, "set_fold"):
-            snap["_estimate"] = {"dev": batch_hll.estimate(bank),
-                                 "bank": bank}
+            snap["_estimate"] = {
+                "dev": batch_hll.estimate(bank), "bank": bank,
+                "device_rows": int(np.count_nonzero(snap["touched"])),
+                "host_rows": 0}
+
+    @staticmethod
+    def _note_fold(snap: dict, dispatches: int, entries: int) -> None:
+        """apply_batch dispatches of the readout's fold and the entries
+        they carried, for `readout` to count."""
+        fold = snap.setdefault("_fold", [0, 0])
+        fold[0] += dispatches
+        fold[1] += entries
 
     def _readout_apply(self, state, cols, snap: dict):
         with self._set_phase(snap, "set_fold"):
+            self._note_fold(snap, 1, int(np.count_nonzero(cols[0] != PAD_ROW)))
             return self._apply_cols_state(state, cols)
 
     def _readout_device(self, state, snap: dict) -> None:
@@ -1964,6 +2010,8 @@ class SetTable(_BaseTable):
             hot = pslots >= 0
             hot_slots = pslots[hot]
             hot_idx, hot_rho = idx_all[hot], rho_all[hot]
+            self._note_fold(snap, -(-hot_slots.shape[0] // self.batch_cap),
+                            hot_slots.shape[0])
             for i in range(0, hot_slots.shape[0], self.batch_cap):
                 sl = slice(i, i + self.batch_cap)
                 chunk_rows = hot_slots[sl]
@@ -1977,17 +2025,27 @@ class SetTable(_BaseTable):
             snap["_estimate"] = {
                 "dev": batch_hll.estimate(state) if nslots else None,
                 "bank": state if nslots else None, "sparse": sparse,
-                "coo": (rows_all, idx_all, rho_all), "hot": hot}
+                "coo": (rows_all, idx_all, rho_all), "hot": hot,
+                # rows left on the host tier: touched, never promoted
+                "device_rows": nslots, "host_rows": int(np.count_nonzero(
+                    snap["touched"] & (sparse["slot_of"] < 0)))}
 
     def readout(self, snap: dict, timing=None, collect: bool = True) -> dict:
         """Both halves back to back; with `collect=False` (the columnar
         flush alone) the dispatch half, the estimate left in the snap
         for `collect`. Such a round is counted where its estimate is
-        on the device (`flush.set.deferred_estimates_total`)."""
+        on the device (`flush.set.deferred_estimates_total`). Every
+        round counts its fold and its rows (`flush.set.*`)."""
         super().readout(snap, timing)
+        dispatches, entries = snap.pop("_fold", (0, 0))
+        self.fold_dispatches_total += dispatches
+        self.fold_entries_total += entries
+        pending = snap.get("_estimate", {})
+        self.device_rows_total += pending.get("device_rows", 0)
+        self.host_rows_total += pending.get("host_rows", 0)
         if collect:
             return self.collect(snap, timing)
-        if snap.get("_estimate", {}).get("dev") is not None:
+        if pending.get("dev") is not None:
             self.deferred_estimates_total += 1
         return snap
 
@@ -2471,10 +2529,22 @@ class ColumnStore:
             if nslots is not None:  # sparse set table: promoted HBM rows
                 rows.append(("columnstore.set_dev_slots", "gauge",
                              float(nslots), tags))
-            deferred = getattr(t, "deferred_estimates_total", None)
-            if deferred is not None:  # the set tables
-                rows.append(("flush.set.deferred_estimates_total",
-                             "counter", float(deferred), ()))
+            if isinstance(t, SetTable):
+                rows += [
+                    ("flush.set.deferred_estimates_total", "counter",
+                     float(t.deferred_estimates_total), ()),
+                    ("flush.set.fold_dispatches_total", "counter",
+                     float(t.fold_dispatches_total), ()),
+                    ("flush.set.fold_entries_total", "counter",
+                     float(t.fold_entries_total), ()),
+                    ("flush.set.device_rows_total", "counter",
+                     float(t.device_rows_total), ()),
+                    ("flush.set.host_rows_total", "counter",
+                     float(t.host_rows_total), ()),
+                    ("set.device_slots", "gauge",
+                     float(t._state_capacity()), ()),
+                    ("set.slot_ladder_climbs_total", "counter",
+                     float(t.slot_ladder_climbs_total), ())]
         # llhist accuracy accounting: samples binned, and how many fell
         # outside the representable magnitude window (collapsed to the
         # zero bin / clamped into a top bin)

@@ -1879,6 +1879,11 @@ class Server:
         # the `sync` spans and, where the sets' estimate ran, `set_wait`
         phases["chip_wait_s"] = (phases.get("sync_s", 0.0)
                                  + phases.get("set_wait_s", 0.0))
+        # the set family's share of `chip_busy_s`: device time up to the
+        # estimate's completion stamp, where the round has one
+        set_busy = rnd.spans_of("chip_busy", family="set")
+        if set_busy:
+            phases["set_chip_busy_s"] = sum(s["wall_s"] for s in set_busy)
         self.statsd.gauge("flush.total_duration_ns", int(duration * 1e9))
         self.statsd.timing("flush.total_duration", duration)
         for phase, secs in phases.items():

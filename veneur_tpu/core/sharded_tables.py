@@ -900,7 +900,9 @@ class ShardedSetTable(_PerDeviceStates, _DigestRouted, SetTable):
         return [jax.device_put(batch_hll.init_state(capacity), d)
                 for d in self._devices]
 
-    def _apply_cols_states(self, states, cols) -> None:
+    def _apply_cols_states(self, states, cols) -> Tuple[int, int]:
+        """Apply one batch to the per-device banks it reaches; returns
+        the apply_batch dispatches and the entries they carried."""
         rows, idxs, rhos = cols
         if not self._digest_routed:
             i = self._rr_next
@@ -908,7 +910,7 @@ class ShardedSetTable(_PerDeviceStates, _DigestRouted, SetTable):
             dev = self._devices[i]
             r, ix, rh = (jax.device_put(c, dev) for c in cols)
             states[i] = batch_hll.apply_batch(states[i], r, ix, rh)
-            return
+            return 1, int(np.count_nonzero(rows != PAD_ROW))
         t0 = time.perf_counter()
         home = self._home_of(rows)
         counts = self._shard_counts_of(home)
@@ -921,13 +923,14 @@ class ShardedSetTable(_PerDeviceStates, _DigestRouted, SetTable):
             route_s += time.perf_counter() - t0
             states[i] = batch_hll.apply_batch(states[i], *placed)
         self._plane.note_routed(self.family, counts, route_s)
+        return int(np.count_nonzero(counts)), int(counts.sum())
 
     def _apply_cols(self, cols):
         self._apply_cols_states(self.states, cols)
 
     def _readout_apply(self, states, cols, snap: dict):
         with self._set_phase(snap, "set_fold"):
-            self._apply_cols_states(states, cols)
+            self._note_fold(snap, *self._apply_cols_states(states, cols))
         return states
 
     def merge_batch(self, stubs, in_regs) -> None:

@@ -519,6 +519,12 @@ class FlushRound:
             self.spans.append(rec)
             self.phases[key] = self.phases.get(key, 0.0) + wall_s
 
+    def spans_of(self, name: str, **tags) -> List[dict]:
+        """The spans of `name` whose tags are `tags`."""
+        with self._lock:
+            return [s for s in self.spans if s["name"] == name
+                    and all(s.get(k) == v for k, v in tags.items())]
+
     def cpu_s(self) -> float:
         """CPU seconds of every thread that worked for the round: each
         span's own thread time, a span nested in another on the same
