@@ -192,6 +192,26 @@ def test_set_bank_programs_fit_at_131072_rows(one_chip, program, others):
     assert compiled.memory_analysis().temp_size_in_bytes < 131072 * 16384 // 8
 
 
+# The flush's backlog fold (`fold_backlog`: the sort and the Pallas kernel
+# over row blocks) at the top rung of `sets100k` and at `global100k`'s
+# 16,384 slots: it must update the bank in place at both (the scatter
+# keeps a whole 268 MB bank at 16,384 rows, D17; the fold must not).
+
+@pytest.mark.parametrize("rows", [131072, 16384])
+def test_backlog_fold_updates_the_bank_in_place(one_chip, tpu_segment_reduce,
+                                                rows):
+    n = batch_hll.fold_length(rows, 16)
+    assert n == rows * 15 + 1024
+    args = _shapes((_mat(rows, batch_hll.M, i8), _vec(n, i32), _vec(n, i32)),
+                   one_chip)
+    compiled = batch_hll.fold_backlog.lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu" in text.lower() and "tpu_custom_call" in text
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes == rows * batch_hll.M
+    assert ma.temp_size_in_bytes < rows * batch_hll.M // 8
+
+
 # -- the four-shard deployment's two heaviest collectives -------------------
 #
 # `global100k-shards4` (benchmark/configs) merges, every flush, four

@@ -562,9 +562,11 @@ def test_set_chip_share_and_set_counters_read_what_the_rounds_did(
 
 def test_set_fold_counters_count_the_rounds_dispatches_and_entries():
     """Through the columnar flush, six keys promoted at their fourth
-    member leave 3 backlog entries each; at a batch_cap of 8 they fold
-    in three dispatches, after the last pending batch's; their estimates
-    and six two-member keys' are computed on the device and the host."""
+    member leave 3 backlog entries each; they fold in one dispatch of
+    the bank's fold size (64 slots x 3 entries, rounded up to a window
+    of 1,024 pairs), after the last pending batch's, though batch_cap
+    is 8; their estimates and six two-member keys' are computed on the
+    device and the host."""
     from veneur_tpu.core.columnstore import ColumnStore
     from veneur_tpu.core.flusher import flush_columnstore_batch
     from veneur_tpu.ops import hll_ref
@@ -587,7 +589,10 @@ def test_set_fold_counters_count_the_rounds_dispatches_and_entries():
         timing=FlushRound())
     backlog = 6 * (PROMOTE_SAMPLES - 1)
     assert sets.fold_entries_total == backlog + pending
-    assert sets.fold_dispatches_total == -(-backlog // 8) + (pending > 0)
+    assert sets._fold_size(64) == 1024
+    assert sets.fold_dispatches_total == -(
+        -backlog // sets._fold_size(64)) + (pending > 0)
+    assert sets.fold_dispatches_total == 1 + (pending > 0)
     assert (sets.device_rows_total, sets.host_rows_total) == (6, 6)
     got = {str(n): v for s in batch.sections for n, v in zip(s.names,
                                                               s.values)}

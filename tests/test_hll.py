@@ -233,7 +233,7 @@ class TestSparseSetTable:
         """300 keys of 16 members promote at their 16th and climb the
         bank from 256 to 2,048 slots within one interval; their first 15
         members wait in the host backlog and fold into the bank at the
-        flush in batch_cap chunks. Ten keys of 5 members stay on the
+        flush in one `fold_backlog`. Ten keys of 5 members stay on the
         host. After the fold and the collect, every estimate and every
         register row is the reference's, and the flush counters say what
         the round did."""
@@ -271,9 +271,12 @@ class TestSparseSetTable:
             assert float(est[row]) == oracle[k].estimate(), k
             np.testing.assert_array_equal(regs[row], oracle[k].regs)
         assert table.fold_entries_total == backlog + pending
-        assert table.fold_dispatches_total == -(-backlog // 256) + (
-            1 if pending else 0)
-        assert table.fold_dispatches_total > 2
+        # the rung's fold size holds the whole backlog: one dispatch,
+        # after the last pending batch's
+        assert table._fold_size(2048) == 2048 * 15
+        assert table.fold_dispatches_total == -(
+            -backlog // table._fold_size(2048)) + (1 if pending else 0)
+        assert table.fold_dispatches_total == 1 + (1 if pending else 0)
         assert (table.device_rows_total, table.host_rows_total) == (300, 10)
 
     def test_dev_slot_cap_keeps_overflow_keys_sparse(self):
@@ -431,3 +434,152 @@ class TestSparseSetTable:
         assert table._nslots == 0  # interval-scoped, like every family
         est, _r, _t, _m = table.snapshot_and_reset()
         assert float(est[row]) == 0.0
+
+
+# -- the flush's backlog fold: one device program ----------------------------
+#
+# Each case feeds a sparse table (key, register, rho) triples in the
+# pump's chunks, swaps, and folds the captured generation through the
+# table's own readout. Registers and estimates must be the reference's
+# (`hll_ref`, built from the same triples) and the captured bank must be
+# byte for byte what the fold before `fold_backlog` made of the same snap:
+# the last pending batch, then the promoted rows' backlog in batch_cap
+# chunks of `apply_batch`. The "pallas" side runs the TPU pass itself
+# (sort, block offsets, the kernel) in interpret mode.
+
+def _fold_case(name, rng):
+    """(table kwargs, phases of (key, register, rho) triples, a threshold
+    to set between the phases or None, the fold's dispatches)."""
+    M = bhll.M
+
+    def members(keys, n, first=0):
+        return [(k, int(rng.integers(0, M)), int(rng.integers(1, 20)))
+                for k in keys for _ in range(first, n)]
+
+    if name == "duplicates":
+        # one register of each key thrice in its backlog, rho 5, 9, 2;
+        # the key promotes at its 4th triple
+        one = [(k, 77 + k, r) for k in range(6) for r in (5, 9, 2)]
+        return (dict(capacity=64, promote_samples=4),
+                [one, members(range(6), 3)], None, 1)
+    if name == "empty":
+        # every key promotes at its first chunk: nothing waits
+        return (dict(capacity=64, promote_samples=1),
+                [members(range(10), 20)], None, 0)
+    if name == "at_size":
+        # 256 keys x 4 waiting = 1,024 pairs, the rung's fold size
+        return (dict(capacity=256, promote_samples=5),
+                [members(range(256), 4), members(range(256), 1)], None, 1)
+    if name == "over_in_chunks":
+        # 200 keys wait 8 members each; the threshold then falls to 2, so
+        # 1,600 pairs meet a fold size of 1,024 (256 x 1, one window)
+        return (dict(capacity=256, promote_samples=16),
+                [members(range(200), 8), members(range(200), 1)], 2, 2)
+    if name == "cpu_auto":
+        # the CPU backend's own threshold (2,048): three keys promote at
+        # their 2,048th member, twenty stay on the host
+        hot = members(range(3), 2100)
+        return (dict(capacity=64, promote_samples=0),
+                [hot[i::3] for i in range(3)] + [members(range(3, 23), 7)],
+                None, 1)
+    if name == "ladder_climb":
+        # 300 keys promote at their 16th member and climb the bank
+        # 256 -> 2,048 inside the interval
+        return (dict(capacity=512, promote_samples=16),
+                [members(range(300), 15), members(range(300), 1)], None, 1)
+    raise KeyError(name)
+
+
+FOLD_CASES = ["duplicates", "empty", "at_size", "over_in_chunks",
+              "cpu_auto", "ladder_climb"]
+
+
+def _chunked_fold(snap, batch_cap):
+    """The fold as it was before `fold_backlog`, on a copy of the snap's
+    captured generation."""
+    import jax.numpy as jnp
+    pad_row = np.int32(2**31 - 1)
+    state = jnp.copy(snap["state"])
+    if snap["cols"] is not None:
+        state = bhll.apply_batch(state, *snap["cols"])
+    coo = snap["sparse"]["coo"]
+    if not coo:
+        return np.asarray(state)
+    rows, idx, rho = (np.concatenate([c[i] for c in coo]) for i in range(3))
+    slots = snap["sparse"]["slot_of"][rows]
+    hot = slots >= 0
+    slots, idx, rho = slots[hot], idx[hot], rho[hot]
+    for i in range(0, slots.shape[0], batch_cap):
+        pad = batch_cap - slots[i:i + batch_cap].shape[0]
+        state = bhll.apply_batch(
+            state, np.r_[slots[i:i + batch_cap], np.full(pad, pad_row)],
+            np.r_[idx[i:i + batch_cap], np.zeros(pad, np.int32)],
+            np.r_[rho[i:i + batch_cap], np.zeros(pad, np.int32)])
+    return np.asarray(state)
+
+
+@pytest.mark.parametrize("side", ["xla", "pallas"])
+@pytest.mark.parametrize("case", FOLD_CASES)
+def test_backlog_fold_matches_reference_and_the_chunked_fold(
+        case, side, monkeypatch):
+    import functools
+
+    import jax
+
+    from veneur_tpu.core.columnstore import SetTable
+    from veneur_tpu.ops import hll_ref
+    from veneur_tpu.samplers.parser import Parser
+
+    kernel_calls = []
+    if side == "pallas":
+        interpreted = jax.jit(functools.partial(bhll._fold_sorted,
+                                                interpret=True),
+                              donate_argnums=0)
+
+        def fold(*args):
+            kernel_calls.append(args[1].shape[0])
+            return interpreted(*args)
+        monkeypatch.setattr(bhll, "fold_backlog", fold)
+    rng = np.random.default_rng(2_147_484_400 + FOLD_CASES.index(case))
+    kwargs, phases, lowered, dispatches = _fold_case(case, rng)
+    table = SetTable(batch_cap=64, sparse=True, **kwargs)
+    keys = 1 + max(k for phase in phases for k, _i, _r in phase)
+    rows = []
+    for k in range(keys):
+        out = []
+        Parser().parse_metric_fast(b"fold.%d:x|s" % k, out.append)
+        with table.lock:
+            rows.append(table.row_for(out[0]))
+    oracle = np.zeros((keys, bhll.M), np.int8)
+    for n, phase in enumerate(phases):
+        if n == 1 and lowered is not None:
+            table._promote_samples = lowered
+        triples = np.asarray(phase, np.int32).reshape(-1, 3)
+        np.maximum.at(oracle, (triples[:, 0], triples[:, 1]),
+                      triples[:, 2].astype(np.int8))
+        cols = (np.asarray(rows, np.int32)[triples[:, 0]], triples[:, 1],
+                triples[:, 2])
+        for at in range(0, triples.shape[0], 100):   # the pump's chunks
+            table.add_batch(*(c[at:at + 100].copy() for c in cols))
+    snap = table.swap_out()
+    want_bank = _chunked_fold(snap, table.batch_cap)
+    coo = snap["sparse"]["coo"]
+    backlog = int(np.count_nonzero(snap["sparse"]["slot_of"][
+        np.concatenate([c[0] for c in coo])] >= 0)) if coo else 0
+    pending = int(np.count_nonzero(snap["cols"][0] != np.int32(2**31 - 1))) \
+        if snap["cols"] is not None else 0
+    snap = table.collect(table.readout(snap, collect=False))
+    est, regs = snap["estimates"], snap["registers"]
+    for k, row in enumerate(rows):
+        np.testing.assert_array_equal(regs[row], oracle[k], err_msg=str(k))
+        assert float(est[row]) == hll_ref.HLL(oracle[k]).estimate(), k
+    if case == "duplicates":
+        assert all(oracle[k][77 + k] >= 9 for k in range(6))
+    np.testing.assert_array_equal(np.asarray(regs._dev), want_bank)
+    assert table.fold_dispatches_total == dispatches + (pending > 0)
+    assert table.fold_entries_total == backlog + pending
+    if side == "pallas":
+        assert len(kernel_calls) == dispatches
+        # every dispatch of a rung has its one length
+        assert set(kernel_calls) <= {bhll.fold_length(
+            table._dev_cap, table.PROMOTE_SAMPLES)}

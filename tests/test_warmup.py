@@ -161,8 +161,9 @@ def test_nothing_compiles_after_the_warm_up(shards, caps):
 def test_the_set_banks_slot_ladder_is_warmed_up_to_its_cap():
     """(a) for the set bank's slot ladder: with `set_max_dev_slots` past
     the first rung, the warm-up compiles every rung promotions can
-    reach (the climb, the apply, the next swap's fresh generation, the
-    estimate), and 2,100 keys promoted in one interval, twice, compile
+    reach (the climb, the apply, the backlog's fold, the next swap's
+    fresh generation, the estimate; the first rung's fold too), and
+    2,100 keys promoted in one interval, twice, compile
     nothing: the first interval climbs 256 -> 2,048 -> 2,100 on the
     live path, the second starts at the top rung."""
     cfg = config(1, (136, 104, 200, 2100, 28, 392))
@@ -172,9 +173,10 @@ def test_the_set_banks_slot_ladder_is_warmed_up_to_its_cap():
     try:
         [warmup] = events(server, "warmup")
         assert [p["program"] for p in warmup["programs"]
-                if p["family"] == "set"] == ["apply", "readout"] + [
+                if p["family"] == "set"] == [
+            "apply", "fold@256", "readout"] + [
             f"{program}@{rung}" for rung in (2048, 2100)
-            for program in ("climb", "apply", "fresh", "readout")]
+            for program in ("climb", "apply", "fold", "fresh", "readout")]
         sets = server.store.sets
         with Compiles() as after:
             for k in range(2):
@@ -296,7 +298,7 @@ def test_the_warmup_event_lists_every_program_and_metrics_carry_its_seconds():
             ("histogram", "apply"), ("histogram", "compact"),
             ("histogram", "readout"), ("histogram", "reset"),
             ("llhist", "apply"), ("llhist", "readout"), ("llhist", "reset"),
-            ("set", "apply"), ("set", "readout")]
+            ("set", "apply"), ("set", "fold@56"), ("set", "readout")]
         for p in warmup["programs"]:
             assert p["seconds"] > 0
             # the CPU backend keeps no persistent cache (compilecache.py)

@@ -130,7 +130,8 @@ class TpuConfig:
     # captured, 2 x 2 GiB at 131,072 slots (a climb briefly holds the
     # old rung beside the new). At 131,072 the v5e compiler keeps no
     # whole-bank temporary in the scatter or the estimate; at 16,384
-    # the scatter keeps one (256 MiB).
+    # the scatter keeps one (256 MiB). The flush's backlog fold
+    # (`batch_hll.fold_backlog`) keeps none at either.
     set_max_dev_slots: int = 65536
 
 

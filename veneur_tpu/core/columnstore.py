@@ -1551,7 +1551,7 @@ class SetTable(_BaseTable):
         # assembly to collect (`readout(collect=False)`)
         self.deferred_estimates_total = 0
         # what the flushes' readouts did (`readout`): the fold's
-        # apply_batch dispatches and the entries they carried, the rows
+        # dispatches and the entries they carried, the rows
         # estimated on the device and on the host; and the bank's climbs
         # up its slot ladder (`_promote_locked`)
         self.fold_dispatches_total = 0
@@ -1697,29 +1697,47 @@ class SetTable(_BaseTable):
         return batch_hll.init_state(capacity)
 
     def warm_programs(self, ps, need_export):
-        """The bank's apply and estimate at its current rung, then, for
-        every rung above it that promotions can reach (`_ladder`), what
-        the live path meets there: the climb (`_pad_cap`, on the
-        dispatcher's thread under `apply_lock`), the apply, the next
-        swap's fresh generation and the estimate. A sparse table's
-        captured bank escapes into the snapshot's register provider and
-        is never zeroed; the sharded dense table has its own list."""
-        programs = [WarmProgram("apply", batch_hll.apply_batch,
-                                _state_and_cols, then=_result),
-                    WarmProgram("readout", batch_hll.estimate, _state_only)]
+        """The bank's apply, backlog fold and estimate at its current
+        rung, then, for every rung above it that promotions can reach
+        (`_ladder`), what the live path meets there: the climb
+        (`_pad_cap`, on the dispatcher's thread under `apply_lock`), the
+        apply, the fold, the next swap's fresh generation and the
+        estimate. A sparse table's captured bank escapes into the
+        snapshot's register provider and is never zeroed; the sharded
+        dense table has its own list (and no backlog)."""
+        apply = WarmProgram("apply", batch_hll.apply_batch,
+                            _state_and_cols, then=_result)
+        readout = WarmProgram("readout", batch_hll.estimate, _state_only)
         if not self._sparse:
-            return programs
-        for rung in self._ladder()[1:]:
+            return [apply, readout]
+        first, *upper = self._ladder()
+        programs = [apply, self._warm_fold(first), readout]
+        for rung in upper:
             programs += [
                 WarmProgram(f"climb@{rung}", _pad_cap, _state_only,
                             static=(rung,), then=_result),
                 WarmProgram(f"apply@{rung}", batch_hll.apply_batch,
                             _state_and_cols, then=_result),
+                self._warm_fold(rung),
                 WarmProgram(f"fresh@{rung}", batch_hll.init_state, _no_args,
                             static=(rung,)),
                 WarmProgram(f"readout@{rung}", batch_hll.estimate,
                             _state_only)]
         return programs
+
+    def _warm_fold(self, rung: int) -> WarmProgram:
+        """The backlog fold at `rung`, on all-padding arrays of the
+        length the bank it is given takes (`batch_hll.fold_length`)."""
+        def args(state, cols):
+            length = batch_hll.fold_length(state.shape[0],
+                                           self.PROMOTE_SAMPLES)
+            return (state, np.full(length, batch_hll.FOLD_PAD, np.int32),
+                    np.zeros(length, np.int32))
+        return WarmProgram(f"fold@{rung}", batch_hll.fold_backlog, args,
+                           then=_result)
+
+    def _fold_size(self, rung: int) -> int:
+        return batch_hll.fold_entries(rung, self.PROMOTE_SAMPLES)
 
     def _warm_state(self, capacity: int):
         # a sparse table's device bank rides its own 8x slot ladder
@@ -1934,8 +1952,8 @@ class SetTable(_BaseTable):
     # table closes none):
     #   set_fold           host, child of `dispatch`: the last pending
     #                      batch's apply, the COO concatenate, the slot
-    #                      lookup, the hot rows' apply_batch dispatches
-    #                      and the estimate's
+    #                      lookup, the backlog's pairs and its fold's
+    #                      dispatch (`_fold_backlog`), the estimate's
     #   set_wait           THE FLUSH THREAD BLOCKED ON THE CHIP, until
     #                      the estimate is ready; a chip runs its stream
     #                      in order, so also until every program
@@ -1965,7 +1983,8 @@ class SetTable(_BaseTable):
 
     @staticmethod
     def _note_fold(snap: dict, dispatches: int, entries: int) -> None:
-        """apply_batch dispatches of the readout's fold and the entries
+        """Dispatches of the readout's fold (the last pending batch's
+        `apply_batch`, the backlog's `fold_backlog`) and the entries
         they carried, for `readout` to count."""
         fold = snap.setdefault("_fold", [0, 0])
         fold[0] += dispatches
@@ -2008,20 +2027,8 @@ class SetTable(_BaseTable):
                 idx_all = rho_all = rows_all
             pslots = sparse["slot_of"][rows_all] if rows_all.size else rows_all
             hot = pslots >= 0
-            hot_slots = pslots[hot]
-            hot_idx, hot_rho = idx_all[hot], rho_all[hot]
-            self._note_fold(snap, -(-hot_slots.shape[0] // self.batch_cap),
-                            hot_slots.shape[0])
-            for i in range(0, hot_slots.shape[0], self.batch_cap):
-                sl = slice(i, i + self.batch_cap)
-                chunk_rows = hot_slots[sl]
-                pad = self.batch_cap - chunk_rows.shape[0]
-                state = batch_hll.apply_batch(
-                    state,
-                    np.concatenate([chunk_rows,
-                                    np.full(pad, PAD_ROW, np.int32)]),
-                    np.concatenate([hot_idx[sl], np.zeros(pad, np.int32)]),
-                    np.concatenate([hot_rho[sl], np.zeros(pad, np.int32)]))
+            state = self._fold_backlog(state, pslots, hot, idx_all, rho_all,
+                                       snap)
             snap["_estimate"] = {
                 "dev": batch_hll.estimate(state) if nslots else None,
                 "bank": state if nslots else None, "sparse": sparse,
@@ -2029,6 +2036,34 @@ class SetTable(_BaseTable):
                 # rows left on the host tier: touched, never promoted
                 "device_rows": nslots, "host_rows": int(np.count_nonzero(
                     snap["touched"] & (sparse["slot_of"] < 0)))}
+
+    def _fold_backlog(self, bank, slots, hot, idx, rho, snap: dict):
+        """The promoted rows' backlog (the COO entries whose `slots` are
+        `hot`) into the captured bank: one `batch_hll.fold_backlog` of
+        the rung's size (`_fold_size`). Where the whole COO fits, it goes
+        as it is, a host row's entries with slot -1, which the device
+        skips: no copy of the hot entries on the host. Otherwise the hot
+        entries alone go, in chunks of that size where they outgrow it
+        (past FOLD_MAX_ENTRIES, or a threshold lowered inside the
+        interval). An empty backlog dispatches nothing."""
+        size = self._fold_size(bank.shape[0])
+        entries = int(np.count_nonzero(hot))
+        if slots.shape[0] > size:
+            slots, idx, rho = slots[hot], idx[hot], rho[hot]
+        n = slots.shape[0] if entries else 0
+        self._note_fold(snap, -(-n // size), entries)
+        length = size + batch_hll.FOLD_WINDOW
+        for i in range(0, n, size):
+            m = min(size, n - i)
+            chunk_slots = np.empty(length, np.int32)
+            chunk_pay = np.empty(length, np.int32)
+            chunk_slots[:m] = slots[i:i + m]
+            chunk_slots[m:] = batch_hll.FOLD_PAD
+            np.left_shift(idx[i:i + m], 8, out=chunk_pay[:m])
+            chunk_pay[:m] |= rho[i:i + m]
+            chunk_pay[m:] = 0
+            bank = batch_hll.fold_backlog(bank, chunk_slots, chunk_pay)
+        return bank
 
     def readout(self, snap: dict, timing=None, collect: bool = True) -> dict:
         """Both halves back to back; with `collect=False` (the columnar
