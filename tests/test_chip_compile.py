@@ -212,6 +212,29 @@ def test_backlog_fold_updates_the_bank_in_place(one_chip, tpu_segment_reduce,
     assert ma.temp_size_in_bytes < rows * batch_hll.M // 8
 
 
+# `compact` at the 131,072 rows of `timers100k` (and `timers100k-zipf`,
+# where it runs about once a batch): the table's four (K, C) grids are
+# donated and updated in place where the row mask says, and the one
+# output besides them is the scalar `done` handle that its completion
+# stamp holds (core/deviceobs.py) while the next apply consumes the state.
+
+def test_compact_updates_the_table_in_place_beside_its_stamp_handle(
+        one_chip, tpu_segment_reduce):
+    rows = 131072
+    state = _shapes(jax.eval_shape(lambda: batch_tdigest.init_state(rows)),
+                    one_chip)
+    lowered = batch_tdigest.compact.lower(
+        state, jax.ShapeDtypeStruct((rows,), jnp.bool_, sharding=one_chip))
+    _state_out, done = lowered.out_info
+    assert done.shape == () and done.dtype == f32
+    compiled = lowered.compile()
+    assert "tpu" in compiled.as_text().lower()
+    ma = compiled.memory_analysis()
+    grids = 4 * rows * C * 4
+    assert ma.alias_size_in_bytes >= grids
+    assert ma.output_size_in_bytes - ma.alias_size_in_bytes <= 4096
+
+
 # -- the four-shard deployment's two heaviest collectives -------------------
 #
 # `global100k-shards4` (benchmark/configs) merges, every flush, four

@@ -356,16 +356,18 @@ class TestKernelRegistry:
 
     def test_watcher_under_many_flush_threads(self):
         """More threads than cores hand rounds to the one watcher under
-        a short switch interval: no hand-off is lost or stamped twice,
-        `join` returns only once all are stamped, and the spans of the
-        one device never overlap, whoever handed them over."""
+        a short switch interval, a compact's handle after each readout:
+        no hand-off is lost or stamped twice, `join` returns only once
+        all readouts are stamped, every compact is booked once, and the
+        spans of the one device never overlap, whoever handed them
+        over."""
         import sys
 
         import jax.numpy as jnp
 
         obs = DeviceObservatory()
         watcher = obs.readout_watcher()
-        threads, per_thread, errors = 24, 40, []
+        threads, per_thread, errors, booked = 24, 40, [], []
         rounds = [FlushRound() for _ in range(threads)]
         handle = jnp.zeros(4)
         [device] = [f"{d.platform}:{d.id}" for d in handle.devices()]
@@ -375,6 +377,8 @@ class TestKernelRegistry:
                 for i in range(per_thread):
                     watcher.watch(rnd, f"f{i % 4}", {device: [handle + i]},
                                   time.perf_counter())
+                    watcher.watch_compact(handle - i, time.perf_counter(),
+                                          booked.append)
                     if i % 8 == 7 and not watcher.join():
                         errors.append("join timed out")
             except Exception as e:   # pragma: no cover - the assertion
@@ -393,7 +397,14 @@ class TestKernelRegistry:
         finally:
             sys.setswitchinterval(old)
         assert watcher.join() and not errors, errors
+        # a last readout: the compacts handed over before it are booked
+        # once it is stamped
+        last = FlushRound()
+        watcher.watch(last, "f0", {device: [handle]}, time.perf_counter())
+        assert watcher.join() and len(last.spans) == 1
         assert watcher._open == 0 and not watcher._handed
+        assert len(booked) == threads * per_thread
+        assert all(s >= 0 for s in booked)
         spans = [s for rnd in rounds for s in rnd.spans]
         assert [len(rnd.spans) for rnd in rounds] == [per_thread] * threads
         assert {s["device"] for s in spans} == {device}
@@ -405,9 +416,135 @@ class TestKernelRegistry:
         for (_s0, e0), (s1, _e1) in zip(ends, ends[1:]):
             assert e0 <= s1 + 1e-9
         counted = {k["family"]: k for k in obs.kernel_report()["kernels"]}
-        assert sum(k["wall"]["count"] for k in counted.values()) == len(spans)
-        assert sum(k["dispatches"] for k in counted.values()) == len(spans)
+        stamped = len(spans) + 1   # and the last readout's
+        assert sum(k["wall"]["count"] for k in counted.values()) == stamped
+        assert sum(k["dispatches"] for k in counted.values()) == stamped
         obs.close()
+
+    def test_compact_stamps_are_booked_once_off_the_dispatchers_thread(
+            self):
+        """A key past its 128 staging slots within one batch (dense)
+        and the whole-table compact its next batch forces, applied on a
+        thread of its own as the dispatcher applies them: each compact's
+        `done` handle is stamped once, on the watcher's thread, and its
+        seconds booked to `compact_chip_seconds_total[path]`; the
+        flush's readout-path compact is booked under `readout`. The
+        flush's join covers every compact handed over before its
+        readout."""
+        store = _mk_store(histo_capacity=64, batch_cap=256)
+        obs = DeviceObservatory()
+        store.attach_deviceobs(obs)
+        histos = store.histos
+        booked = []
+        book = histos._book_compact_chip
+
+        def recording(path, seconds):
+            booked.append((path, seconds, threading.current_thread().name))
+            book(path, seconds)
+
+        histos._book_compact_chip = recording
+        hot = [b"hot:%d.5|ms" % i for i in range(200)]
+
+        def dispatcher():
+            for _ in range(3):   # dense, then dense after a compact, twice
+                _feed_store(store, hot)
+
+        t = threading.Thread(target=dispatcher, name="ingest-dispatch")
+        t.start()
+        t.join(60.0)
+        # one more dense batch stays pending: it compacts on the flush
+        for line in hot:
+            Parser().parse_metric_fast(line, store.process)
+        flush_columnstore_batch(store, True, PCTS, AGGS)
+        assert histos.compacts_total == {"live": 2, "readout": 1}
+        assert histos.dense_keys_total == {"live": 3, "readout": 1}
+        assert sorted(p for p, _s, _n in booked) == ["live", "live",
+                                                     "readout"]
+        assert {n for _p, _s, n in booked} == {"readout-watcher"}
+        for path in ("live", "readout"):
+            mine = [s for p, s, _n in booked if p == path]
+            assert all(s >= 0 for s in mine)
+            assert histos.compact_chip_seconds_total[path] == sum(mine)
+        rows = {(r[0], tuple(r[3])): r[2] for r in store.telemetry_rows()
+                if r[0].startswith("ingest.tdigest.")}
+        assert rows[("ingest.tdigest.compact_chip_seconds_total",
+                     ("path:live",))] == histos.compact_chip_seconds_total[
+                         "live"]
+        assert rows[("ingest.tdigest.dense_keys_total",
+                     ("path:readout",))] == 1
+        obs.close()
+
+    def test_compact_hand_off_never_waits_and_join_waits_for_readouts(self):
+        """A compact still on the chip holds the watcher, not the thread
+        that hands the next ones over; the flush's `join` returns once
+        its readouts are stamped, whatever compacts queue behind them;
+        each compact is booked once, in hand-over order, when the chip
+        has done it."""
+        import jax.numpy as jnp
+
+        obs = DeviceObservatory()
+        watcher = obs.readout_watcher()
+        release = threading.Event()
+        booked = []
+        handle = jnp.zeros(4)
+        [dev] = handle.devices()
+
+        class OnTheChip:
+            """A compact's `done` handle whose program has not ended."""
+
+            def devices(self):
+                return {dev}
+
+            def block_until_ready(self):
+                release.wait(30.0)
+                return self
+
+        rnd = FlushRound()
+        watcher.watch(rnd, "histogram",
+                      {f"{dev.platform}:{dev.id}": [handle]},
+                      time.perf_counter())
+        t0 = time.perf_counter()
+        for i in range(50):
+            watcher.watch_compact(
+                OnTheChip(), time.perf_counter(),
+                lambda s, i=i: booked.append(
+                    (i, s, threading.current_thread().name)))
+        handed_s = time.perf_counter() - t0
+        assert watcher.join()
+        assert [s["name"] for s in rnd.spans] == ["chip_busy"]
+        assert not booked and handed_s < 1.0
+        release.set()
+        deadline = time.monotonic() + 30.0
+        while len(booked) < 50 and time.monotonic() < deadline:
+            time.sleep(0.01)
+        assert [i for i, _s, _n in booked] == list(range(50))
+        assert {n for _i, _s, n in booked} == {"readout-watcher"}
+        assert all(s >= 0 for _i, s, _n in booked)
+        assert watcher.join() and watcher._open == 0
+        obs.close()
+
+    def test_disabled_observatory_stamps_no_compact(self):
+        """Under `device_observatory: false` the compacts are counted and
+        the dense keys too, but nothing stamps them: no watcher, no
+        chip seconds, no row."""
+        server, _obs = mk_server(device_observatory=False)
+        try:
+            hot = [b"hot:%d.5|ms" % i for i in range(200)]
+            _feed(server, hot)
+            _feed(server, hot)
+            server.flush()
+            histos = server.store.histos
+            assert histos.compacts_total["live"] == 1
+            assert histos.dense_keys_total["live"] == 2
+            assert histos.compact_chip_seconds_total == {"live": 0.0,
+                                                         "readout": 0.0}
+            assert server.deviceobs._watcher is None
+            names = {r[0] for r in server.store.telemetry_rows()}
+            assert "ingest.tdigest.dense_keys_total" in names
+            assert "ingest.tdigest.compact_chip_seconds_total" not in names
+        finally:
+            server.config.flush_on_shutdown = False
+            server.shutdown()
 
     def test_disabled_observatory_has_no_watcher(self):
         obs = DeviceObservatory(enabled=False)

@@ -14,6 +14,7 @@ rows, and WAL-backfilled timestamp lines.
 
 from __future__ import annotations
 
+import dataclasses
 import json
 import struct
 import sys
@@ -408,11 +409,12 @@ class _Flushes:
     and tags are the tables' cached objects, the same from flush to
     flush for a row's lifetime."""
 
-    def __init__(self):
+    def __init__(self, rows=True):
         self.store = ColumnStore(counter_capacity=64, gauge_capacity=64,
                                  histo_capacity=64, set_capacity=32,
                                  batch_cap=256)
         self.parser = Parser()
+        self.rows = rows
 
     def flush(self, lines, reclaim_idle=0):
         for line in lines:
@@ -421,6 +423,9 @@ class _Flushes:
         batch, _fwd = flush_columnstore_batch(self.store, False, PCTS, AGGS)
         if reclaim_idle:
             self.store.counters.reclaim_idle(reclaim_idle)
+        if not self.rows:   # sections that do not say their table rows
+            batch.sections = [dataclasses.replace(sec, rows=None)
+                              for sec in batch.sections]
         return batch
 
 
@@ -466,11 +471,14 @@ def test_arena_is_reused_whole_by_a_second_flush_of_the_same_keys():
     assert b'"host":"other"' in joined and b'"device":"sda"' in joined
 
 
-def test_arena_rows_after_a_key_that_comes_or_goes_are_looked_up_again():
+@pytest.mark.parametrize("rows", [True, False], ids=["shelf", "by_position"])
+def test_arena_rows_after_a_key_that_comes_or_goes_are_looked_up_again(rows):
     """A key that stops reporting shifts the rows behind it in its
-    section, and so does its return: those rows miss the arena (their
-    prefixes come from `_frags`), the rest of the flush is reused."""
-    flushes, sink = _Flushes(), _dd_sink()
+    section, and so does its return: those rows miss the arena. Where
+    the section says its table rows, they are found again on its row
+    shelf and nothing renders; else their prefixes come from `_frags`,
+    the rest of the flush reused by position."""
+    flushes, sink = _Flushes(rows), _dd_sink()
     enc = _dd_encoder(sink, "native")
     _assert_native_is_the_loop(enc, sink, flushes.flush(_key_lines()))
     without = [i for i in range(10) if i != 5]
@@ -484,7 +492,8 @@ def test_arena_rows_after_a_key_that_comes_or_goes_are_looked_up_again():
                                flushes.flush(_key_lines(without)))
     assert enc.prefix_renders == 0
     _assert_native_is_the_loop(enc, sink, flushes.flush(_key_lines()))
-    assert enc.prefix_renders == behind + 1   # k.c5 and the rows behind it
+    # by position: k.c5 and the rows behind it
+    assert enc.prefix_renders == (0 if rows else behind + 1)
     _assert_native_is_the_loop(enc, sink, flushes.flush(_key_lines()))
     assert enc.prefix_renders == 0
 
@@ -509,9 +518,12 @@ def test_arenas_outlive_a_flush_of_other_sections_and_age_out(monkeypatch):
     """An interval with one stray series (a server's own, alone in its
     flush) leaves the arenas of the sections it lacks in place; a
     section that stays away longer than `ARENA_IDLE_FLUSHES` flushes
-    renders anew."""
+    loses its arena but finds its rows on its shelf, and one away longer
+    than `SHELF_IDLE_FLUSHES` renders anew."""
     from veneur_tpu.core import egress
 
+    monkeypatch.setattr(egress, "SHELF_IDLE_FLUSHES",
+                        egress.ARENA_IDLE_FLUSHES + 2)
     flushes, sink = _Flushes(), _dd_sink()
     enc = _dd_encoder(sink, "native")
     full = flushes.flush(_key_lines())
@@ -530,7 +542,60 @@ def test_arenas_outlive_a_flush_of_other_sections_and_age_out(monkeypatch):
     assert len(enc._arenas) == 1
     again = flushes.flush(_key_lines())
     _assert_native_is_the_loop(enc, sink, again)
+    assert enc.prefix_renders == 0
+    for _ in range(egress.SHELF_IDLE_FLUSHES + 1):
+        stray = flushes.flush([b"stray.unique:u1|s|#service:me"])
+        _assert_native_is_the_loop(enc, sink, stray)
+    assert len(enc._arenas) == len(enc._shelves) == 1
+    again = flushes.flush(_key_lines())
+    _assert_native_is_the_loop(enc, sink, again)
     assert enc.prefix_renders == _section_rows(again)
+
+
+def test_shelf_serves_a_whole_flush_after_two_halves_and_quiet_flushes():
+    """A server that flushed its keys only in parts (a tick that split
+    a burst), then a few flushes with none of them, renders nothing for
+    the first flush of them all: every row is on its section's shelf."""
+    from veneur_tpu.core import egress
+
+    flushes, sink = _Flushes(), _dd_sink()
+    enc = _dd_encoder(sink, "native")
+    for half in ([0, 1, 2, 3, 4], [0, 5, 6, 7, 8, 9]):
+        _assert_native_is_the_loop(
+            enc, sink, flushes.flush(_key_lines(half, tail=half[-1] == 9)))
+    for _ in range(egress.ARENA_IDLE_FLUSHES + 1):
+        _assert_native_is_the_loop(
+            enc, sink, flushes.flush([b"stray.unique:u1|s|#service:me"]))
+    whole = flushes.flush(_key_lines())
+    bodies = _assert_native_is_the_loop(enc, sink, whole)
+    assert enc.prefix_renders == 0
+    assert enc.native_rows == _section_rows(whole) - 1   # dropme.x
+    assert b"dropme.x" not in b"".join(bodies)
+
+
+def test_shelf_lays_its_bytes_anew_when_rows_render_again(monkeypatch):
+    """A row re-tagged flush after flush appends a prefix each time; the
+    shelf lays its live prefixes out anew once the garbage outgrows
+    them, and the bodies stay the Python loop's."""
+    from veneur_tpu.core import egress
+
+    monkeypatch.setattr(egress, "SHELF_SLACK_BYTES", 0)
+    flushes, sink = _Flushes(), _dd_sink()
+    enc = _dd_encoder(sink, "native")
+    _assert_native_is_the_loop(enc, sink, flushes.flush(_key_lines()))
+    filled = []
+    for k in range(16):
+        batch = flushes.flush(_key_lines())
+        section = batch.sections[0]
+        section.tags[2] = list(section.tags[2]) + [f"late:{k}"]
+        bodies = _assert_native_is_the_loop(enc, sink, batch)
+        assert enc.prefix_renders == 1
+        assert b"".join(bodies).count(b'"late:%d"' % k) == 1
+        [shelf] = [s for s in enc._shelves
+                   if s.holds(int(section.rows[0]), section.names[0])]
+        assert shelf.filled <= 2 * shelf.live
+        filled.append(shelf.filled)
+    assert any(b < a for a, b in zip(filled, filled[1:]))   # laid anew
 
 
 def test_arena_sees_a_row_recycled_to_another_key():

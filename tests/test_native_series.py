@@ -2,7 +2,8 @@
 own: `vnt_dd_series`' float formatting against CPython's `repr(float)`
 (through `_json_num`, the Python loop's renderer), its room check, the
 pointer compare that decides arena reuse, and that a call releases the
-GIL. What the encoder builds on top is pinned by tests/test_egress.py.
+GIL, and the row-by-row compare with a row shelf. What the encoder
+builds on top is pinned by tests/test_egress.py.
 """
 
 from __future__ import annotations
@@ -38,8 +39,8 @@ def _series(prefixes, values, mid=b""):
     cap = int(offsets[-1]) + n * lib.vnt_dd_series_room(len(mid))
     out = np.empty(max(cap, 1), np.uint8)
     wrote = lib.vnt_dd_series(
-        b"".join(prefixes), offsets.ctypes.data, values.ctypes.data, n,
-        mid, len(mid), out.ctypes.data, cap)
+        b"".join(prefixes), offsets.ctypes.data, offsets.ctypes.data + 8,
+        values.ctypes.data, n, mid, len(mid), out.ctypes.data, cap)
     assert 0 <= wrote <= cap
     return out[:wrote].tobytes()
 
@@ -149,9 +150,10 @@ def test_series_refuses_a_buffer_without_room_for_the_longest_value():
     room = lib.vnt_dd_series_room(0)
     assert room >= len(repr(float(values[0]))) + len(b"]]},")
     out = np.empty(2 * room, np.uint8)
-    assert lib.vnt_dd_series(b"", offsets.ctypes.data, values.ctypes.data,
+    beg, end = offsets.ctypes.data, offsets.ctypes.data + 8
+    assert lib.vnt_dd_series(b"", beg, end, values.ctypes.data,
                              2, b"", 0, out.ctypes.data, 2 * room - 1) == -1
-    assert lib.vnt_dd_series(b"", offsets.ctypes.data, values.ctypes.data,
+    assert lib.vnt_dd_series(b"", beg, end, values.ctypes.data,
                              2, b"", 0, out.ctypes.data, 2 * room) > 0
 
 
@@ -177,6 +179,40 @@ def test_changed_rows_compares_element_identity():
     assert compare(kept_names.copy(), kept_tags.copy()) == []
     assert compare(new_names, kept_tags) == [1]
     assert compare(new_names, new_tags) == [1, 3]
+
+
+def test_series_read_prefixes_wherever_they_lie():
+    """`beg`/`end` need not be one run of offsets: a row shelf's
+    prefixes lie in any order, with other bytes between them."""
+    lib = native.load_series()
+    base = b"..B..A..."
+    beg, end = np.array([5, 2], np.int64), np.array([6, 3], np.int64)
+    values = np.array([1.0, 2.0])
+    out = np.empty(128, np.uint8)
+    wrote = lib.vnt_dd_series(base, beg.ctypes.data, end.ctypes.data,
+                              values.ctypes.data, 2, b":", 1,
+                              out.ctypes.data, 128)
+    assert out[:wrote].tobytes() == b"A:1.0]]},B:2.0]]}"
+
+
+def test_changed_at_compares_with_the_shelf_row_by_row():
+    lib = native.load_series()
+    kept_names = np.array([None, "n1", "n2", None, "n4"], object)
+    kept_tags = np.empty(5, object)
+    kept_tags[1:3], kept_tags[4] = [["a"], ["b"]], ["c"]
+    rows = np.array([1, 2, 3, 4], np.int64)
+    names = np.array([kept_names[1], "".join(["n", "2"]), "n3",
+                      kept_names[4]], object)
+    tags = np.empty(4, object)
+    tags[0], tags[1], tags[2], tags[3] = (kept_tags[1], kept_tags[2], ["x"],
+                                          list(kept_tags[4]))
+    changed = np.empty(4, np.int64)
+    n = lib.vnt_dd_changed_at(
+        names.ctypes.data, tags.ctypes.data, kept_names.ctypes.data,
+        kept_tags.ctypes.data, rows.ctypes.data, 4, changed.ctypes.data)
+    # an equal str that is another object, a row never shelved, an equal
+    # list that is another object
+    assert changed[:n].tolist() == [1, 2, 3]
 
 
 def test_the_library_is_a_cdll_not_a_pydll():
@@ -214,8 +250,8 @@ def test_a_second_thread_runs_while_a_large_section_encodes():
             time.sleep(0.001)
         before, t0 = ticks[0], time.perf_counter()
         wrote = lib.vnt_dd_series(
-            arena, offsets.ctypes.data, values.ctypes.data, n, mid,
-            len(mid), out.ctypes.data, cap)
+            arena, offsets.ctypes.data, offsets.ctypes.data + 8,
+            values.ctypes.data, n, mid, len(mid), out.ctypes.data, cap)
         after, wall_s = ticks[0], time.perf_counter() - t0
     finally:
         stop.set()

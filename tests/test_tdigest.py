@@ -114,7 +114,7 @@ class TestBatchedDigest:
             state = btd.apply_batch(state, rows, vals, wts)
         # fold staged batches into the main grid, as the table does
         # periodically and at every snapshot
-        return btd.compact(state)
+        return btd.compact(state, np.ones(num_keys, bool))[0]
 
     def test_matches_scalar_reference_uniform(self):
         rng = random.Random(11)
@@ -174,9 +174,13 @@ class TestBatchedDigest:
         assert float(np.sum(stage_w[0])) == 0.0
         assert float(np.sum(stage_w[1])) == 0.0
         assert float(np.sum(stage_w[2])) == 64.0
-        # after compaction the staged weight lands in row 2's main grid
-        state = btd.compact(state)
+        # after compaction the staged weight lands in row 2's main grid;
+        # a compact of row 2 alone leaves rows 0/1 as they were
+        state, _done = btd.compact(state, np.array([False, False, True]))
         assert float(np.sum(np.asarray(state["weights"])[2])) == 64.0
+        assert float(np.sum(np.asarray(state["sweights"])[2])) == 0.0
+        np.testing.assert_array_equal(before[0], np.asarray(state["wv"])[0])
+        np.testing.assert_array_equal(before[1], np.asarray(state["wv"])[1])
 
     def test_centroid_budget(self):
         rng = random.Random(19)
@@ -293,7 +297,7 @@ class TestFusedExportFlush:
             vals = rng.normal(50, 20, B).astype(np.float32)
             wts = rng.choice([1.0, 2.0], B).astype(np.float32)
             state = bt.apply_batch(state, rows, vals, wts)
-            state = bt.compact(state)
+            state, _done = bt.compact(state, np.ones(K, bool))
         rows = rng.integers(0, K, B).astype(np.int32)
         vals = rng.lognormal(1, 1, B).astype(np.float32)
         wts = np.ones(B, np.float32)
@@ -304,7 +308,7 @@ class TestFusedExportFlush:
         f_means, f_w, f_min, f_max, f_recip = bt.unpack_export(
             export_packed)
 
-        legacy = bt.compact(dict(state))
+        legacy, _done = bt.compact(dict(state), np.ones(K, bool))
         legacy_packed = bt.flush_quantiles_packed(
             legacy, ps, fold_staging=False)
         legacy_out = bt.unpack_flush(np.asarray(legacy_packed), len(ps))

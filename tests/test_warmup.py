@@ -2,15 +2,17 @@
 `warm_programs`) and the compaction counters of the digest table.
 
 (a) once the warm-up thread has ended, the first batch, the first
-    staging overflow (one key past 128 samples: `compact`), the first
-    flush with data and the first flush over recycled generations
-    compile nothing, by JAX's own compile events (what the benchmark's
-    `harness/sut.py` `CompileMeter` counts), on one device and on four;
+    staging overflow (one key past 128 samples: `compact`), a key dense
+    within one batch (the k-bucket fold), the first flush with data and
+    the first flush over recycled generations compile nothing, by JAX's
+    own compile events (what the benchmark's `harness/sut.py`
+    `CompileMeter` counts), on one device and on four;
 (b) a timers-only server fed over UDP, whole-table compacts included,
     against `ops/tdigest_ref.py`, and `ingest.tdigest.compacts_total`
     equal to the overflows the traffic forced;
 (c) the `warmup` event, `warmup.seconds_total{family}` and the
-    compaction rows at `/metrics`;
+    compaction rows at `/metrics` (the dense keys' and the compacts'
+    chip seconds with (a)'s dense key);
 (d) a program of the list that raises is a `warmup_failed` event, and
     the other families are still warmed.
 
@@ -154,6 +156,44 @@ def test_nothing_compiles_after_the_warm_up(shards, caps):
                 assert got["w.s"] == 5.0
                 assert got["w.cold.7.min"] == 21.5
         assert after.count == 0
+    finally:
+        stop(server)
+
+
+@pytest.mark.parametrize("shards,caps", [
+    (1, (184, 168, 144, 44, 36, 328)),
+    pytest.param(4, (192, 160, 152, 52, 44, 312), marks=pytest.mark.mesh)])
+def test_a_dense_key_compiles_nothing_and_its_counters_reach_metrics(
+        shards, caps):
+    """(a) for a key dense within one batch (200 samples of one timer, past
+    its 128 staging slots: `host_slots` folds them into batch-local
+    k-buckets and marks the row full), the compact its next batch forces,
+    and one more under the flush: nothing compiles, and both counters of
+    the digest table are at `/metrics` with the observatory on, by path."""
+    server, sink = started(config(shards, caps))
+    try:
+        histos = server.store.histos
+        dense = [b"w.dense:%d.25|ms" % j for j in range(200)]
+        with Compiles() as after:
+            feed(server, dense)
+            feed(server, dense)
+            for line in dense:
+                server.handle_metric_packet(line)
+            server.flush()
+            got = {m.name: m.value for m in sink.wait_flush(30.0)}
+        assert after.count == 0
+        assert got["w.dense.count"] == 600.0
+        assert got["w.dense.max"] == 199.25 and got["w.dense.min"] == 0.25
+        assert histos.dense_keys_total == {"live": 2, "readout": 1}
+        assert histos.compacts_total == {"live": 1, "readout": 1}
+        rows = metric_rows(server, "veneur_ingest_tdigest_")
+        for path, n in (("live", 2), ("readout", 1)):
+            assert rows['veneur_ingest_tdigest_dense_keys_total'
+                        '{path="%s"}' % path] == n
+            # the flush's join covered the compacts handed over before
+            # its readout: both are stamped
+            assert rows['veneur_ingest_tdigest_compact_chip_seconds_total'
+                        '{path="%s"}' % path] > 0
     finally:
         stop(server)
 
@@ -355,7 +395,7 @@ def test_cache_events_count_this_threads_hits_and_misses(
 
 def test_a_failed_warm_up_is_an_event_and_the_rest_is_still_warmed(
         monkeypatch, caplog):
-    def refuse(state):
+    def refuse(*args):
         raise RuntimeError("the compiler refused")
 
     real = columnstore.HistoTable.warm_programs

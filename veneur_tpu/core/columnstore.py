@@ -177,6 +177,16 @@ def _result(carry, result):
     return result
 
 
+def _state_and_fold(state, cols):
+    """A compact's arguments: the state and a fold mask of its rows."""
+    return (state, np.zeros(state["wv"].shape[0], bool))
+
+
+def _result_state(carry, result):
+    """The state of a (state, done) pair (`batch_tdigest.compact`)."""
+    return result[0]
+
+
 class WarmupFailed(Exception):
     """A program of the warm-up list raised: which one, for the
     `warmup_failed` event."""
@@ -1269,13 +1279,13 @@ class HistoTable(_BaseTable):
     """Histograms and timers, all scopes, one digest grid.
 
     Batches rank-park raw samples into the digest staging grid (O(batch)
-    per apply, exact); the host tracks a conservative per-key staged
-    bound (sum of per-batch max row counts) and runs the mean-sorted
-    `compact` — the only capacity-proportional pass — before any key
-    could overflow its C staging slots, and always at snapshot. This
-    mirrors the reference's amortized temp-buffer merge
-    (merging_digest.go:115-140): sparse keys stage dozens of batches
-    per compact, dense keys compact about once per batch."""
+    per apply, exact); the host tracks each key's staged count and runs
+    the mean-sorted `compact` — the only capacity-proportional pass —
+    over the rows that would overflow their C staging slots (and those
+    half full), before the batch that would. This mirrors the reference's amortized temp-buffer
+    merge (merging_digest.go:115-140): a sparse key stages until its own
+    slots fill, however often a hot key compacts, and a dense key
+    compacts about once per batch."""
 
     def _init_pending(self):
         self._prow = np.full(self.batch_cap, PAD_ROW, np.int32)
@@ -1289,9 +1299,15 @@ class HistoTable(_BaseTable):
         # whole-table compacts a key past its C staging slots forced,
         # and their host wall, by the path that ran them: `live` under
         # apply_lock on the dispatcher's thread, `readout` on the flush
-        # thread over the captured generation's last pending batch
+        # thread over the captured generation's last pending batch;
+        # the keys dense within one batch (> C samples), which
+        # `host_slots` folded into batch-local k-buckets; and the
+        # compacts' device seconds from completion stamps, booked by
+        # the device observatory's watcher thread (absent without one)
         self.compacts_total = {"live": 0, "readout": 0}
         self.compact_seconds_total = {"live": 0.0, "readout": 0.0}
+        self.dense_keys_total = {"live": 0, "readout": 0}
+        self.compact_chip_seconds_total = {"live": 0.0, "readout": 0.0}
 
     def _init_arrays(self):
         self._init_pending()
@@ -1329,29 +1345,52 @@ class HistoTable(_BaseTable):
                                             self._staged_counts)
         self._applies += 1
 
-    def _compact(self, state, path: str):
-        """One whole-table (or whole-shard) compact, counted and timed:
-        the wall is the dispatch's; the kernel runs behind it."""
+    def _compact(self, state, fold, path: str):
+        """One compact of the rows `fold` marks (a program over the
+        whole table, or shard), counted and timed: the wall is the
+        dispatch's; the kernel runs behind it. With a device observatory
+        its `done` handle goes to the completion watcher, which books
+        the device seconds to `compact_chip_seconds_total[path]` off
+        this thread."""
         t0 = time.perf_counter()
-        state = batch_tdigest.compact(state)
+        state, done = batch_tdigest.compact(state, fold)
         self.compacts_total[path] += 1
         self.compact_seconds_total[path] += time.perf_counter() - t0
+        obs = self._deviceobs
+        if obs is not None and obs.enabled:
+            obs.readout_watcher().watch_compact(
+                done, t0, partial(self._book_compact_chip, path))
         return state
+
+    def _book_compact_chip(self, path: str, seconds: float) -> None:
+        # the watcher thread is the only writer
+        self.compact_chip_seconds_total[path] += seconds
+
+    def _slots(self, state, cols, staged_counts, path: str):
+        """`host_slots` for one batch, compacting first the rows whose
+        staging would overflow; counts the keys it folded dense. The
+        compact costs the same whatever it folds, so the rows at least
+        half full (`batch_tdigest.FOLD_ALONG`) fold with them: they
+        would force compacts of their own soon after."""
+        rows, vals, wts = cols
+        slots, overflow, dense = batch_tdigest.host_slots(
+            rows, vals, wts, staged_counts)
+        if overflow is not None:
+            fold = overflow | (staged_counts >= batch_tdigest.FOLD_ALONG)
+            state = self._compact(state, fold, path)
+            staged_counts[fold] = 0
+            slots, _, dense = batch_tdigest.host_slots(
+                rows, vals, wts, staged_counts)
+        self.dense_keys_total[path] += dense
+        return state, slots
 
     def _apply_cols_state(self, state, cols, staged_counts,
                           path: str = "live"):
         """Pure batch apply over an explicit (state, staging-occupancy)
         pair: the live path passes the table's own, the flush readout
         passes the captured generation's."""
-        rows, vals, wts = cols
-        slots, overflow = batch_tdigest.host_slots(
-            rows, vals, wts, staged_counts)
-        if overflow:
-            state = self._compact(state, path)
-            staged_counts[:] = 0
-            slots, _ = batch_tdigest.host_slots(
-                rows, vals, wts, staged_counts)
-        return batch_tdigest.apply_batch(state, rows, vals, wts, slots)
+        state, slots = self._slots(state, cols, staged_counts, path)
+        return batch_tdigest.apply_batch(state, *cols, slots)
 
     def _fresh_state_at(self, capacity: int):
         return batch_tdigest.init_state(capacity)
@@ -1370,8 +1409,8 @@ class HistoTable(_BaseTable):
                             state, *cols,
                             np.zeros(cols[0].shape[0], np.int32)),
                         then=_result),
-            WarmProgram("compact", batch_tdigest.compact, _state_only,
-                        then=_result),
+            WarmProgram("compact", batch_tdigest.compact, _state_and_fold,
+                        then=_result_state),
             WarmProgram("readout", readout, _state_only, static=(ps,)),
             WarmProgram("reset", _reset_tdigest_donated, _state_only,
                         then=_result)]
@@ -2548,12 +2587,21 @@ class ColumnStore:
             rows.append(("ingest.apply.lock_wait_seconds_total", "counter",
                          t.apply_lock_wait_seconds_total, tags))
             for path, n in getattr(t, "compacts_total", {}).items():
-                # the digest table only: compacts its keys forced
+                # the digest table only: compacts its keys forced, and
+                # the keys dense within a batch
                 rows.append(("ingest.tdigest.compacts_total", "counter",
                              float(n), [f"path:{path}"]))
                 rows.append(("ingest.tdigest.compact_seconds_total",
                              "counter", t.compact_seconds_total[path],
                              [f"path:{path}"]))
+                rows.append(("ingest.tdigest.dense_keys_total", "counter",
+                             float(t.dense_keys_total[path]),
+                             [f"path:{path}"]))
+                if t._deviceobs is not None:
+                    rows.append((
+                        "ingest.tdigest.compact_chip_seconds_total",
+                        "counter", t.compact_chip_seconds_total[path],
+                        [f"path:{path}"]))
             pending = getattr(t, "_n", None)
             if pending is not None:  # statuses have no batch buffers
                 rows.append(("columnstore.batch_cap", "gauge",

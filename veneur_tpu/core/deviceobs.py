@@ -32,7 +32,9 @@ for it, three planes wired through the existing registries:
   family and device in the flush round); `apply_s`, `merge_s`,
   `reset_s` and `prewarm_s` hold the HOST wall of an asynchronous
   dispatch (`merge_s`: of the `merge{family}` span, stacking
-  included), which says nothing of how long the chip took.
+  included), which says nothing of how long the chip took. The same
+  watcher stamps every t-digest `compact` (the table books its
+  seconds: `ingest.tdigest.compact_chip_seconds_total`).
 - **Shard-balance observatory** — computed at scrape time from the
   attached store's digest-routed tables: per-shard live rows and
   samples-routed, a digest-space occupancy histogram, the skew ratio
@@ -161,11 +163,20 @@ class _ReadoutWatcher:
     dispatch start, to the stamp. The span's wall also feeds
     `device.kernel.readout_s{family}`.
 
+    The t-digest table hands over each `compact`'s `done` handle the
+    same way (`watch_compact`, from the dispatcher's thread under
+    `apply_lock` or from the flush thread): stamped in its turn with the
+    readouts, its seconds go to the table's `book` callback and to no
+    span, and it moves the device's last stamp, so a readout's span no
+    longer holds a compact that finished before it. `join` waits only
+    for readouts: a compact dispatched after them is not the flush's.
+
     What a stamp can and cannot say. A chip runs its stream in order, so
     a span holds everything the device ran between two watched
     completions: programs nobody watches (the dispatcher's live applies
-    and compacts during the flush, the merges, resets) are booked to the
-    next watched program of that device. One watcher waits for the
+    during the flush, the merges, resets) are booked to the next
+    watched program of that device; a compact handed over after a
+    readout it ran ahead of reads 0. One watcher waits for the
     devices of a family in turn, so a device that finished before the
     one ahead of it reads late by the difference; and a stamp read while
     the flush thread holds the GIL is late by up to the interpreter's
@@ -178,7 +189,7 @@ class _ReadoutWatcher:
         self._obs = weakref.ref(obs)
         self._cv = threading.Condition()
         self._handed: deque = deque()
-        self._open = 0        # handed over and not yet stamped
+        self._open = 0        # readouts handed over and not yet stamped
         self._closed = False
         self._last: Dict[str, float] = {}   # device -> its last stamp
         self.thread = threading.Thread(
@@ -188,13 +199,25 @@ class _ReadoutWatcher:
     def watch(self, rnd, family: str, by_device: Dict[str, list],
               dispatched_at: float) -> None:
         with self._cv:
-            self._handed.append((rnd, family, by_device, dispatched_at))
+            self._handed.append((rnd, family, by_device, dispatched_at,
+                                 None))
             self._open += 1
             self._cv.notify_all()
 
+    def watch_compact(self, done, dispatched_at: float,
+                      book: Callable[[float], None]) -> None:
+        """A compact's `done` handle (`batch_tdigest.compact`); `book`
+        gets its device seconds on the watcher's thread. Never waits."""
+        [d] = done.devices()
+        by_device = {f"{d.platform}:{d.id}": [done]}
+        with self._cv:
+            self._handed.append((None, "histogram", by_device,
+                                 dispatched_at, book))
+            self._cv.notify_all()
+
     def join(self) -> bool:
-        """Until everything handed over is stamped: a wake-up once the
-        flush thread has synced the same handles itself."""
+        """Until every readout handed over is stamped: a wake-up once
+        the flush thread has synced the same handles itself."""
         with self._cv:
             return self._cv.wait_for(lambda: not self._open, self.JOIN_S)
 
@@ -210,17 +233,19 @@ class _ReadoutWatcher:
                 if not self._handed:
                     return
                 item = self._handed.popleft()
+            readout = item[0] is not None
             try:
                 self._stamp(*item)
             except Exception:
-                logger.exception("readout completion stamp failed")
+                logger.exception("completion stamp failed")
             finally:
                 del item   # the handles: nothing outlives its stamp
-                with self._cv:
-                    self._open -= 1
-                    self._cv.notify_all()
+                if readout:
+                    with self._cv:
+                        self._open -= 1
+                        self._cv.notify_all()
 
-    def _stamp(self, rnd, family, by_device, dispatched_at):
+    def _stamp(self, rnd, family, by_device, dispatched_at, book):
         import jax
 
         obs = self._obs()
@@ -232,6 +257,9 @@ class _ReadoutWatcher:
             done = time.perf_counter()
             start = max(self._last.get(device, 0.0), dispatched_at)
             done = self._last[device] = max(done, start)
+            if book is not None:   # a compact: no span, no registry row
+                book(done - start)
+                continue
             rnd.stamped("chip_busy", "readout", start, done,
                         family=family, device=device)
             if obs is not None:

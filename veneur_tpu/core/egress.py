@@ -40,6 +40,13 @@ FRAG_CACHE_CAP = 1 << 20
 # did without it: a quiet interval or two must not cost the next full
 # one its arenas, and a section that is gone must not be kept for ever
 ARENA_IDLE_FLUSHES = 2
+# a section's prefixes by table row (`_RowShelf`) outlive its arenas: a
+# warm-up or a quiet spell of several flushes must not cost the first
+# full flush after it every prefix; a section gone this long is dropped
+SHELF_IDLE_FLUSHES = 16
+# a shelf lays its bytes anew once they pass twice its live prefixes
+# and this much (rows rendered again leave their old bytes behind)
+SHELF_SLACK_BYTES = 1 << 20
 
 _MASK64 = (1 << 64) - 1
 _INF = float("inf")
@@ -96,6 +103,9 @@ class DatadogColumnarEncoder:
         # a section's arena, found again by the identity of its first
         # row: (id(name), id(tags), kind); the arena pins both objects
         self._arenas: Dict[tuple, _SectionArena] = {}
+        # the prefixes of the sections that carry their table rows, by
+        # row: where an arena misses, its rows are found here in bulk
+        self._shelves: List[_RowShelf] = []
         self._flushes = 0  # `encode_bodies` calls: what arenas age by
         # of the last `encode_bodies`: series the native encoder wrote,
         # and section rows whose prefix came from `_frag` (arena misses;
@@ -203,6 +213,8 @@ class DatadogColumnarEncoder:
         for key in [key for key, arena in self._arenas.items()
                     if arena.used + ARENA_IDLE_FLUSHES < self._flushes]:
             del self._arenas[key]
+        self._shelves = [shelf for shelf in self._shelves
+                         if shelf.used + SHELF_IDLE_FLUSHES >= self._flushes]
         if batch.bucket_sections:
             les = _dd_le_json()
             for bs in batch.bucket_sections:
@@ -280,7 +292,8 @@ class DatadogColumnarEncoder:
             cap = int(offsets[lo + k] - offsets[lo]) + k * room
             out = np.empty(cap, np.uint8)
             wrote = lib.vnt_dd_series(
-                arena.arena, offsets.ctypes.data + 8 * lo,
+                arena.ptr, arena.beg.ctypes.data + 8 * lo,
+                arena.end.ctypes.data + 8 * lo,
                 vals.ctypes.data + 8 * lo, k, mid, len(mid),
                 out.ctypes.data, cap)
             if wrote < 0:
@@ -298,17 +311,17 @@ class DatadogColumnarEncoder:
         `_frags` keys on; the kept arrays pin the objects, so an
         address cannot have been recycled), also where the section is
         only the kept one's first rows (keys at its end did not
-        report); else it is rebuilt, the rows that differ looked up
-        through `_frag`."""
+        report); else it is rebuilt: from the section's row shelf where
+        the section has its rows (a key that came or went, or a partial
+        flush, costs only the rows never rendered), else from the kept
+        arena by position; the rows that differ are looked up through
+        `_frag`."""
         names = np.ascontiguousarray(sec.names)
         tags = np.ascontiguousarray(sec.tags)
         n = names.shape[0]
         key = (id(names[0]), id(tags[0]), is_counter)
         kept = self._arenas.get(key)
-        if kept is None:
-            miss = range(n)
-            prefixes = [None] * n
-        else:
+        if kept is not None:
             same = min(n, kept.names.shape[0])
             changed = np.empty(same, np.int64)
             n_changed = lib.vnt_dd_changed_rows(
@@ -317,15 +330,144 @@ class DatadogColumnarEncoder:
                 same, changed.ctypes.data)
             if n_changed == 0 and n == same:
                 kept.used = self._flushes
+                if kept.shelf is not None:
+                    kept.shelf.used = self._flushes
                 return kept, kept.series_among(n)
-            miss = changed[:n_changed].tolist() + list(range(same, n))
-            prefixes = kept.prefixes[:n] + [None] * (n - same)
-        for i in miss:
-            prefixes[i] = self._frag(names[i], tags[i], is_counter)[1]
-        self.prefix_renders += len(miss)
-        arena = self._arenas[key] = _SectionArena(
-            names, tags, prefixes, self._flushes)
+        if sec.rows is not None:
+            arena = self._shelved(lib, sec, names, tags, is_counter)
+        else:
+            if kept is None or kept.prefixes is None:
+                miss = range(n)
+                prefixes = [None] * n
+            else:
+                miss = changed[:n_changed].tolist() + list(range(same, n))
+                prefixes = kept.prefixes[:n] + [None] * (n - same)
+            for i in miss:
+                prefixes[i] = self._frag(names[i], tags[i], is_counter)[1]
+            self.prefix_renders += len(miss)
+            arena = _SectionArena(names, tags, prefixes, self._flushes)
+        self._arenas[key] = arena
         return arena, arena.offsets.shape[0] - 1
+
+    def _shelved(self, lib, sec, names: np.ndarray, tags: np.ndarray,
+                 is_counter: bool) -> "_SectionArena":
+        """The section's arena from its row shelf: the shelf whose rows
+        hold the section's first or last series (same `str`, same type),
+        else a new one. A row whose name or tags list is another object
+        than the shelf's (never rendered, recycled to another key,
+        re-tagged) is looked up through `_frag` and shelved; the others
+        cost no Python: the arena reads their prefixes where the shelf
+        keeps them."""
+        rows = np.ascontiguousarray(sec.rows, np.int64)
+        n = rows.shape[0]
+        shelf = next((s for s in self._shelves
+                      if s.is_counter == is_counter
+                      and (s.holds(int(rows[0]), names[0])
+                           or s.holds(int(rows[-1]), names[n - 1]))), None)
+        if shelf is None:
+            shelf = _RowShelf(is_counter)
+            self._shelves.append(shelf)
+        shelf.used = self._flushes
+        shelf.reserve(int(rows.max()) + 1)
+        changed = np.empty(n, np.int64)
+        n_changed = lib.vnt_dd_changed_at(
+            names.ctypes.data, tags.ctypes.data, shelf.names.ctypes.data,
+            shelf.tags.ctypes.data, rows.ctypes.data, n, changed.ctypes.data)
+        if n_changed:
+            miss = changed[:n_changed]
+            shelf.put(rows[miss], names[miss], tags[miss], [
+                self._frag(names[i], tags[i], is_counter)[1]
+                for i in miss.tolist()])
+            self.prefix_renders += int(n_changed)
+        size = shelf.size[rows]
+        beg = shelf.beg[rows]
+        rendered = None
+        if (size < 0).any():
+            rendered = np.flatnonzero(size >= 0)
+            size, beg = size[rendered], beg[rendered]
+        return _SectionArena.laid(names, tags, shelf, beg, beg + size,
+                                  rendered, self._flushes)
+
+
+class _RowShelf:
+    """One section's prefixes by table row, kept across flushes (rows
+    ascend in a section, `FlushSection.rows`): the `names` and `tags`
+    objects each row rendered (None: never), which pin them, so an
+    address cannot have been recycled; the row's prefix, and where its
+    bytes lie in `data` (`beg`, `size`; size -1 where the sink's name
+    prefixes drop the row). A row rendered again appends; `data` is
+    laid anew when it holds more than twice the live bytes. `used` is
+    the flush that last read it."""
+
+    __slots__ = ("is_counter", "names", "tags", "prefixes", "beg", "size",
+                 "data", "filled", "live", "used")
+
+    def __init__(self, is_counter: bool):
+        self.is_counter = is_counter
+        self.names = np.empty(0, object)
+        self.tags = np.empty(0, object)
+        self.prefixes = np.empty(0, object)
+        self.beg = np.zeros(0, np.int64)
+        self.size = np.full(0, -1, np.int64)
+        self.data = np.empty(0, np.uint8)
+        self.filled = 0  # bytes of `data` written
+        self.live = 0    # of those, the rows' current prefixes
+        self.used = 0
+
+    def holds(self, row: int, name: str) -> bool:
+        return row < self.names.shape[0] and self.names[row] is name
+
+    def reserve(self, rows: int) -> None:
+        have = self.names.shape[0]
+        if rows <= have:
+            return
+        rows = max(rows, 2 * have)
+        for attr, fill in (("names", None), ("tags", None),
+                           ("prefixes", None), ("beg", 0), ("size", -1)):
+            old = getattr(self, attr)
+            grown = np.full(rows, fill, old.dtype)
+            grown[:have] = old
+            setattr(self, attr, grown)
+
+    def put(self, rows: np.ndarray, names: np.ndarray, tags: np.ndarray,
+            prefixes: List[Optional[bytes]]) -> None:
+        """Shelves the rows' objects and new prefixes (None: dropped)."""
+        self.live -= int(np.maximum(self.size[rows], 0).sum())
+        self.names[rows], self.tags[rows] = names, tags
+        fresh = np.empty(len(prefixes), object)
+        fresh[:] = prefixes
+        self.prefixes[rows] = fresh
+        size = np.fromiter((-1 if p is None else len(p) for p in prefixes),
+                           np.int64, len(prefixes))
+        self.size[rows] = size
+        blob = b"".join(p for p in prefixes if p is not None)
+        self.live += len(blob)
+        if self.filled + len(blob) > 2 * self.live + SHELF_SLACK_BYTES:
+            self._lay()   # the rows' older bytes are mostly garbage
+            return
+        at = self.filled + np.cumsum(np.maximum(size, 0)) - np.maximum(size, 0)
+        self.beg[rows] = at
+        self._append(blob)
+
+    def _append(self, blob: bytes) -> None:
+        end = self.filled + len(blob)
+        if end > self.data.shape[0]:
+            # a new array: arenas laid on the old one keep it
+            grown = np.empty(max(end, 2 * self.data.shape[0]), np.uint8)
+            grown[:self.filled] = self.data[:self.filled]
+            self.data = grown
+        self.data[self.filled:end] = np.frombuffer(blob, np.uint8)
+        self.filled = end
+
+    def _lay(self) -> None:
+        """`data` anew from the live prefixes alone, in row order."""
+        live = np.flatnonzero(self.size >= 0)
+        sizes = self.size[live]
+        self.beg[live] = np.cumsum(sizes) - sizes
+        self.data = np.empty(0, np.uint8)
+        self.filled = 0
+        self._append(b"".join(self.prefixes[live].tolist()))
+        self.live = self.filled
 
 
 class _SectionArena:
@@ -333,13 +475,16 @@ class _SectionArena:
     `names` and `tags` arrays it rendered (their elements are the
     column store's cached objects, the same for a row's lifetime), each
     row's prefix (None: dropped by the sink's name prefixes), and the
-    prefixes of the rows that render laid back to back in `arena`, row
-    `j` of them at `offsets[j]:offsets[j + 1]`. `rendered` indexes
-    those rows in the section, None when all render; `used` is the
-    flush that last encoded from it."""
+    prefixes of the rows that render, row `j` of them at
+    `arena[beg[j]:end[j]]` (`ptr` is the address `vnt_dd_series`
+    reads); `offsets` sums their sizes. `rendered` indexes those rows in
+    the section, None when all render; `used` is the flush that last
+    encoded from it. Built from `prefixes`, the arena lays them back to
+    back; one `laid` on a row `shelf` keeps no `prefixes` (None) and
+    reads the shelf's bytes."""
 
-    __slots__ = ("names", "tags", "prefixes", "arena", "offsets",
-                 "rendered", "used")
+    __slots__ = ("names", "tags", "prefixes", "arena", "ptr", "beg", "end",
+                 "offsets", "rendered", "used", "shelf")
 
     def __init__(self, names: np.ndarray, tags: np.ndarray,
                  prefixes: List[Optional[bytes]], used: int):
@@ -347,16 +492,36 @@ class _SectionArena:
         self.tags = tags
         self.prefixes = prefixes
         self.used = used
+        self.shelf = None
         kept = [p for p in prefixes if p is not None]
         self.rendered = None
         if len(kept) != len(prefixes):
             self.rendered = np.fromiter(
                 (i for i, p in enumerate(prefixes) if p is not None),
                 np.int64, len(kept))
-        self.arena = b"".join(kept)
+        self.arena = self.ptr = b"".join(kept)
         self.offsets = np.zeros(len(kept) + 1, np.int64)
         np.cumsum(np.fromiter(map(len, kept), np.int64, len(kept)),
                   out=self.offsets[1:])
+        self.beg, self.end = self.offsets[:-1], self.offsets[1:]
+
+    @classmethod
+    def laid(cls, names: np.ndarray, tags: np.ndarray, shelf: "_RowShelf",
+             beg: np.ndarray, end: np.ndarray, rendered: Optional[np.ndarray],
+             used: int) -> "_SectionArena":
+        """An arena of the rendering rows' prefixes where `shelf` keeps
+        them, `shelf.data[beg[j]:end[j]]`. It holds that array: a shelf
+        that grows or lays its bytes anew takes another, and appends
+        only past what is written."""
+        self = cls.__new__(cls)
+        self.names, self.tags, self.prefixes = names, tags, None
+        self.shelf = shelf
+        self.used, self.rendered = used, rendered
+        self.arena, self.ptr = shelf.data, shelf.data.ctypes.data
+        self.beg, self.end = beg, end
+        self.offsets = np.zeros(beg.shape[0] + 1, np.int64)
+        np.cumsum(end - beg, out=self.offsets[1:])
+        return self
 
     def series_among(self, rows: int) -> int:
         """How many of the first `rows` rows render."""

@@ -385,12 +385,14 @@ class FlushSection:
     """One homogeneous column group: parallel names/values/tags arrays
     sharing a metric type. `tags` entries are per-row list refs shared
     with RowMeta — consumers must copy before mutating (materialize
-    does)."""
+    does). `rows`: the table rows the series come from, ascending, where
+    the section has them (an encoder finds a row's state again by it)."""
 
     names: np.ndarray   # object ndarray of str
     values: np.ndarray  # float64
     tags: np.ndarray    # object ndarray of List[str] (shared refs)
     mtype: MetricType
+    rows: Optional[np.ndarray] = None  # int64, parallel to names
 
     def select(self, mask: np.ndarray) -> Optional["FlushSection"]:
         """The rows a boolean mask picks: this very section where it
@@ -401,7 +403,8 @@ class FlushSection:
         if not mask.any():
             return None
         return FlushSection(self.names[mask], self.values[mask],
-                            self.tags[mask], self.mtype)
+                            self.tags[mask], self.mtype,
+                            None if self.rows is None else self.rows[mask])
 
 
 _LE_TAGS: Optional[List[str]] = None
@@ -818,7 +821,8 @@ def readout_columnstore(
             if rows.size:
                 sections.append(FlushSection(
                     table.flush_names("", rows, meta_list, lambda m: m.name),
-                    vals_sel, table.flush_tags(rows, meta_list), mtype))
+                    vals_sel, table.flush_tags(rows, meta_list), mtype,
+                    rows))
 
         with timing.phase("assembly_scalar", parent="assembly"):
             scalar_family(store.counters, c_vals, c_touched, c_meta,
@@ -856,7 +860,7 @@ def readout_columnstore(
                         htab.flush_names(
                             suffix, hr[mask], h_meta,
                             lambda m, s=suffix: f"{m.name}.{s}"),
-                        values[mask], tags_hr[mask], mtype))
+                        values[mask], tags_hr[mask], mtype, hr[mask]))
 
                 lmin, lmax = cols["lmin"], cols["lmax"]
                 lsum, lweight = cols["lsum"], cols["lweight"]
@@ -900,7 +904,8 @@ def readout_columnstore(
                             htab.flush_names(
                                 p, pr, h_meta,
                                 lambda m, p=p: _percentile_name(m.name, p)),
-                            pq[:, ps_index[p]], ptags, MetricType.GAUGE))
+                            pq[:, ps_index[p]], ptags, MetricType.GAUGE,
+                            pr))
 
                 if need_export:
                     (exp_means, exp_weights, exp_min, exp_max,
@@ -944,7 +949,7 @@ def readout_columnstore(
                     sections.append(FlushSection(
                         stab.flush_names("", er, s_meta, lambda m: m.name),
                         np.asarray(estimates, np.float64)[er],
-                        stab.flush_tags(er, s_meta), MetricType.GAUGE))
+                        stab.flush_tags(er, s_meta), MetricType.GAUGE, er))
 
         # ---- log-linear histograms ------------------------------------------
         # percentiles/sum/count columnarize like every other family; the
@@ -991,7 +996,7 @@ def readout_columnstore(
                             lltab.flush_names(
                                 p, er, ll_meta,
                                 lambda m, p=p: _percentile_name(m.name, p)),
-                            quants[:, j], tags_er, MetricType.GAUGE))
+                            quants[:, j], tags_er, MetricType.GAUGE, er))
                     e_rows, e_bins, e_counts = \
                         llhist_ref.nonzero_entries(ll_bins)
                     kept = emit[e_rows]
@@ -1005,14 +1010,14 @@ def readout_columnstore(
                                           lambda m: f"{m.name}.sum"),
                         llhist_ref.entry_sums(e_rows, e_bins, e_counts,
                                               er.size),
-                        tags_er, MetricType.GAUGE))
+                        tags_er, MetricType.GAUGE, er))
                     indptr, le_idx, cum, total = llhist_ref.cumulative_entries(
                         e_rows, e_bins, e_counts, er.size)
                     total = total.astype(np.float64)
                     sections.append(FlushSection(
                         lltab.flush_names("count", er, ll_meta,
                                           lambda m: f"{m.name}.count"),
-                        total, tags_er, MetricType.COUNTER))
+                        total, tags_er, MetricType.COUNTER, er))
                     bucket_sections.append(BucketSection(
                         lltab.flush_names("bucket", er, ll_meta,
                                           lambda m: f"{m.name}.bucket"),

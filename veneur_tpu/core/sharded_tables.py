@@ -658,13 +658,8 @@ class ShardedHistoTable(_PerDeviceStates, _DigestRouted, HistoTable):
         the per-shard staging compact. Returns the wall of placing the
         batch on the shard's device (routing, not apply)."""
         dev = self._devices[i]
-        slots, overflow = batch_tdigest.host_slots(
-            rows, vals, wts, shard_counts[i])
-        if overflow:
-            states[i] = self._compact(states[i], path)
-            shard_counts[i][:] = 0
-            slots, _ = batch_tdigest.host_slots(
-                rows, vals, wts, shard_counts[i])
+        states[i], slots = self._slots(states[i], (rows, vals, wts),
+                                       shard_counts[i], path)
         t0 = time.perf_counter()
         placed = [jax.device_put(c, dev) for c in (rows, vals, wts, slots)]
         route_s = time.perf_counter() - t0
@@ -789,7 +784,8 @@ class ShardedHistoTable(_PerDeviceStates, _DigestRouted, HistoTable):
             return states
 
         def compact(states):
-            return [batch_tdigest.compact(st) for st in states]
+            return [batch_tdigest.compact(
+                st, np.zeros(st["wv"].shape[0], bool))[0] for st in states]
 
         def readout(merged):
             if need_export:

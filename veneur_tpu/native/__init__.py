@@ -183,14 +183,18 @@ def _declare_series(lib) -> None:
     i64 = ctypes.c_int64
     lib.vnt_dd_series.restype = i64
     lib.vnt_dd_series.argtypes = [
-        ctypes.c_char_p, ctypes.c_void_p, ctypes.c_void_p, i64,
-        ctypes.c_char_p, i64, ctypes.c_void_p, i64]
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        i64, ctypes.c_char_p, i64, ctypes.c_void_p, i64]
     lib.vnt_dd_series_room.restype = i64
     lib.vnt_dd_series_room.argtypes = [i64]
     lib.vnt_dd_changed_rows.restype = i64
     lib.vnt_dd_changed_rows.argtypes = [
         ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
         ctypes.c_void_p, i64, ctypes.c_void_p]
+    lib.vnt_dd_changed_at.restype = i64
+    lib.vnt_dd_changed_at.argtypes = [
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p,
+        ctypes.c_void_p, ctypes.c_void_p, i64, ctypes.c_void_p]
 
 
 class _Unit:
@@ -265,7 +269,8 @@ def unavailable_reason() -> str | None:
 
 def load_series():
     """Returns the Datadog series encoder's library (`vnt_dd_series`,
-    `vnt_dd_changed_rows`; core/egress.py drives them), or None if
+    `vnt_dd_changed_rows`, `vnt_dd_changed_at`; core/egress.py drives them),
+    or None if
     unavailable."""
     return _SERIES.load()
 

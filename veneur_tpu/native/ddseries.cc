@@ -89,22 +89,23 @@ char* fmt_repr(double v, char* p) {
 extern "C" {
 
 // Appends `n` series, comma-joined, to `out`: per row
-//   arena[off[i]:off[i+1]] + mid + repr(vals[i]) + "]]}"
-// where `mid` is the flush's `],"points":[[<ts>,` fragment. `off` has
-// n + 1 entries (the rows' prefixes lie back to back in the arena).
-// Returns the bytes written, or -1 if `cap` could not hold them.
-int64_t vnt_dd_series(const char* arena, const int64_t* off,
-                      const double* vals, int64_t n,
+//   base[beg[i]:end[i]] + mid + repr(vals[i]) + "]]}"
+// where `mid` is the flush's `],"points":[[<ts>,` fragment. Prefixes
+// that lie back to back pass their n + 1 offsets as `beg` and, one entry
+// on, as `end`. Returns the bytes written, or -1 if `cap` could not hold
+// them.
+int64_t vnt_dd_series(const char* base, const int64_t* beg,
+                      const int64_t* end, const double* vals, int64_t n,
                       const char* mid, int64_t mid_len,
                       char* out, int64_t cap) {
     if (n <= 0) return 0;
-    const int64_t need = (off[n] - off[0])
-        + n * (mid_len + kValueRoom + 4);
+    int64_t need = n * (mid_len + kValueRoom + 4);
+    for (int64_t i = 0; i < n; ++i) need += end[i] - beg[i];
     if (need > cap) return -1;
     char* p = out;
     for (int64_t i = 0; i < n; ++i) {
         if (i) *p++ = ',';
-        p = put(p, arena + off[i], off[i + 1] - off[i]);
+        p = put(p, base + beg[i], end[i] - beg[i]);
         p = put(p, mid, mid_len);
         p = fmt_repr(vals[i], p);
         p = put(p, "]]}", 3);
@@ -128,6 +129,20 @@ int64_t vnt_dd_changed_rows(const void* const* names,
     int64_t d = 0;
     for (int64_t i = 0; i < n; ++i)
         if (names[i] != kept_names[i] || tags[i] != kept_tags[i])
+            out[d++] = i;
+    return d;
+}
+
+// The same against a row shelf, which keeps its objects by table row:
+// names[i] against kept_names[rows[i]], tags[i] against kept_tags[rows[i]].
+int64_t vnt_dd_changed_at(const void* const* names,
+                          const void* const* tags,
+                          const void* const* kept_names,
+                          const void* const* kept_tags,
+                          const int64_t* rows, int64_t n, int64_t* out) {
+    int64_t d = 0;
+    for (int64_t i = 0; i < n; ++i)
+        if (names[i] != kept_names[rows[i]] || tags[i] != kept_tags[rows[i]])
             out[d++] = i;
     return d;
 }

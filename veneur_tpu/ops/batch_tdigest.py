@@ -19,18 +19,22 @@ whole table of digests is three dense device arrays (means, weights of shape
      batch-local weighted midpoint quantile (host_slots) — statistically
      sound at that density and identical to what a per-batch merge would
      do with them.
-  3. When any key's staging would otherwise overflow its C slots — the
-     host tracks exact per-key occupancy — and always before flush/
-     export/merge, `compact` folds staging into the main grid with the
+  3. When a key's staging would otherwise overflow its C slots — the
+     host tracks exact per-key occupancy — `compact` folds the staging
+     of the rows that would overflow (and of those at least half full,
+     `FOLD_ALONG`, at no extra cost) into their main grid with the
      mean-sorted recompress: sort [main | staging] slots by mean, bucket
      by the arcsine k-scale of combined midpoint quantiles (parity with
      merging_digest.go:259-262), and segment-reduce the (sorted, hence
-     contiguous) buckets with a chunked one-hot matmul on the MXU.
+     contiguous) buckets with a chunked one-hot matmul on the MXU. The
+     readouts and merges fold staging themselves.
 
 Sparse keys (the 100k-key regime: ~1 sample/key/batch) therefore stage
-EXACTLY and amortize the capacity-proportional recompress over dozens of
-batches; dense keys compact about once per batch, exactly like the
-reference's temp buffer filling per ~5·compression samples. After every
+EXACTLY until their own slots fill, however often a hot key forces a
+compact (each recompress of a row's grid costs accuracy: a key of 70
+samples recompressed once a batch strayed 0.021 in rank); dense keys
+compact about once per batch, exactly like the reference's temp buffer
+filling per ~5·compression samples. After every
 compact each slot spans at most one k-unit of the combined distribution,
 so quantile error stays in the sequential algorithm's class. Bucketing
 by floor(k) bounds the store at `compression` centroids per key (the
@@ -53,6 +57,10 @@ from veneur_tpu.ops import device_scope
 
 COMPRESSION = 100.0  # parity with reference samplers/samplers.go:350
 C = 128  # centroid slots per key; >= COMPRESSION buckets, lane-aligned
+# a compact forced by some rows also folds every row with at least this
+# many staged samples (a row folded with fewer would take a recompress
+# it does not need, and each costs it accuracy)
+FOLD_ALONG = C // 2
 
 _INF = jnp.float32(jnp.inf)
 
@@ -116,9 +124,11 @@ def host_slots(rows, values, weights, counts):
     statistically sound at that density — and are marked full so the
     next touch forces a compact.
 
-    Returns (slots, overflow). overflow=True means some key's staged
-    count plus this batch would exceed C: the caller must `compact`
-    (zeroing `counts`) and call again; `counts` is not mutated then.
+    Returns (slots, overflow, dense): `dense` is how many keys took
+    the k-bucket fold. `overflow` is None, or the (K,) mask of the rows
+    whose staged count plus this batch would exceed C: the caller must
+    `compact` those rows (zeroing their `counts`) and call again;
+    `counts` is not mutated then.
     """
     cap = counts.shape[0]
     out = np.zeros(rows.shape[0], np.int32)
@@ -126,15 +136,16 @@ def host_slots(rows, values, weights, counts):
     r = rows[valid]
     n = r.shape[0]
     if n == 0:
-        return out, False
+        return out, None, 0
     g = np.bincount(r, minlength=cap).astype(np.int32)
-    if bool(np.any((counts > 0) & (counts + g > C))):
-        return out, True
+    overflow = (counts > 0) & (counts + g > C)
+    if overflow.any():
+        return out, overflow, 0
     dense = g > C
     if not dense.any():
         out[valid] = counts[r] + host_ranks(r)
         counts += g
-        return out, False
+        return out, None, 0
 
     v = np.asarray(values)[valid]
     w = np.asarray(weights)[valid]
@@ -163,13 +174,13 @@ def host_slots(rows, values, weights, counts):
     out[valid] = sl
     counts += g
     counts[dense] = C  # full: next touch of a dense key forces a compact
-    return out, False
+    return out, None, int(np.count_nonzero(dense))
 
 
 def batch_slots(rows, values, weights, num_keys):
     """Slots for a standalone single batch (fresh staging)."""
     counts = np.zeros(num_keys, np.int32)
-    slots, _ = host_slots(np.asarray(rows), values, weights, counts)
+    slots, _, _ = host_slots(np.asarray(rows), values, weights, counts)
     return slots
 
 
@@ -358,18 +369,25 @@ def _fold_grids(state):
 
 @partial(jax.jit, donate_argnums=0)
 @device_scope("compact", "histogram")
-def compact(state):
-    """Fold the staging grid into the main grid with the mean-sorted
-    recompress, leaving staging empty. Run every few applied batches and
-    always before flush/export/cross-shard merge."""
+def compact(state, fold):
+    """Fold the staging grid of the rows that the (K,) bool mask `fold`
+    marks into their main grid with the mean-sorted recompress, leaving
+    their staging empty; every other row keeps both grids as they are
+    (a recompress costs a row accuracy, so no row takes one it does not
+    need). Run when a row's staging would overflow (`host_slots`).
+
+    Returns (state, done). `done` is a scalar of the recompressed grid
+    that no later program donates: the handle a completion stamp may
+    hold while the next apply consumes `state` (core/deviceobs.py)."""
     state = dict(state)
     cat_m, cat_w = _fold_grids(state)
     new_m, new_w = _recompress(cat_m, cat_w, state["wv"].shape[0])
-    state["weights"] = new_w
-    state["wv"] = new_m * new_w
-    state["sweights"] = jnp.zeros_like(new_w)
-    state["swv"] = jnp.zeros_like(new_w)
-    return state
+    keep = ~fold[:, None]
+    state["weights"] = jnp.where(keep, state["weights"], new_w)
+    state["wv"] = jnp.where(keep, state["wv"], new_m * new_w)
+    state["sweights"] = jnp.where(keep, state["sweights"], 0.0)
+    state["swv"] = jnp.where(keep, state["swv"], 0.0)
+    return state, new_w[-1, -1]
 
 
 @jax.jit
